@@ -38,9 +38,7 @@ import os
 from typing import Dict
 
 from dnn_page_vectors_tpu.config import CONFIGS, get_config
-from dnn_page_vectors_tpu.utils.platform import honor_jax_platforms_env
-
-honor_jax_platforms_env()
+from dnn_page_vectors_tpu.utils.platform import enable_compile_cache
 
 
 def _parse_overrides(pairs) -> Dict[str, object]:
@@ -430,6 +428,7 @@ def main(argv=None) -> None:
     # transient-I/O retry policy — every command goes through this
     from dnn_page_vectors_tpu.utils import faults
     faults.install_from_config(cfg)
+    enable_compile_cache()
 
     from dnn_page_vectors_tpu.parallel.mesh import multihost_init
     multihost_init()

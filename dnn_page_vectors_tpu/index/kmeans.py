@@ -32,8 +32,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dnn_page_vectors_tpu.ops.topk import stage_shard
-from dnn_page_vectors_tpu.utils.compat import (
-    pcast_varying, shard_map_unchecked)
 
 _PASS_CACHE: Dict[Tuple, object] = {}
 
@@ -70,7 +68,7 @@ def _build_shard_pass(mesh: Mesh, nlist: int, chunk: int, scaled: bool,
         # carry starts as a constant; pcast marks it varying over 'data' so
         # the scan's in/out types agree under shard_map (see ops/topk.py)
         init = jax.tree_util.tree_map(
-            lambda x: pcast_varying(x, ("data",)),
+            lambda x: lax.pcast(x, ("data",), to="varying"),
             (jnp.zeros((nlist, D), jnp.float32),
              jnp.zeros((nlist,), jnp.float32)))
 
@@ -116,8 +114,9 @@ def _build_shard_pass(mesh: Mesh, nlist: int, chunk: int, scaled: bool,
         in_specs = (P("data"), P(), P())
     # psum makes sums/counts replicated — a dynamic fact the static
     # varying-axis checker can't infer (same escape hatch as sharded_topk)
-    mapped = shard_map_unchecked(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=(P(), P(), P("data")))
+    mapped = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                           out_specs=(P(), P(), P("data")),
+                           check_vma=False)
     return jax.jit(mapped)
 
 

@@ -429,9 +429,8 @@ class BulkEmbedder:
                     with prof.stage("d2h"):
                         # ONE packed drain per dispatch: ids + vectors
                         # (+ scales) materialize together instead of a
-                        # sequence of per-array np.asarray syncs — on a
-                        # tunneled/remote backend each sync is a full
-                        # round trip, and the drain rate (stage_d2h_bytes
+                        # sequence of per-array np.asarray syncs — each
+                        # sync is a round trip, and the drain rate (stage_d2h_bytes
                         # over stage_d2h_s, reported as
                         # embed_d2h_mbytes_per_sec) is what bounds the
                         # from-text sweep (docs/MFU.md "host pipeline").

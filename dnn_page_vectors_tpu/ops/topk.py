@@ -29,8 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from dnn_page_vectors_tpu.utils.compat import (
-    pcast_varying, shard_map_unchecked)
 
 
 def _topk_scan(q: jnp.ndarray, pages: jnp.ndarray, k: int, chunk: int,
@@ -129,7 +127,7 @@ def _build_sharded_topk(mesh: Mesh, k: int, chunk: int, scaled: bool):
         # carry starts as a constant; pcast marks it varying over 'data' so
         # the scan's in/out types agree under shard_map
         init = jax.tree_util.tree_map(
-            lambda x: pcast_varying(x, ("data",)),
+            lambda x: lax.pcast(x, ("data",), to="varying"),
             (jnp.full((q.shape[0], k), -jnp.inf, jnp.float32),
              jnp.full((q.shape[0], k), -1, jnp.int32)))
         s, i = _topk_scan(q, pages_local, k, c, valid_local,
@@ -157,8 +155,8 @@ def _build_sharded_topk(mesh: Mesh, k: int, chunk: int, scaled: bool):
     else:
         fn = lambda q, pages, valid: run(q, pages, None, valid)  # noqa: E731
         in_specs = (P(), P("data"), P())
-    mapped = shard_map_unchecked(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=(P(), P()))
+    mapped = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                           out_specs=(P(), P()), check_vma=False)
     return jax.jit(mapped)
 
 

@@ -48,8 +48,7 @@ def _ring_attention_local(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     Returns [B, H, L_loc, Dh] float32 — the exact global-attention output
     for the local queries.
     """
-    from dnn_page_vectors_tpu.utils.compat import axis_size
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     scale = 1.0 / np.sqrt(q.shape[-1])
     qf = q.astype(jnp.float32) * scale
@@ -120,7 +119,7 @@ def ring_attention(mesh: Mesh, q: jnp.ndarray, k: jnp.ndarray,
         fn_ = fn
         in_specs = (qkv_spec, qkv_spec, qkv_spec, mask_spec, P())
         args = (q, k, v, kv_mask, bias_table)
-    from dnn_page_vectors_tpu.utils.compat import shard_map_unchecked
-    return shard_map_unchecked(
+    return jax.shard_map(
         fn_, mesh=mesh, in_specs=in_specs, out_specs=qkv_spec,
+        check_vma=False,
     )(*args)

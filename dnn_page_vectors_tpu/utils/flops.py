@@ -168,22 +168,31 @@ _PEAK_HBM = [
     ("v5e", 819e9),
     ("v5litepod", 819e9),
     ("v5p", 2765e9),
-    ("v5", 2765e9),
     ("v4", 1228e9),
     ("v3", 450e9),
     ("v2", 350e9),
 ]
 
 
-def device_peak_hbm_bps(device) -> Optional[float]:
-    """Per-device peak HBM bandwidth in bytes/s, or None when unknown."""
+def _peak_for(device, table, what: str) -> Optional[float]:
+    """`table` row for this device's kind; None off-TPU (CPU has no peak
+    to compare against). A TPU kind the table does not list is an error,
+    never a neighbouring generation's peak."""
     kind = getattr(device, "device_kind", "").lower()
     if "tpu" not in kind and getattr(device, "platform", "") != "tpu":
         return None
-    for sub, bw in _PEAK_HBM:
+    for sub, peak in table:
         if sub in kind:
-            return bw
-    return None
+            return peak
+    raise ValueError(
+        f"no {what} on record for TPU device_kind "
+        f"{getattr(device, 'device_kind', '')!r}; add its published "
+        "figure to utils/flops.py")
+
+
+def device_peak_hbm_bps(device) -> Optional[float]:
+    """Per-device peak HBM bandwidth in bytes/s, or None off-TPU."""
+    return _peak_for(device, _PEAK_HBM, "peak HBM bandwidth")
 
 
 def roofline(flops_per_pair: float, bytes_per_pair: float,
@@ -207,7 +216,6 @@ _PEAK_BF16 = [
     ("v5e", 197e12),
     ("v5litepod", 197e12),
     ("v5p", 459e12),
-    ("v5", 459e12),
     ("v4", 275e12),
     ("v3", 61.5e12),
     ("v2", 23e12),
@@ -215,11 +223,5 @@ _PEAK_BF16 = [
 
 
 def device_peak_flops(device) -> Optional[float]:
-    """Per-device peak bf16 FLOP/s, or None when unknown (e.g. CPU)."""
-    kind = getattr(device, "device_kind", "").lower()
-    if "tpu" not in kind and getattr(device, "platform", "") != "tpu":
-        return None
-    for sub, peak in _PEAK_BF16:
-        if sub in kind:
-            return peak
-    return None
+    """Per-device peak bf16 FLOP/s, or None off-TPU (e.g. CPU)."""
+    return _peak_for(device, _PEAK_BF16, "peak bf16 FLOP/s")
