@@ -1,0 +1,8 @@
+"""Share of the traced window in which no operation ran on the device."""
+
+
+def read(ctx):
+    red = ctx.get("reduced")
+    if ctx.get("job") != "serve" or not red or red["idle_share"] is None:
+        return None
+    return 100.0 * red["idle_share"]
