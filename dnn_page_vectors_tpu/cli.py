@@ -729,7 +729,7 @@ def main(argv=None) -> None:
         # compute/d2h/write) in the final JSON: the operator sees WHICH
         # stage binds the sweep, not just the end-to-end rate
         from dnn_page_vectors_tpu.utils.profiling import PipelineProfiler
-        prof = PipelineProfiler()
+        prof = PipelineProfiler(prefix="embed.")
         with maybe_profile(args.profile, cfg.workdir):
             embedder.embed_corpus(trainer.corpus, store,
                                   start=args.start, stop=args.stop,
@@ -851,57 +851,62 @@ def main(argv=None) -> None:
             cfg, embedder, trainer.corpus, store, preload_hbm_gb=preload,
             log=MetricsLogger(cfg.workdir, echo=False,
                               registry=telemetry.default_registry()))
-        if args.queries:
-            # batch mode: every line is a query; the whole file goes through
-            # ONE search_many (bucket-filling tiled dispatch), one JSON
-            # result line per query in input order
-            with open(args.queries) as f:
-                queries = [ln.strip() for ln in f if ln.strip()]
-            results = svc.search_many(queries, k=k,
-                                      filters=args.filter_expr)
-            for query, res in zip(queries, results):
-                print(json.dumps({"query": query, "results": res}),
+        # --profile: the answers' serve.* stages and the device ops in one
+        # jax.profiler trace (docs/OBSERVABILITY.md "The combined trace")
+        with maybe_profile(args.profile, cfg.workdir):
+            if args.queries:
+                # batch mode: every line is a query; the whole file goes
+                # through ONE search_many (bucket-filling tiled dispatch),
+                # one JSON result line per query in input order
+                with open(args.queries) as f:
+                    queries = [ln.strip() for ln in f if ln.strip()]
+                results = svc.search_many(queries, k=k,
+                                          filters=args.filter_expr)
+                for query, res in zip(queries, results):
+                    print(json.dumps({"query": query, "results": res}),
+                          flush=True)
+                # flushes cache/stage counters to the metrics log
+                svc.close()
+            elif args.interactive:
+                import sys
+                svc.warmup(k=k)
+                print(json.dumps({"ready": True, "vectors": store.num_vectors,
+                                  "hbm_resident": svc.preloaded,
+                                  "degraded": svc.degraded,
+                                  "fault_counters": faults.counters(),
+                                  "latency_ms": round(
+                                      svc.warm_latency_ms, 3)}),
                       flush=True)
-            svc.close()     # flushes cache/stage counters to the metrics log
-        elif args.interactive:
-            import sys
-            svc.warmup(k=k)
-            print(json.dumps({"ready": True, "vectors": store.num_vectors,
-                              "hbm_resident": svc.preloaded,
-                              "degraded": svc.degraded,
-                              "fault_counters": faults.counters(),
-                              "latency_ms": round(svc.warm_latency_ms, 3)}),
-                  flush=True)
-            for line in sys.stdin:
-                query = line.strip()
-                if not query:
-                    continue
-                if query == ":refresh":
-                    # zero-downtime hot-swap to the store's current
-                    # generation (after an out-of-process `append`):
-                    # in-flight queries finish on the old view
-                    print(json.dumps({"refreshed": svc.refresh()},
-                                     sort_keys=True), flush=True)
-                    continue
-                if query == ":metrics":
-                    # live JSON snapshot of the serving registry (docs/
-                    # OBSERVABILITY.md): flat metrics + typed instruments
-                    # with windowed qps/p99 + the lifecycle event ring
-                    print(json.dumps(svc.metrics_snapshot(),
-                                     sort_keys=True), flush=True)
-                    continue
-                print(json.dumps({"query": query,
+                for line in sys.stdin:
+                    query = line.strip()
+                    if not query:
+                        continue
+                    if query == ":refresh":
+                        # zero-downtime hot-swap to the store's current
+                        # generation (after an out-of-process `append`):
+                        # in-flight queries finish on the old view
+                        print(json.dumps({"refreshed": svc.refresh()},
+                                         sort_keys=True), flush=True)
+                        continue
+                    if query == ":metrics":
+                        # live JSON snapshot of the serving registry (docs/
+                        # OBSERVABILITY.md): flat metrics + typed instruments
+                        # with windowed qps/p99 + the lifecycle event ring
+                        print(json.dumps(svc.metrics_snapshot(),
+                                         sort_keys=True), flush=True)
+                        continue
+                    print(json.dumps({"query": query,
+                                      "results": svc.search(
+                                          query, k=k,
+                                          filters=args.filter_expr)}),
+                          flush=True)
+                svc.close()
+            else:
+                print(json.dumps({"query": args.query,
+                                  "degraded": svc.degraded,
                                   "results": svc.search(
-                                      query, k=k,
-                                      filters=args.filter_expr)}),
-                      flush=True)
-            svc.close()
-        else:
-            print(json.dumps({"query": args.query,
-                              "degraded": svc.degraded,
-                              "results": svc.search(
-                                  args.query, k=k,
-                                  filters=args.filter_expr)}))
+                                      args.query, k=k,
+                                      filters=args.filter_expr)}))
     elif args.command == "loadtest":
         # SLO harness (docs/SERVING.md "SLO methodology"): replay a seeded
         # traffic shape against a live micro-batched service and

@@ -379,7 +379,8 @@ class BulkEmbedder:
                    else workers)
         write_pending = (self.cfg.eval.writeback_depth if write_pending is None
                          else write_pending)
-        prof = PipelineProfiler() if profiler is None else profiler
+        prof = (PipelineProfiler(prefix="embed.") if profiler is None
+                else profiler)
         # embed-sweep throughput as registry instruments (docs/
         # OBSERVABILITY.md): the windowed pages counter answers "what is
         # the rate RIGHT NOW" mid-sweep, the end-of-job gauge mirrors the

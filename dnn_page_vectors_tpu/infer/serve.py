@@ -193,29 +193,37 @@ class _MicroBatcher:
         return fut
 
     def _run(self) -> None:
+        # the dispatcher thread is the serve front's one scarce resource:
+        # batcher_idle / batch_window / dispatch (opened in _dispatch) split
+        # its time into waiting for work, waiting for company, and working
+        stage = self._svc._stage
         while True:
-            item = self._q.get()
+            with stage("batcher_idle"):
+                item = self._q.get()
             if item is self._STOP:
                 return
             batch = [item]
-            deadline = time.perf_counter() + max(0.0, self._window_s())
-            while len(batch) < self._max:
-                rem = deadline - time.perf_counter()
-                try:
-                    nxt = (self._q.get_nowait() if rem <= 0
-                           else self._q.get(timeout=rem))
-                except queue_mod.Empty:
-                    break
-                if nxt is self._STOP:
-                    self._dispatch(batch)
-                    return
-                batch.append(nxt)
+            stopping = False
+            with stage("batch_window"):
+                deadline = time.perf_counter() + max(0.0, self._window_s())
+                while len(batch) < self._max:
+                    rem = deadline - time.perf_counter()
+                    try:
+                        nxt = (self._q.get_nowait() if rem <= 0
+                               else self._q.get(timeout=rem))
+                    except queue_mod.Empty:
+                        break
+                    if nxt is self._STOP:
+                        stopping = True
+                        break
+                    batch.append(nxt)
             self._dispatch(batch)
+            if stopping:
+                return
             self._svc._adapt_window()
 
     def _dispatch(self, batch) -> None:
         svc = self._svc
-        tracer = svc.tracer
         # THE DOOR (docs/SERVING.md "Network front end"): a request whose
         # deadline expired while it queued is rejected here, BEFORE it
         # can occupy a bucket slot — its caller gets DeadlineExceeded now
@@ -235,7 +243,16 @@ class _MicroBatcher:
                 live.append(item)
         if not live:
             return
-        batch = live
+        with svc._stage("dispatch"):
+            self._dispatch_live(live)
+
+    def _dispatch_live(self, batch) -> None:
+        """Answer one batch that passed the door: everything here runs
+        under the `dispatch` stage, so what tokenize/encode/topk/merge/
+        format leave over (grouping, trace grafting, waking the callers)
+        is that stage's self time."""
+        svc = self._svc
+        tracer = svc.tracer
         now = time.perf_counter()
         for _, _, _, t0, ctx, _ in batch:
             svc.profiler.add("queue_wait", now - t0)
@@ -460,7 +477,7 @@ class SearchService:
         # per-stage serving breakdown (queue_wait/tokenize/encode/topk/
         # merge/format) — one shared instance; the batcher and concurrent
         # callers all add into it
-        self.profiler = profiler or PipelineProfiler()
+        self.profiler = profiler or PipelineProfiler(prefix="serve.")
         # -- telemetry (docs/OBSERVABILITY.md) ----------------------------
         # One registry per service (counters must not mix across services)
         # holding every serving instrument; request-scoped tracing follows
@@ -905,16 +922,15 @@ class SearchService:
 
     @contextlib.contextmanager
     def _stage(self, name: str, **attrs):
-        """One serving stage, observed twice from one clock: cumulative
-        seconds into the PipelineProfiler (the aggregate view) and a span
-        on the active request trace (the per-request view). Yields the
-        span so call sites can attach attributes (ANN stats, cache hits)."""
-        t0 = time.perf_counter()
-        with self.tracer.span(name, **attrs) as sp:
-            try:
-                yield sp
-            finally:
-                self.profiler.add(name, time.perf_counter() - t0)
+        """One serving stage, observed three times over one interval:
+        cumulative seconds into the PipelineProfiler (the aggregate view),
+        its `serve.<name>` event in the jax profiler's trace when a session
+        is recording (the device's clock), and a span on the active request
+        trace (the per-request view). Yields the span so call sites can
+        attach attributes (ANN stats, cache hits)."""
+        with self.profiler.stage(name), \
+                self.tracer.span(name, **attrs) as sp:
+            yield sp
 
     def _count_fault(self, name: str) -> None:
         self.fault_counters[name] = self.fault_counters.get(name, 0) + 1
@@ -1441,7 +1457,8 @@ class SearchService:
             view.merge = reuse.merge
             return
 
-        def merge(cands):
+        @jax.named_scope("merge")    # names its ops; the program stays
+        def merge(cands):            # `jit_merge`
             # Device-side cross-shard merge, output PACKED into one fp32
             # array: every host<->device round trip adds to per-query
             # serving latency, so the k winners across all resident shards

@@ -369,6 +369,7 @@ def _flash_forward(q, k, v, kv_mask, bias, seg, block_q, block_kv,
 
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -444,6 +445,7 @@ def _flash_backward(q, k, v, kv_mask, bias, seg, g, out, lse, block_q,
 
         dq = pl.pallas_call(
             dq_kernel,
+            name="flash_dq",
             grid=(B, H, Lp // block_q),
             in_specs=in_specs + [qspec, rowspec, rowspec],
             out_specs=qspec,
@@ -484,6 +486,7 @@ def _flash_backward(q, k, v, kv_mask, bias, seg, g, out, lse, block_q,
 
         dq, db = pl.pallas_call(
             dq_db_kernel,
+            name="flash_dq_dbias",
             grid=(H, Lp // block_q, B),
             in_specs=in_specs + [qspec, rowspec, rowspec],
             out_specs=[qspec,
@@ -531,6 +534,7 @@ def _flash_backward(q, k, v, kv_mask, bias, seg, g, out, lse, block_q,
         args.extend([seg_q, seg_kv])
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="flash_dkv",
         grid=(B, H, Sp // block_kv),
         in_specs=in_specs + [qfull, rowfull, rowfull],
         out_specs=[kvspec, kvspec],

@@ -130,19 +130,24 @@ def _build_sharded_topk(mesh: Mesh, k: int, chunk: int, scaled: bool):
             lambda x: lax.pcast(x, ("data",), to="varying"),
             (jnp.full((q.shape[0], k), -jnp.inf, jnp.float32),
              jnp.full((q.shape[0], k), -1, jnp.int32)))
-        s, i = _topk_scan(q, pages_local, k, c, valid_local,
-                          scales=scales_local, init=init)
-        gi = jnp.where(i >= 0, i + shard * rows, -1)
-        # gather every shard's k candidates over ICI and merge everywhere
-        all_s = lax.all_gather(s, "data")            # [n_data, Bq, k]
-        all_i = lax.all_gather(gi, "data")
-        Bq = q.shape[0]
-        cat_s = jnp.transpose(all_s, (1, 0, 2)).reshape(Bq, n_data * k)
-        cat_i = jnp.transpose(all_i, (1, 0, 2)).reshape(Bq, n_data * k)
-        kk = min(k, n_data * k)
-        top_s, pos = lax.top_k(cat_s, kk)
-        top_i = jnp.take_along_axis(cat_i, pos, axis=1)
-        top_i = jnp.where(jnp.isfinite(top_s), top_i, -1)
+        # named_scope: the two regions of this program carry their names
+        # in every op's metadata, for --profile in xprof/Perfetto
+        with jax.named_scope("sharded_topk.scan"):
+            s, i = _topk_scan(q, pages_local, k, c, valid_local,
+                              scales=scales_local, init=init)
+        with jax.named_scope("sharded_topk.local_topk"):
+            gi = jnp.where(i >= 0, i + shard * rows, -1)
+            # gather every shard's k candidates over ICI and merge
+            # everywhere
+            all_s = lax.all_gather(s, "data")        # [n_data, Bq, k]
+            all_i = lax.all_gather(gi, "data")
+            Bq = q.shape[0]
+            cat_s = jnp.transpose(all_s, (1, 0, 2)).reshape(Bq, n_data * k)
+            cat_i = jnp.transpose(all_i, (1, 0, 2)).reshape(Bq, n_data * k)
+            kk = min(k, n_data * k)
+            top_s, pos = lax.top_k(cat_s, kk)
+            top_i = jnp.take_along_axis(cat_i, pos, axis=1)
+            top_i = jnp.where(jnp.isfinite(top_s), top_i, -1)
         return top_s, top_i
 
     # After the all_gather every shard computes the identical merge, so the

@@ -102,8 +102,20 @@ class KnobDriftRule(Rule):
         instruments = set()
         inst_re = re.compile(
             r"\.(?:counter|gauge|histogram)\(\s*[\"']([a-z_][a-z0-9_.]*)")
+        # so do the profiler's trace events (`serve.topk`): the prefix a
+        # PipelineProfiler is built with + a stage name (docs/
+        # OBSERVABILITY.md "The combined trace") — exempt every such pair
+        prefix_re = re.compile(
+            r"PipelineProfiler\(\s*prefix=[\"']([a-z_]+\.)[\"']")
+        stage_re = re.compile(
+            r"\b_?stage\(\s*[\"']([a-z_][a-z0-9_]*)[\"']")
+        prefixes, stages = set(), set()
         for rel in ctx.glob(ctx.pkg, ".py"):
-            instruments.update(inst_re.findall(ctx.read(rel) or ""))
+            text = ctx.read(rel) or ""
+            instruments.update(inst_re.findall(text))
+            prefixes.update(prefix_re.findall(text))
+            stages.update(stage_re.findall(text))
+        instruments.update(p + s for p in prefixes for s in stages)
         pat = re.compile(
             r"\b(" + "|".join(map(re.escape, sorted(sections))) +
             r")\.([a-z_][a-z0-9_]*)\b")

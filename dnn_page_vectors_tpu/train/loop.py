@@ -65,13 +65,18 @@ def make_train_step(model, tx, loss_chunk: int = 0):
                 rngs={"dropout": rng},
                 page_seg=batch.get("page_seg"),
                 page_pos=batch.get("page_pos"))
-            return cosine_contrastive_loss(q, p, scale, neg,
-                                           chunk=loss_chunk)
+            # Flax names the towers' ops by module path; the loss and the
+            # optimizer are no modules, so they get their scopes here
+            with jax.named_scope("loss"):
+                return cosine_contrastive_loss(q, p, scale, neg,
+                                               chunk=loss_chunk)
 
         (loss, metrics), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, state.opt_state,
+                                           state.params)
+            params = optax.apply_updates(state.params, updates)
         metrics = dict(metrics)
         metrics["grad_norm"] = optax.global_norm(grads)
         return TrainState(params=params, opt_state=opt_state,
@@ -305,7 +310,8 @@ class Trainer:
         flops_pair = train_flops_per_pair(cfg, cfg.train.batch_size)
         # graftcheck: off=host-sync -- one-time sync before the loop
         start_step = int(state.step)
-        prof = PipelineProfiler() if profiler is None else profiler
+        prof = (PipelineProfiler(prefix="train.") if profiler is None
+                else profiler)
         # train-loop throughput as registry instruments (docs/
         # OBSERVABILITY.md): a windowed steps counter gives live steps/sec
         # mid-run; the gauges mirror the numbers the metrics line reports
@@ -317,24 +323,36 @@ class Trainer:
               if scan_k > 1 else self.batches(start_step=start_step,
                                               profiler=prof))
         last: Dict[str, float] = {}
-        t0 = time.perf_counter()
+        # the logged rate covers the interval since the previous log line
+        # (t_mark, i_mark); the first step holds the compile, so the clock
+        # restarts behind its barrier and no logged rate includes it
+        t_mark, i_mark = time.perf_counter(), 0
         for c in range(steps // scan_k):
-            batch = next(it)
-            with prof.stage("compute"):   # dispatch; async past the first
-                state, metrics = step_fn(state, batch, base_rng)
-            _m_steps.inc(scan_k)
             i = (c + 1) * scan_k         # steps completed this call
-            if i % cfg.train.log_every == 0 or i == steps:
+            # step boundaries in a `--profile` trace (for the operator:
+            # xprof/Perfetto group device ops under each step)
+            with jax.profiler.StepTraceAnnotation(
+                    "train", step_num=start_step + i):
+                batch = next(it)
+                with prof.stage("compute"):   # dispatch; async past the first
+                    state, metrics = step_fn(state, batch, base_rng)
+            _m_steps.inc(scan_k)
+            at_log = i % cfg.train.log_every == 0 or i == steps
+            if c == 0 and not at_log:
+                # graftcheck: off=host-sync -- once per call: the barrier
+                # that keeps compilation out of every logged rate
+                jax.block_until_ready(state.params)
+                t_mark, i_mark = time.perf_counter(), i
+            if at_log:
                 metrics = {k: float(v) for k, v in metrics.items()}
                 with prof.stage("sync"):
                     # graftcheck: off=host-sync -- log-cadence drain:
                     # fires every log_every steps, not per step
                     jax.block_until_ready(state.params)
-                dt = time.perf_counter() - t0
-                # graftcheck: off=host-sync -- after the log-cadence
-                # drain above; the value is already on host
-                done = int(state.step) - start_step
-                pps_chip = done * pages_per_step / dt / n_dev
+                now = time.perf_counter()
+                pps_chip = ((i - i_mark) * pages_per_step
+                            / (now - t_mark) / n_dev)
+                t_mark, i_mark = now, i
                 metrics["pages_per_sec_per_chip"] = pps_chip
                 _reg.gauge("train.pages_per_sec_per_chip").set(pps_chip)
                 if peak:

@@ -13,6 +13,9 @@ import threading
 import time
 from typing import Dict
 
+import jax
+from jax.profiler import TraceAnnotation
+
 
 class PipelineProfiler:
     """Cumulative per-stage wall time for the host<->device pipelines.
@@ -28,6 +31,11 @@ class PipelineProfiler:
       write_wait    device loop blocked on the bounded writeback budget
 
     The serving path (infer/serve.py) uses:
+      batcher_idle  dispatcher thread blocked on an empty request queue
+      batch_window  dispatcher thread waiting out the coalescing window
+      dispatch      dispatcher thread answering one coalesced batch: the
+                    parent of tokenize/encode/topk/merge/format there, so
+                    dispatch minus those is host time no stage names
       queue_wait    request sat in the micro-batcher queue before dispatch
       tokenize      encode_batch over the coalesced cache-miss queries
       encode        compiled query-tower dispatch (+ host materialize)
@@ -42,9 +50,21 @@ class PipelineProfiler:
     `produce_wait`) say which stage binds, not how long the job took.
     Thread-safe: producers, tokenizer workers, and the writer thread all
     add into one instance.
+
+    Every `stage()` is ALSO an event in the jax profiler's trace
+    (docs/OBSERVABILITY.md "The combined trace"): a `TraceAnnotation` over
+    the interval the stage times, on the thread that runs it, so a
+    `--profile` run shows the program's stages on the device ops' clock.
+    The event is named `prefix + stage`; the owner that builds the profiler
+    picks the prefix (`serve.`, `train.`, `embed.`; none = the bare stage
+    name) and the dictionary keys never carry it. Names keep to letters,
+    digits, `_` and `.`. With no profiler session the annotation is inert.
+    `add()` alone (a duration measured elsewhere, e.g. the per-request
+    `queue_wait`) records no event.
     """
 
-    def __init__(self) -> None:
+    def __init__(self, prefix: str = "") -> None:
+        self._prefix = prefix
         self._lock = threading.Lock()
         self._sec: Dict[str, float] = {}
         self._n: Dict[str, int] = {}
@@ -69,11 +89,12 @@ class PipelineProfiler:
 
     @contextlib.contextmanager
     def stage(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add(name, time.perf_counter() - t0)
+        with TraceAnnotation(self._prefix + name):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                self.add(name, time.perf_counter() - t0)
 
     def reset(self) -> None:
         with self._lock:
@@ -159,7 +180,6 @@ def maybe_profile(enabled: bool, workdir: str):
     if not enabled:
         yield
         return
-    import jax
     trace_dir = os.path.join(workdir, "trace")
     os.makedirs(trace_dir, exist_ok=True)
     with jax.profiler.trace(trace_dir):

@@ -3,7 +3,10 @@ smoke-only; nothing asserted a trace appears) — plus the LatencyStats /
 PipelineProfiler contracts the observability PR leans on: bounded-memory
 reservoir with nearest-rank percentile semantics stable across the change,
 and per-stage call counts next to the cumulative seconds."""
+import glob
 import os
+import threading
+import time
 
 import pytest
 
@@ -109,3 +112,162 @@ def test_pipeline_profiler_summary_emits_counts_next_to_seconds():
     assert s["stage_h2d_n"] == 1
     assert list(s) == ["stage_h2d_s", "stage_h2d_n",
                        "stage_tokenize_s", "stage_tokenize_n"]
+
+
+def _host_events(trace_dir):
+    """{event name: [(thread line's index, duration_ns)]} over the host
+    planes of the one .xplane.pb under `trace_dir`, read with jax alone
+    (a line's name is the OS thread's, the same for every Python thread)."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            for ev in line.events:
+                out.setdefault(ev.name, []).append((i, ev.duration_ns))
+    return out
+
+
+def test_stage_is_an_event_on_the_profilers_clock(tmp_path):
+    """Every stage() is also a TraceAnnotation: under a profiler session
+    the event is in the trace under `prefix + stage`, on the thread that
+    ran it, as long as the seconds the profiler summed for it — while the
+    dictionary keys stay bare and add() alone records no event."""
+    prof = PipelineProfiler(prefix="serve.")
+    bare = PipelineProfiler()
+
+    def on_other_thread():
+        with prof.stage("merge"):
+            time.sleep(0.03)
+
+    with maybe_profile(True, str(tmp_path)):
+        for _ in range(3):
+            with prof.stage("topk"):
+                time.sleep(0.02)
+        t = threading.Thread(target=on_other_thread, name="other")
+        t.start()
+        t.join(timeout=30)
+        assert not t.is_alive()
+        with bare.stage("h2d"):
+            time.sleep(0.01)
+        prof.add("queue_wait", 0.5)
+    ev = _host_events(os.path.join(str(tmp_path), "trace"))
+    assert len(ev["serve.topk"]) == 3 and len(ev["serve.merge"]) == 1
+    assert len(ev["h2d"]) == 1                   # no prefix: the bare name
+    for name, p in (("topk", prof), ("merge", prof), ("h2d", bare)):
+        event = p._prefix + name
+        traced_s = sum(d for _, d in ev[event]) / 1e9
+        assert traced_s == pytest.approx(p.stages()[name], rel=0.05), event
+    assert {ln for ln, _ in ev["serve.merge"]}.isdisjoint(
+        ln for ln, _ in ev["serve.topk"])        # its own thread's line
+    assert not {"serve.queue_wait", "queue_wait", "topk"} & set(ev)
+    assert set(prof.stages()) == {"topk", "merge", "queue_wait"}
+    assert prof.counts() == {"topk": 3, "merge": 1, "queue_wait": 1}
+
+
+def _lowered_text(fn, *args):
+    return fn.lower(*args).as_text(debug_info=True)
+
+
+def test_named_scopes_reach_the_lowered_step_and_scan(tmp_path):
+    """Where Flax gives no module path the program names the region itself:
+    `loss` and `optimizer` in the train step, `sharded_topk.scan` and
+    `sharded_topk.local_topk` in the scan — found in the op metadata of
+    the programs lowered on the CPU, which is what a --profile trace shows
+    per device op. The compiled programs keep their names."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from dnn_page_vectors_tpu.ops import topk
+    from dnn_page_vectors_tpu.train.loop import make_train_step
+    cfg = get_config("cdssm_toy", {
+        "data.num_pages": 64, "data.trigram_buckets": 512,
+        "model.embed_dim": 16, "model.conv_channels": 16,
+        "model.out_dim": 16, "train.batch_size": 16,
+    })
+    trainer = Trainer(cfg, workdir=str(tmp_path))
+    state = trainer.init_state()
+    extra = trainer._tok_extra()
+    batch = {"query": jnp.zeros((16, cfg.data.query_len) + extra, jnp.int32),
+             "page": jnp.zeros((16, cfg.data.page_len) + extra, jnp.int32)}
+    step = jax.jit(make_train_step(trainer.model, trainer.tx))
+    text = _lowered_text(step, state, batch, trainer.base_rng())
+    assert "jit_train_step" in text
+    # under value_and_grad the forward ops read jvp(loss), the backward
+    # ones transpose(jvp(loss))
+    for scope in ("jit(train_step)/jvp(loss)/",
+                  "jit(train_step)/transpose(jvp(loss))/",
+                  "jit(train_step)/optimizer/"):
+        assert scope in text, scope
+
+    mesh = Mesh(np.array(jax.devices()[:2]).reshape(2, 1, 1),
+                ("data", "model", "seq"))
+    scan = topk._build_sharded_topk(mesh, 5, 64, False)
+    text = _lowered_text(scan, jnp.zeros((4, 16)),
+                         jnp.zeros((256, 16), jnp.float16), jnp.int32(256))
+    assert "jit__lambda" in text     # trace_modules.scan finds it by this
+    for scope in ("sharded_topk.scan/", "sharded_topk.local_topk/"):
+        assert scope in text, scope
+
+
+def test_flash_kernels_carry_their_names():
+    """Each pallas_call of ops/flash_attention.py has a `name=`: forward,
+    dq (no bias), dq + dbias, and dk/dv — read off the traced program."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from dnn_page_vectors_tpu.ops.flash_attention import flash_attention
+    q = jnp.zeros((1, 2, 128, 32))
+    mask = jnp.ones((1, 128), bool)
+
+    def plain(q, k, v):
+        return flash_attention(q, k, v, mask, interpret=True).sum()
+
+    def biased(q, k, v, b):
+        return flash_attention(q, k, v, mask, bias=b, interpret=True).sum()
+
+    def names(jaxpr):
+        return set(re.findall(r"flash_\w+", str(jaxpr)))
+
+    assert names(jax.make_jaxpr(jax.grad(plain, argnums=(0, 1, 2)))(
+        q, q, q)) == {"flash_fwd", "flash_dq", "flash_dkv"}
+    assert names(jax.make_jaxpr(jax.grad(biased, argnums=(0, 3)))(
+        q, q, q, jnp.zeros((2, 128, 128)))) == {
+            "flash_fwd", "flash_dq_dbias", "flash_dkv"}
+
+
+def test_train_marks_its_steps_and_logs_rates_without_the_compile(tmp_path):
+    """Trainer.train under --profile: one `train` step mark per iteration
+    and the loop's own stages as `train.<stage>` events; the logged
+    pages_per_sec_per_chip covers the steps since the barrier behind the
+    first one, so the compile (most of this toy run's wall time) is not in
+    it."""
+    import json
+    cfg = get_config("cdssm_toy", {
+        "data.num_pages": 64, "data.trigram_buckets": 512,
+        "model.embed_dim": 16, "model.conv_channels": 16,
+        "model.out_dim": 16,
+        "train.batch_size": 16, "train.log_every": 4,
+    })
+    trainer = Trainer(cfg, workdir=str(tmp_path))
+    state = trainer.init_state()
+    t0 = time.perf_counter()
+    with maybe_profile(True, str(tmp_path)):
+        _, last = trainer.train(steps=4, state=state)
+    wall = time.perf_counter() - t0
+    ev = _host_events(os.path.join(str(tmp_path), "trace"))
+    assert len(ev["train"]) == 4
+    assert len(ev["train.compute"]) == 4 and len(ev["train.sync"]) == 1
+    # 3 of the 4 steps lie behind the barrier; had the compile been inside
+    # the interval the rate would be under 4 steps' pages over the wall
+    since_t0 = 4 * 16 / wall / trainer.mesh.devices.size
+    assert last["pages_per_sec_per_chip"] > 3 * since_t0, (last, wall)
+    with open(os.path.join(str(tmp_path), "metrics.jsonl")) as f:
+        assert json.loads(f.readlines()[-1])["step"] == 4

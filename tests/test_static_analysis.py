@@ -739,6 +739,22 @@ def test_drift_rules_mini_project(tmp_path):
         assert quiet not in knob_msgs + ev_msgs + mk_msgs
 
 
+def test_drift_knobs_exempts_profiler_stage_events(tmp_path):
+    """`serve.topk` in a doc is the profiler's trace event (the prefix a
+    PipelineProfiler is built with + a stage name), not a knob; a
+    `serve.<word>` that is neither knob, instrument nor stage still is."""
+    root = _mini_project(str(tmp_path), clean=True)
+    with open(os.path.join(root, "dnn_page_vectors_tpu", "svc.py"), "w") as f:
+        f.write('prof = PipelineProfiler(prefix="serve.")\n'
+                'with prof.stage("topk"):\n    pass\n'
+                'with self._stage("merge", shards=3):\n    pass\n')
+    doc = os.path.join(root, "docs", "OBSERVABILITY.md")
+    with open(doc, "a") as f:
+        f.write("Events: `serve.topk`, `serve.merge`; not `serve.nostage`.\n")
+    msgs = [f.msg for f in analyze(root=root).findings]
+    assert len(msgs) == 1 and "serve.nostage" in msgs[0], msgs
+
+
 def test_drift_rules_clean_mini_project(tmp_path):
     root = _mini_project(str(tmp_path), clean=True)
     r = analyze(root=root)

@@ -506,3 +506,89 @@ def test_disabled_tracing_serves_identically(served):
     assert [r["page_id"] for r in got] == [r["page_id"] for r in want]
     assert off.tracer.traces() == [] and off.tracer.slow_queries() == []
     assert off.metrics()["serve_window_qps"] > 0   # metrics still live
+
+
+# ---------------------------------------------------------------------------
+# the dispatcher thread's spans (PipelineProfiler stages, serve.* events)
+# ---------------------------------------------------------------------------
+
+_CHILD_STAGES = ("tokenize", "encode", "topk", "merge", "format")
+
+
+def _search_through_batcher(svc, trainer, n=24, clients=8):
+    from concurrent.futures import ThreadPoolExecutor
+    with ThreadPoolExecutor(clients) as pool:
+        return list(pool.map(
+            lambda i: svc.search(trainer.corpus.query_text(i), k=5),
+            range(n)))
+
+
+def test_dispatcher_thread_spans_split_its_time(served):
+    """batcher_idle / batch_window / dispatch are stages of the service's
+    profiler, timed on the serve-batcher thread alone: one `dispatch` per
+    batch that passed the door and parent of the stages under it, so the
+    three never sum past the wall clock and the children never past
+    `dispatch`; a batch shed whole at the door adds none."""
+    import time
+    from concurrent.futures import Future
+
+    from dnn_page_vectors_tpu.infer.transport import DeadlineExceeded
+    _, trainer, _, _ = served
+    svc = _svc(served, preload=4.0, serve={"batch_window_ms": 5.0})
+    t0 = time.perf_counter()
+    svc.start_batcher()
+    b = svc._batcher
+    try:
+        res = _search_through_batcher(svc, trainer)
+        n_dispatch = svc.profiler.counts()["dispatch"]
+        shed: Future = Future()
+        b._dispatch([("q", (5, None, None), shed, time.perf_counter(), None,
+                      svc._clock() - 1.0)])
+        with pytest.raises(DeadlineExceeded):
+            shed.result(timeout=5)
+        assert svc.profiler.counts()["dispatch"] == n_dispatch
+    finally:
+        svc.close()                       # joins the thread: spans closed
+    wall = time.perf_counter() - t0
+    assert all(res)
+    sec, n = svc.profiler.stages(), svc.profiler.counts()
+    assert n["dispatch"] == len(b.batch_sizes) > 0
+    assert sum(b.batch_sizes) == n["queue_wait"] == len(res)
+    assert sec["batcher_idle"] > 0 and n["batch_window"] == n["dispatch"]
+    assert sum(sec[c] for c in _CHILD_STAGES) <= sec["dispatch"]
+    assert (sec["batcher_idle"] + sec["batch_window"] + sec["dispatch"]
+            <= wall)
+
+
+def test_stage_sums_accumulate_with_every_tracer_off(served):
+    """No jax profiler session and obs.enabled=false: the annotations are
+    inert, the request tracer yields NULL_SPAN, and a search through the
+    batcher still answers with every stage sum accumulated."""
+    _, trainer, _, _ = served
+    svc = _svc(served, preload=4.0, obs={"enabled": False})
+    svc.start_batcher()
+    try:
+        res = _search_through_batcher(svc, trainer, n=6, clients=3)
+    finally:
+        svc.close()
+    assert all(res) and svc.tracer.traces() == []
+    sec = svc.profiler.stages()
+    for name in _CHILD_STAGES + ("dispatch", "batch_window", "batcher_idle",
+                                 "queue_wait"):
+        assert sec.get(name, 0.0) > 0.0, (name, sec)
+
+
+def test_device_merge_carries_its_scope_and_keeps_its_name(served):
+    """The cross-shard merge's ops are named `merge/...` in the lowered
+    program, whose own name stays `jit_merge` (trace_modules.merge)."""
+    import jax.numpy as jnp
+    svc = _svc(served, preload=4.0)
+    try:
+        view = svc._view
+        cands = [(jnp.zeros((4, 5)), jnp.zeros((4, 5), jnp.int32))
+                 for _ in view.shards]
+        text = view.merge.lower(cands).as_text(debug_info=True)
+    finally:
+        svc.close()
+    assert len(view.shards) == 3
+    assert "jit_merge" in text and "jit(merge)/merge/" in text
