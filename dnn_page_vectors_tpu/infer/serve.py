@@ -117,7 +117,7 @@ import threading
 import time
 from collections import OrderedDict
 from concurrent.futures import Future
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -125,7 +125,7 @@ from dnn_page_vectors_tpu.infer.bulk_embed import BulkEmbedder
 from dnn_page_vectors_tpu.infer.transport import DeadlineExceeded
 from dnn_page_vectors_tpu.infer.vector_store import VectorStore, read_ahead
 from dnn_page_vectors_tpu.ops.topk import (
-    merge_shard_topk, sharded_topk, stage_shard, topk_over_store)
+    merge_shard_topk, sharded_topk_fn, stage_shard, topk_over_store)
 from dnn_page_vectors_tpu.utils import faults
 from dnn_page_vectors_tpu.utils.profiling import LatencyStats, PipelineProfiler
 from dnn_page_vectors_tpu.utils.telemetry import MetricsRegistry
@@ -402,6 +402,18 @@ def _merge_topk_host(s1, i1, s2, i2, k: int):
             np.take_along_axis(i, order, axis=1))
 
 
+class _Shard(NamedTuple):
+    """One store shard resident on the device, with everything a launch of
+    the scan over it takes (_stage_view makes it, _dispatch_bucket reads
+    it): a refresh hands a shard whose bytes are unchanged on whole, or
+    with newer tombstones masked in `ids` alone."""
+    ids: np.ndarray        # [n] int64 page ids, -1 = tombstoned since staged
+    n: int                 # rows of `pages` that hold a vector
+    pages: object          # [pad_rows, D] device rows at the stored width
+    scales: object         # [pad_rows] device fp16 scales (int8 store) | None
+    valid: object          # `n` as an int32 scalar replicated on the device
+
+
 class _ServeView:
     """One atomic serving snapshot (docs/UPDATES.md): everything
     search_many touches that a refresh() can change — the store handle
@@ -441,7 +453,7 @@ class _ServeView:
         # store-wide even for a restricted view so every partition splits a
         # stacked query matrix on the same block order
         self.steps: List[int] = store.model_steps()
-        self.shards = None   # [(ids np[int64], n, pages [R, D], scl|None)]
+        self.shards: Optional[List[_Shard]] = None
         self.shard_keys: List[tuple] = []
         self.shard_steps: List[Optional[int]] = []   # stamp per staged shard
         self.stream_entries: List[Dict] = []
@@ -1329,6 +1341,7 @@ class SearchService:
         import jax
         import jax.numpy as jnp
         from jax import lax
+        from jax.sharding import NamedSharding, PartitionSpec as P
 
         plan = faults.active()
         store = view.store
@@ -1342,6 +1355,19 @@ class SearchService:
                 and reuse.pad_rows == rows):
             reuse_map = {key: tup for key, tup
                          in zip(reuse.shard_keys, reuse.shards)}
+        # the scan's `valid` argument, made HERE and kept with the shard: an
+        # int32 scalar replicated over the mesh, one per distinct row count
+        # (a reused shard brings its own along), so that a bucket's launch
+        # loop (_dispatch_bucket) converts and transfers nothing per shard
+        replicated = NamedSharding(self.embedder.mesh, P())
+        valid_of = {shard.n: shard.valid for shard in reuse_map.values()}
+
+        def device_count(n: int):
+            v = valid_of.get(n)
+            if v is None:
+                v = valid_of[n] = jax.device_put(np.int32(n), replicated)
+            return v
+
         staged, keys, stamps = [], [], []
         used = 0.0
         per_shard = rows * per_row / self._n_data
@@ -1357,14 +1383,14 @@ class SearchService:
             try:
                 hit = reuse_map.get(key)
                 if hit is not None:
-                    old_ids, old_n, pages, scl = hit
+                    old_ids, old_n = hit.ids, hit.n
                     ids = store.load_ids(entry)
                     live = np.asarray(ids[ids >= 0], np.int64)
                     alive_old = old_ids[old_ids >= 0]
                     if np.array_equal(live, alive_old):
                         # staged block current (modulo rows already masked
                         # by an earlier skip): plain reuse
-                        staged.append((old_ids, old_n, pages, scl))
+                        staged.append(hit)
                         keys.append(key)
                         stamps.append(estep)
                         used += per_shard
@@ -1381,7 +1407,7 @@ class SearchService:
                     if dead_frac <= self._restage_density:
                         masked = np.where(np.isin(old_ids, live),
                                           old_ids, np.int64(-1))
-                        staged.append((masked, old_n, pages, scl))
+                        staged.append(hit._replace(ids=masked))
                         keys.append(key)
                         stamps.append(estep)
                         used += per_shard
@@ -1417,9 +1443,11 @@ class SearchService:
                     ids = ids[keep]
                     vecs = np.asarray(vecs)[keep]
                     scl = None if scl is None else np.asarray(scl)[keep]
-                staged.append((ids, int(ids.shape[0]),
-                               *stage_shard(vecs, rows, store.dim,
-                                            self.embedder.mesh, scales=scl)))
+                n = int(ids.shape[0])
+                staged.append(_Shard(
+                    ids, n, *stage_shard(vecs, rows, store.dim,
+                                         self.embedder.mesh, scales=scl),
+                    device_count(n)))
                 keys.append(key)
                 stamps.append(estep)
                 used += per_shard
@@ -1447,8 +1475,8 @@ class SearchService:
         # combined-id -> page-id table for the device-side merge below:
         # shard slot s, padded row r  ->  slot s * rows + r
         view.pid_table = np.full((len(staged) * rows,), -1, np.int64)
-        for slot, (sids, n, _, _) in enumerate(staged):
-            view.pid_table[slot * rows: slot * rows + n] = sids
+        for slot, shard in enumerate(staged):
+            view.pid_table[slot * rows: slot * rows + shard.n] = shard.ids
         if reuse is not None and reuse.merge is not None \
                 and reuse.pad_rows == rows:
             # the merge program depends only on pad_rows (and retraces per
@@ -2406,7 +2434,7 @@ class SearchService:
             bs, bi = self._collect_bucket(view, nreal, qs, packed, k)
             out_s[s0: s0 + nreal] = bs[:nreal]
             out_i[s0: s0 + nreal] = bi[:nreal]
-        scan = (sum(nv for _, nv, _, _ in view.shards)
+        scan = (sum(shard.n for shard in view.shards)
                 + sum(e["count"] for e in view.stream_entries)) * row_bytes
         return out_s, out_i, scan
 
@@ -2519,7 +2547,16 @@ class SearchService:
         each shard is scored by the block matching its recorded stamp, so
         a mid-migration bucket runs the same one merged dispatch — the
         dual-stamp routing costs one extra h2d put per extra stamp, not a
-        second sweep."""
+        second sweep.
+
+        The launch loop holds nothing but the launches: the jitted scan is
+        resolved once per bucket, and each shard's arguments were made
+        when the view was staged (_stage_view) — `pages` and `scales`
+        padded to `pad_rows`, which _build_view keeps a multiple of the
+        mesh's 'data' axis, and `valid`, the shard's row count as an
+        int32 scalar already on the device. Once the query blocks are up,
+        a bucket moves nothing from host to device and runs one program
+        per shard plus the merge."""
         import jax.numpy as jnp
 
         nreal = next(iter(qblocks.values())).shape[0]
@@ -2536,11 +2573,18 @@ class SearchService:
                                   rows=view.pad_rows,
                                   shards=len(view.shards))
         with self._stage("topk", shards=len(view.shards)):
-            cands = [
-                sharded_topk(qs.get(st, fallback), pages,
-                             self.embedder.mesh, k=k, valid=n, scales=scl)
-                for st, (_, n, pages, scl) in zip(view.shard_steps,
-                                                  view.shards)]
+            # a store has one dtype: every shard of a view has scales, or
+            # none has
+            scaled = view.shards[0].scales is not None
+            scan = sharded_topk_fn(self.embedder.mesh, k, scaled=scaled)
+            if scaled:
+                cands = [scan(qs.get(st, fallback), pages, scl, valid)
+                         for st, (_, _, pages, scl, valid)
+                         in zip(view.shard_steps, view.shards)]
+            else:
+                cands = [scan(qs.get(st, fallback), pages, valid)
+                         for st, (_, _, pages, _, valid)
+                         in zip(view.shard_steps, view.shards)]
             packed = view.merge(cands)                 # async, on device
         return nreal, qs, packed
 

@@ -165,26 +165,43 @@ def _build_sharded_topk(mesh: Mesh, k: int, chunk: int, scaled: bool):
     return jax.jit(mapped)
 
 
+def sharded_topk_fn(mesh: Mesh, k: int, chunk: int = 8192,
+                    scaled: bool = False):
+    """The jitted scan `sharded_topk` launches, for a caller that resolves
+    it once and then launches it per shard on arguments it already holds
+    on the device (`SearchService._dispatch_bucket`): (q, pages, valid),
+    or (q, pages, scales, valid) when `scaled`. The caller owns what the
+    wrapper checks: pages rows divide mesh 'data', `valid` is an int32
+    scalar."""
+    key = (mesh, int(k), int(chunk), bool(scaled))
+    fn = _SHARDED_CACHE.get(key)
+    if fn is None:
+        fn = _SHARDED_CACHE[key] = _build_sharded_topk(mesh, k, chunk, scaled)
+    return fn
+
+
 def sharded_topk(q: jnp.ndarray, pages, mesh: Mesh, k: int = 10,
-                 chunk: int = 8192, valid: int | None = None, scales=None
-                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                 chunk: int = 8192, valid: int | jax.Array | None = None,
+                 scales=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Top-k with pages [N, D] row-sharded over the mesh 'data' axis.
 
     N must divide by mesh 'data'; rows >= `valid` are padding (score -inf,
     index -1). q is replicated. Returns replicated (scores, indices) with
     indices global into the sharded row order. `pages` may be fp16 rows or
     int8 codes with per-row `scales` [N] — widened on-device (_topk_scan).
-    """
-    key = (mesh, int(k), int(chunk), scales is not None)
-    fn = _SHARDED_CACHE.get(key)
-    if fn is None:
-        fn = _SHARDED_CACHE[key] = _build_sharded_topk(
-            mesh, k, chunk, scales is not None)
+
+    `valid` is a Python int, made into a device scalar HERE (one small
+    program per call: fine for a sweep that also stages a shard per call),
+    or an int32 scalar already on the device, passed through as it is (the
+    serving view makes one per distinct count when it is staged,
+    `SearchService._stage_view`)."""
+    fn = sharded_topk_fn(mesh, k, chunk, scales is not None)
     N = pages.shape[0]
     if N % mesh.shape["data"]:
         raise ValueError(f"pages rows {N} must divide mesh data axis "
                          f"{mesh.shape['data']}; pad the input")
-    v = jnp.int32(N if valid is None else valid)
+    v = valid if isinstance(valid, jax.Array) else jnp.int32(
+        N if valid is None else valid)
     return fn(q, pages, v) if scales is None else fn(q, pages, scales, v)
 
 

@@ -29,3 +29,40 @@ def eight_devices():
     devs = jax.devices()
     assert len(devs) >= 8, f"expected 8 fake CPU devices, got {len(devs)}"
     return devs
+
+
+@pytest.fixture
+def launches(tmp_path):
+    """`with launches() as seen:` records a profiler trace around its body
+    and, once the body is done, fills `seen` from the host tracer's events:
+    `programs`, how many compiled programs the CPU client was handed (one
+    `PjRtCpuExecutable::Execute` per launch, whatever the mesh), and
+    `jitted`, the names of the jitted functions that were called
+    (`PjitFunction(<name>)`): what the chip's trace counts per XLA module."""
+    import contextlib
+    import glob
+    import re
+    import tempfile
+
+    @contextlib.contextmanager
+    def record():
+        import jax
+        from jax.profiler import ProfileData
+        seen = {"programs": 0, "jitted": set()}
+        trace_dir = tempfile.mkdtemp(prefix="launches_", dir=tmp_path)
+        with jax.profiler.trace(trace_dir):
+            yield seen
+        path, = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                       "*.xplane.pb"))
+        for plane in ProfileData.from_file(path).planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name == "PjRtCpuExecutable::Execute":
+                        seen["programs"] += 1
+                    m = re.fullmatch(r"PjitFunction\((.*)\)", ev.name)
+                    if m:
+                        seen["jitted"].add(m.group(1))
+
+    return record

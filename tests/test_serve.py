@@ -4,6 +4,7 @@ different algorithm), and the interactive CLI must answer a stdin stream."""
 import io
 import json
 import os
+import zlib
 
 import numpy as np
 import pytest
@@ -158,3 +159,100 @@ def test_preloaded_int8_store_matches_streaming(tmp_path):
                                    [r["score"] for r in b], atol=1e-4)
         hits += qi in [r["page_id"] for r in a]
     assert hits >= 2
+
+
+# -- the resident scan's launch loop ---------------------------------------
+
+_D, _ROWS = 24, (40, 40, 23)     # three shards, an uneven last one
+
+
+def _unit_rows(seed, n):
+    v = np.random.default_rng(seed).standard_normal((n, _D)).astype(
+        np.float32)
+    return v / np.linalg.norm(v, axis=1, keepdims=True)
+
+
+class _TextTower:
+    """A page tower for MigrationPlan: a page's vector at `step`."""
+
+    def __init__(self, step):
+        self.step = step
+
+    def embed_texts(self, texts, tower="page", batch_size=None):
+        return np.stack([
+            _unit_rows(zlib.crc32(f"{self.step}|{t}".encode()), 1)[0]
+            for t in texts])
+
+
+class _Pages:
+    def page_text(self, i):
+        return f"page {int(i)}"
+
+
+def _resident_view(sdir, mesh, case):
+    """A service over 40 + 40 + 23 rows resident in three shards: float16
+    rows, int8 codes with scales, or two model stamps (the base migrated
+    to step 2, the appended generation still at step 1)."""
+    from dnn_page_vectors_tpu.infer.partition_host import MeshEmbedder
+    from dnn_page_vectors_tpu.maintenance.migrate import MigrationPlan
+    store = VectorStore(sdir, dim=_D, shard_size=_ROWS[0],
+                        dtype="int8" if case == "int8" else "float16")
+    store.ensure_model_step(1)
+    for si, n in enumerate(_ROWS[:2]):
+        store.write_shard(si, np.arange(si * 40, si * 40 + n),
+                          _unit_rows(si, n))
+    w = VectorStore(sdir).begin_generation()
+    w.write_shard(np.arange(80, 80 + _ROWS[2]), _unit_rows(2, _ROWS[2]))
+    w.commit()
+    svc = SearchService(get_config("cdssm_toy", {"model.out_dim": _D}),
+                        MeshEmbedder(mesh), _Pages(), VectorStore(sdir),
+                        preload_hbm_gb=4.0)
+    if case == "two_stamp":
+        svc.begin_migration(("tower", 2), 2)
+        plan = MigrationPlan(VectorStore(sdir), _Pages(), _TextTower(2), 2)
+        plan.begin()
+        plan.migrate_unit(0)
+        svc.refresh()
+    return svc
+
+
+@pytest.mark.parametrize("case", ["float16", "int8", "two_stamp"])
+def test_bucket_is_one_launch_per_shard_and_moves_nothing(
+        tmp_path, launches, case):
+    """Everything a shard's launch needs was made when the view was staged:
+    a warmed bucket runs with host-to-device transfers disallowed (only the
+    query block's explicit put goes up) and launches one scan per shard and
+    one merge, no other program; the answers are the streaming sweep's."""
+    import jax
+    from jax.sharding import Mesh
+
+    from dnn_page_vectors_tpu.ops.topk import merge_topk_host, topk_over_store
+    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+    svc = _resident_view(str(tmp_path / "store"), mesh, case)
+    view, k = svc._view, 7
+    assert [shard.n for shard in view.shards] == list(_ROWS)
+    assert [int(shard.valid) for shard in view.shards] == list(_ROWS)
+    assert view.shards[0].valid is view.shards[1].valid   # one per count
+    stamps = sorted(set(view.shard_steps))
+    assert stamps == ([1, 2] if case == "two_stamp" else [1])
+    qv = np.concatenate([_unit_rows(100 + s, 5) for s in stamps], axis=1)
+    blocks = svc._qv_blocks(view, qv)
+    assert sorted(blocks) == stamps
+    svc._collect_bucket(view, *svc._dispatch_bucket(view, blocks, k), k)
+    with launches() as seen, jax.transfer_guard_host_to_device("disallow"):
+        bucket = svc._dispatch_bucket(view, blocks, k)
+    got_s, got_i = svc._collect_bucket(view, *bucket, k)
+    assert seen["programs"] == len(view.shards) + 1
+    assert seen["jitted"] == {"run" if case == "int8" else "<lambda>",
+                              "merge"}
+    want_s = np.full((5, k), -np.inf, np.float32)
+    want_i = np.full((5, k), -1, np.int64)
+    for st in stamps:
+        s, i = topk_over_store(
+            blocks[st], view.store, mesh, k=k,
+            entries=[e for e in view.entries
+                     if view.store.entry_step(e) == st])
+        want_s, want_i = merge_topk_host(want_s, want_i, s, i)
+    np.testing.assert_array_equal(got_i, want_i)
+    np.testing.assert_allclose(got_s, want_s, atol=1e-6)
+    svc.close()

@@ -52,6 +52,36 @@ def test_sharded_topk_matches_single_device(eight_devices):
     assert (np.asarray(iv) < 200).all()
 
 
+@pytest.mark.parametrize("n_data", [1, 4])
+def test_sharded_topk_takes_valid_as_a_device_scalar(eight_devices, launches,
+                                                     n_data):
+    """`valid` as a Python int and as an int32 scalar already on the
+    device (replicated over the mesh, as the serving view stages it) give
+    the same bits; the device scalar goes to the scan as it is, so the call
+    moves nothing up and launches the scan alone, where the int costs a
+    conversion program first."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    mesh = make_mesh(MeshConfig(data=n_data))
+    rng = np.random.default_rng(3)
+    q = jax.device_put(rng.normal(size=(6, 16)).astype(np.float32),
+                       NamedSharding(mesh, P()))
+    pages = jax.device_put(rng.normal(size=(256, 16)).astype(np.float16),
+                           NamedSharding(mesh, P("data")))
+    on_device = jax.device_put(np.int32(200), NamedSharding(mesh, P()))
+    want = sharded_topk(q, pages, mesh, k=9, chunk=32, valid=200)
+    sharded_topk(q, pages, mesh, k=9, chunk=32, valid=on_device)   # warm
+    with launches() as seen, jax.transfer_guard_host_to_device("disallow"):
+        got = sharded_topk(q, pages, mesh, k=9, chunk=32, valid=on_device)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    assert (np.asarray(got[1]) < 200).all()
+    assert seen["jitted"] == {"<lambda>"} and seen["programs"] == 1
+    with launches() as seen:
+        sharded_topk(q, pages, mesh, k=9, chunk=32, valid=200)
+    assert seen["jitted"] == {"<lambda>", "convert_element_type"}
+
+
 def test_topk_over_store_matches_brute_force(eight_devices, tmp_path):
     """Streaming the store shard-by-shard over the mesh must equal one giant
     in-memory search — no step materializes the full store."""

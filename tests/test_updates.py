@@ -412,6 +412,49 @@ def test_tombstone_aware_restage_policy(env, tmp_path):
     svc.close()
 
 
+def test_reused_shards_keep_their_device_row_count(env, tmp_path):
+    """The scan's `valid` argument is staged with the shard
+    (SearchService._stage_view): a refresh that appends a generation, and
+    one that only adds tombstones under the restage threshold, hand every
+    reused shard on with the SAME device scalar (and device rows) it had,
+    the appended shard gets its own, and the masked shard keeps the count
+    of the rows its device copy still holds — the tombstoned page never
+    surfaces."""
+    import dataclasses
+    emb, trainer = env["emb"], env["trainer"]
+    store = _copy_store(env, tmp_path)
+    cfg = env["cfg"].replace(updates=dataclasses.replace(
+        env["cfg"].updates, restage_tombstone_density=0.05))
+    svc = SearchService(cfg, emb, trainer.corpus, store, preload_hbm_gb=4.0)
+    base = svc._view.shards
+    assert [int(s.valid) for s in base] == [100, 100, 100]
+    assert base[0].valid is base[1].valid is base[2].valid   # one per count
+
+    append_corpus(emb, _grown(trainer.corpus, 350), store)
+    svc.refresh()
+    grown = svc._view.shards
+    assert len(grown) == 4
+    for old, new in zip(base, grown):
+        assert new.valid is old.valid and new.pages is old.pages
+    assert grown[3].n == 50 and int(grown[3].valid) == 50
+    assert grown[3].valid is not base[0].valid
+    assert grown[3].valid.dtype == np.int32
+
+    dead_vec = _stored_vecs(store, [7, 340])
+    append_corpus(emb, _grown(trainer.corpus, 350), store, tombstone=[7])
+    svc.refresh()
+    assert svc.restage_skipped >= 1 and svc.restage_forced == 0
+    masked = svc._view.shards
+    for old, new in zip(grown, masked):
+        assert new.valid is old.valid and new.pages is old.pages
+    assert masked[0].n == 100 and int(masked[0].valid) == 100
+    assert 7 not in masked[0].ids and 7 in grown[0].ids
+    _, got = svc.topk_vectors(dead_vec, k=10)
+    assert 7 not in got[0].tolist(), "tombstoned row still servable"
+    assert got[1][0] == 340             # the appended shard serves
+    svc.close()
+
+
 def test_quarantine_plus_append_never_double_assigns(env, tmp_path):
     """The no-double-assign contract: a quarantined base shard leaves its
     id-range discoverable (missing_id_ranges), the append cursor skips it,
