@@ -1,0 +1,7 @@
+"""Device time under the name scope `mla` (norm, the five projections, RoPE, flash) as a share of the train step's device time (the XLA module
+the cell's `trace_modules.step` names): forward, recomputation, backward."""
+from benchmarks import trace_scopes
+
+
+def read(ctx):
+    return trace_scopes.step_share(ctx, "mla")

@@ -49,7 +49,8 @@ class DataConfig:
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     """Encoder zoo settings. `encoder` selects the family."""
-    encoder: str = "cdssm"           # cdssm | kim_cnn | lstm | bert | t5
+    # cdssm | kim_cnn | lstm | bert | t5 | glm4_moe_lite
+    encoder: str = "cdssm"
     embed_dim: int = 128             # token/word embedding width
     out_dim: int = 128               # final vector dimension (both towers)
     # conv families
@@ -68,6 +69,28 @@ class ModelConfig:
     attention: str = "dense"
     shared_towers: bool = False      # share params between query/page towers
     dtype: str = "bfloat16"          # compute dtype on MXU
+    # glm4_moe_lite (models/glm_moe.py): the published config's keys under
+    # their published names (mlp_dim is its intermediate_size, the dense
+    # layers' width; num_layers its num_hidden_layers) ...
+    q_lora_rank: int = 768
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 192
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 256
+    moe_intermediate_size: int = 1536
+    n_routed_experts: int = 64
+    num_experts_per_tok: int = 4
+    routed_scaling_factor: float = 1.8
+    first_k_dense_replace: int = 1
+    rope_theta: float = 1e6
+    rms_norm_eps: float = 1e-5
+    # ... and the share of the routed experts THIS chip holds: a contiguous
+    # range of `experts_held` experts from `experts_held_start`. The router
+    # always scores all n_routed_experts; 0 held = all of them.
+    experts_held: int = 0
+    experts_held_start: int = 0
+    # recompute each block's activations in the backward pass
+    remat_blocks: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -682,6 +705,29 @@ def bert_long_sp() -> Config:
     )
 
 
+def glm47_flash_ep8() -> Config:
+    """GLM-4.7-Flash (zai-org, `glm4_moe_lite`) as ONE shared tower, cut to
+    one chip's share of a layer that 8 chips hold together (expert parallel:
+    8 of the 64 routed experts here; attention, router and shared expert
+    replicated; an eighth of the 154,880 embedding rows): 1 dense + 4 expert
+    layers of the published 47, every width as published. Pages of 1,024
+    tokens through causal flash attention, last-token pool, per-block
+    recomputation (benchmarks/configs/glm47_flash_ep8.json states the cut)."""
+    return Config(
+        name="glm47_flash_ep8",
+        data=DataConfig(tokenizer="wordpiece", corpus="toy",
+                        num_pages=10_000_000, vocab_size=19_360,
+                        page_len=1024, query_len=32),
+        model=ModelConfig(encoder="glm4_moe_lite", num_layers=5,
+                          num_heads=20, model_dim=2048, mlp_dim=10_240,
+                          out_dim=2048, attention="flash", dropout=0.0,
+                          shared_towers=True, experts_held=8,
+                          experts_held_start=0, remat_blocks=True),
+        mesh=MeshConfig(data=1),
+        train=TrainConfig(batch_size=32, steps=100_000, learning_rate=1e-4),
+    )
+
+
 CONFIGS = {
     "cdssm_toy": cdssm_toy,
     "kim_cnn_v5e8": kim_cnn_v5e8,
@@ -690,6 +736,7 @@ CONFIGS = {
     "hardneg_v5p64": hardneg_v5p64,
     "mt5_multilingual": mt5_multilingual,
     "bert_long_sp": bert_long_sp,
+    "glm47_flash_ep8": glm47_flash_ep8,
 }
 
 

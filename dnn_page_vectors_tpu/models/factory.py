@@ -8,6 +8,7 @@ import jax.numpy as jnp
 
 from dnn_page_vectors_tpu.config import Config
 from dnn_page_vectors_tpu.models.cdssm import CdssmEncoder
+from dnn_page_vectors_tpu.models.glm_moe import GlmMoeEncoder, GlmSizes
 from dnn_page_vectors_tpu.models.kim_cnn import KimCnnEncoder
 from dnn_page_vectors_tpu.models.lstm import LstmEncoder
 from dnn_page_vectors_tpu.models.transformer import TransformerEncoder
@@ -49,6 +50,25 @@ def _build_encoder(cfg: Config, vocab_size: int, name: str,
                                   attention_kind=m.attention,
                                   mesh=mesh if m.attention == "ring" else None,
                                   dtype=dtype, name=name)
+    if m.encoder == "glm4_moe_lite":
+        sizes = GlmSizes(
+            num_heads=m.num_heads, model_dim=m.model_dim, mlp_dim=m.mlp_dim,
+            moe_mlp_dim=m.moe_intermediate_size, q_lora_rank=m.q_lora_rank,
+            kv_lora_rank=m.kv_lora_rank,
+            qk_nope_head_dim=m.qk_nope_head_dim,
+            qk_rope_head_dim=m.qk_rope_head_dim, v_head_dim=m.v_head_dim,
+            n_routed_experts=m.n_routed_experts,
+            num_experts_per_tok=m.num_experts_per_tok,
+            routed_scaling_factor=m.routed_scaling_factor,
+            first_k_dense_replace=m.first_k_dense_replace,
+            experts_held=m.experts_held or m.n_routed_experts,
+            experts_held_start=m.experts_held_start,
+            rope_theta=m.rope_theta, norm_eps=m.rms_norm_eps)
+        return GlmMoeEncoder(vocab_size=vocab_size, sizes=sizes,
+                             num_layers=m.num_layers, out_dim=m.out_dim,
+                             dropout=m.dropout, remat=m.remat_blocks,
+                             attention_kind=m.attention, dtype=dtype,
+                             name=name)
     raise ValueError(f"unknown encoder {cfg.model.encoder!r}")
 
 

@@ -1,0 +1,362 @@
+"""GLM-4.7-Flash (`glm4_moe_lite`) as an embedding tower: latent attention
+(MLA), a dense SwiGLU first layer, then routed-expert layers with one shared
+expert, causal, RoPE, RMSNorm, no biases; the hidden state of the last
+non-pad token is projected to the page/query vector.
+
+The expert layer is told WHICH experts it holds (`experts_held` of the
+published `n_routed_experts`, a contiguous range from `experts_held_start`):
+it routes every token over all the published experts, computes the part of
+the result that its own experts give, and leaves out what the absent ones
+would add. With all experts held that is the whole layer; with a share it is
+one expert-parallel rank's part, before the exchange that sums the ranks
+(parallel/sharding.py has the expert axis's rule; the exchange itself is not
+built, and nothing stands in for absent chips).
+
+Per block (h the input, all norms RMSNorm with a learned scale; the two
+halves are modules `layers/block<i>_mix` and `layers/block<i>_ffn`):
+    x = h + MLA(norm(h));  y = x + FFN(norm(x))
+MLA:  cq = norm(h Wdq); q = cq Wuq -> heads of [nope | rope];
+      [ckv | k_rope] = h Wdkv; ckv = norm(ckv); ckv Wukv -> heads of
+      [k_nope | v]; RoPE on q_rope and the one shared k_rope (half-split
+      pairing: dim i rotates with dim i + rope/2); k = [k_nope | k_rope];
+      softmax(q k^T / sqrt(nope + rope) + causal + pad) v; Wo.
+FFN, dense layers: (silu(u Wg) * (u Wu)) Wd.
+FFN, expert layers: s = sigmoid(u Wr) in float32; S = top-k of s + b;
+      w_i = scale * s_i / (sum_{j in S} s_j + 1e-20);
+      E_shared(u) + sum_{i in S, i held} w_i E_i(u). b selects and never
+      weighs, and takes no gradient. No token is dropped.
+
+Device-side scopes (docs/OBSERVABILITY.md): `mla`, `mla.flash`, `moe`,
+`moe.router`, `moe.dispatch`, `moe.experts`, `moe.shared`, `moe.combine`.
+Counters are sown into the `moe_stats` collection by the tower, once per
+call: `held` [expert layers, experts_held] assignments per held expert,
+`absent` and `dropped` [expert layers].
+"""
+from __future__ import annotations
+
+import dataclasses
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from dnn_page_vectors_tpu.models.transformer import RmsNorm
+from dnn_page_vectors_tpu.ops import grouped_matmul as gm
+
+STATS = "moe_stats"          # the counters' collection
+_EXPERT_TILE = 256           # rows per tile of the grouped product
+_ROW_GROUP_TOKENS = 4096     # tokens in a group of rows (GlmMoeEncoder)
+_FLASH_BLOCK = 512           # square tile of the causal flash kernels
+
+
+@dataclasses.dataclass(frozen=True)
+class GlmSizes:
+    """The published keys of a `glm4_moe_lite` config that shape a block,
+    and the share of the routed experts held here."""
+    num_heads: int
+    model_dim: int
+    mlp_dim: int                  # the dense layers' width
+    moe_mlp_dim: int              # every expert's width
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    n_routed_experts: int
+    num_experts_per_tok: int
+    routed_scaling_factor: float
+    first_k_dense_replace: int
+    experts_held: int
+    experts_held_start: int = 0
+    rope_theta: float = 1e6
+    norm_eps: float = 1e-5
+
+
+def rope(x: jnp.ndarray, theta: float) -> jnp.ndarray:
+    """Rotary positions over ALL of x's last dim, positions 0..L-1 along
+    axis 1 of [B, L, H, R]; half-split pairing (i with i + R/2)."""
+    half = x.shape[-1] // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv[None, :]
+    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+class SwiGlu(nn.Module):
+    mlp_dim: int
+    model_dim: int
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=self.dtype,
+                                         name=name)
+        h = nn.silu(dense(self.mlp_dim, "wi_0")(x)) \
+            * dense(self.mlp_dim, "wi_1")(x)
+        return dense(self.model_dim, "wo_mlp")(h)
+
+
+class MlaAttention(nn.Module):
+    num_heads: int
+    model_dim: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float
+    norm_eps: float
+    dtype: jnp.dtype = jnp.bfloat16
+    kind: str = "flash"           # flash | dense
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray, pad_mask: jnp.ndarray) -> jnp.ndarray:
+        B, L, _ = x.shape
+        H, nope, rp, vd = (self.num_heads, self.qk_nope_head_dim,
+                           self.qk_rope_head_dim, self.v_head_dim)
+        dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=self.dtype,
+                                         name=name)
+        norm = lambda name: RmsNorm(dtype=self.dtype, eps=self.norm_eps,
+                                    name=name)
+        cq = norm("q_norm")(dense(self.q_lora_rank, "wq_a")(x))
+        q = dense(H * (nope + rp), "wq_b")(cq).reshape(B, L, H, nope + rp)
+        kv = dense(self.kv_lora_rank + rp, "wkv_a")(x)
+        ckv = norm("kv_norm")(kv[..., :self.kv_lora_rank])
+        k_rope = rope(kv[..., None, self.kv_lora_rank:], self.rope_theta)
+        kv = dense(H * (nope + vd), "wkv_b")(ckv).reshape(B, L, H, nope + vd)
+        q = jnp.concatenate(
+            [q[..., :nope], rope(q[..., nope:], self.rope_theta)], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_rope, (B, L, H, rp))],
+            axis=-1)
+        v = kv[..., nope:]
+        bhld = lambda t: t.transpose(0, 2, 1, 3)
+        if self.kind == "flash":
+            if nope + rp != vd:
+                raise ValueError(
+                    "flash attention wants one head width for q, k and v; "
+                    f"got {nope + rp} and {vd} (use model.attention=dense)")
+            from dnn_page_vectors_tpu.ops.flash_attention import (
+                flash_attention)
+            with jax.named_scope("mla.flash"):
+                out = flash_attention(bhld(q), bhld(k), bhld(v), pad_mask,
+                                      block_q=_FLASH_BLOCK,
+                                      block_kv=_FLASH_BLOCK, causal=True)
+            out = bhld(out.astype(self.dtype))
+        elif self.kind == "dense":
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) \
+                / np.sqrt(nope + rp)
+            pos = jnp.arange(L)
+            allowed = (pos[None, :] <= pos[:, None])[None, None] \
+                & pad_mask[:, None, None, :]
+            s = jnp.where(allowed, s, jnp.asarray(-1e9, jnp.float32))
+            out = jnp.einsum("bhqk,bkhd->bqhd",
+                             nn.softmax(s, axis=-1).astype(self.dtype), v)
+        else:
+            raise ValueError(f"unknown attention kind {self.kind!r} for the "
+                             "latent-attention tower (want dense | flash)")
+        return dense(self.model_dim, "wo")(out.reshape(B, L, H * vd))
+
+
+class RoutedExperts(nn.Module):
+    """The expert layer's FFN: router over all `n_routed_experts`, the held
+    experts' grouped SwiGLU, one shared expert."""
+    model_dim: int
+    mlp_dim: int                  # width of every expert
+    n_routed_experts: int
+    num_experts_per_tok: int
+    routed_scaling_factor: float
+    experts_held: int
+    experts_held_start: int
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray):
+        """[B, L, d] -> (FFN(x), this call's counters: `held` [experts_held]
+        assignments per held expert, `absent`, `dropped`)."""
+        B, L, d = x.shape
+        E, k, H = (self.n_routed_experts, self.num_experts_per_tok,
+                   self.experts_held)
+        if not 0 < H <= E - self.experts_held_start:
+            raise ValueError(f"experts held {H} from "
+                             f"{self.experts_held_start} do not lie in the "
+                             f"{E} routed experts")
+        u = x.reshape(B * L, d)
+        stacked = nn.initializers.variance_scaling(
+            1.0, "fan_in", "truncated_normal", in_axis=-2, out_axis=-1,
+            batch_axis=(0,))
+        w_gate = self.param("w_gate", stacked, (H, d, self.mlp_dim))
+        w_up = self.param("w_up", stacked, (H, d, self.mlp_dim))
+        w_down = self.param("w_down", stacked, (H, self.mlp_dim, d))
+        bias = self.param("select_bias", nn.initializers.zeros, (E,))
+
+        with jax.named_scope("moe.router"):
+            logits = nn.Dense(E, use_bias=False, dtype=jnp.float32,
+                              precision="highest",
+                              name="router")(u.astype(jnp.float32))
+            s = jax.nn.sigmoid(logits)                        # [T, E] f32
+            _, chosen = jax.lax.top_k(
+                s + jax.lax.stop_gradient(bias)[None, :], k)
+            picked = jnp.take_along_axis(s, chosen, axis=1)   # [T, k]
+            weight = self.routed_scaling_factor * picked / (
+                picked.sum(-1, keepdims=True) + 1e-20)
+        with jax.named_scope("moe.dispatch"):
+            plan = gm.plan_rows(chosen, self.experts_held_start, H,
+                                _EXPERT_TILE)
+            rows = gm.permute(u, plan)
+        with jax.named_scope("moe.experts"):
+            mm = lambda a, w: gm.grouped_matmul(a, w.astype(self.dtype),
+                                                plan, _EXPERT_TILE)
+            h = nn.silu(mm(rows, w_gate)) * mm(rows, w_up)
+            rows = mm(h, w_down)
+        with jax.named_scope("moe.combine"):
+            routed = gm.unpermute(rows, weight, plan)         # [T, d] f32
+        with jax.named_scope("moe.shared"):
+            shared = SwiGlu(self.mlp_dim, d, dtype=self.dtype,
+                            name="shared")(u)
+        stats = {"held": plan.sizes, "absent": plan.absent,
+                 "dropped": B * L * k - plan.absent
+                 - plan.valid.sum(dtype=jnp.int32)}
+        return (shared + routed.astype(self.dtype)).reshape(B, L, d), stats
+
+
+class MixHalf(nn.Module):
+    """x + MLA(norm(x)): the first half of a block."""
+    sizes: GlmSizes
+    deterministic: bool = True
+    dropout: float = 0.0
+    dtype: jnp.dtype = jnp.bfloat16
+    attention_kind: str = "flash"
+
+    @nn.compact
+    def __call__(self, x, pad_mask):
+        c = self.sizes
+        with jax.named_scope("mla"):
+            h = RmsNorm(dtype=self.dtype, eps=c.norm_eps, name="ln_attn")(x)
+            h = MlaAttention(
+                c.num_heads, c.model_dim, c.q_lora_rank, c.kv_lora_rank,
+                c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim,
+                c.rope_theta, c.norm_eps, dtype=self.dtype,
+                kind=self.attention_kind, name="attn")(h, pad_mask)
+        return x + nn.Dropout(self.dropout)(
+            h, deterministic=self.deterministic)
+
+
+class FfnHalf(nn.Module):
+    """x + FFN(norm(x)): the second half of a block, dense or routed;
+    returns it with the routed layer's counters (None for a dense one)."""
+    sizes: GlmSizes
+    routed: bool
+    deterministic: bool = True
+    dropout: float = 0.0
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        c = self.sizes
+        h = RmsNorm(dtype=self.dtype, eps=c.norm_eps, name="ln_mlp")(x)
+        stats = None
+        if self.routed:
+            with jax.named_scope("moe"):
+                h, stats = RoutedExperts(
+                    c.model_dim, c.moe_mlp_dim, c.n_routed_experts,
+                    c.num_experts_per_tok, c.routed_scaling_factor,
+                    c.experts_held, c.experts_held_start, dtype=self.dtype,
+                    name="moe")(h)
+        else:
+            h = SwiGlu(c.mlp_dim, c.model_dim, dtype=self.dtype,
+                       name="mlp")(h)
+        return x + nn.Dropout(self.dropout)(
+            h, deterministic=self.deterministic), stats
+
+
+class Blocks(nn.Module):
+    """All the blocks, for one group of rows; a scan body, (carry, (x,
+    pad_mask)) -> (carry, (y, the expert layers' counters stacked by
+    layer)). With `remat` each half block is recomputed in the backward
+    pass, which then holds one half's activations at a time."""
+    sizes: GlmSizes
+    num_layers: int
+    remat: bool = False
+    deterministic: bool = True
+    dropout: float = 0.0
+    dtype: jnp.dtype = jnp.bfloat16
+    attention_kind: str = "flash"
+
+    @nn.compact
+    def __call__(self, carry, xs):
+        x, pad_mask = xs
+        mix = nn.remat(MixHalf) if self.remat else MixHalf
+        ffn = nn.remat(FfnHalf) if self.remat else FfnHalf
+        common = dict(deterministic=self.deterministic, dropout=self.dropout,
+                      dtype=self.dtype)
+        stats = []
+        for i in range(self.num_layers):
+            x = mix(self.sizes, attention_kind=self.attention_kind,
+                    name=f"block{i}_mix", **common)(x, pad_mask)
+            x, st = ffn(self.sizes,
+                        routed=i >= self.sizes.first_k_dense_replace,
+                        name=f"block{i}_ffn", **common)(x)
+            if st is not None:
+                stats.append(st)
+        stats = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *stats) \
+            if stats else {}
+        return carry, (x, stats)
+
+
+class GlmMoeEncoder(nn.Module):
+    vocab_size: int
+    sizes: GlmSizes
+    num_layers: int
+    out_dim: int
+    dropout: float = 0.0
+    remat: bool = False           # recompute each half block in the backward
+    dtype: jnp.dtype = jnp.bfloat16
+    attention_kind: str = "flash"
+
+    @nn.compact
+    def __call__(self, ids: jnp.ndarray,
+                 deterministic: bool = True) -> jnp.ndarray:
+        # ids: [B, L], 0 = pad, pads at the end of the row.
+        B, L = ids.shape
+        pad_mask = ids > 0
+        x = nn.Embed(self.vocab_size, self.sizes.model_dim, dtype=self.dtype,
+                     name="tok_embed")(ids)
+        # A block is independent row by row. With recomputation on, a long
+        # batch goes through the blocks in groups of rows, in sequence, so
+        # that only one group's activations are live at a time: the expert
+        # layer's sorted buffers have room for the worst case, 8 times the
+        # expected load of an eighth of the experts.
+        groups = B * L // _ROW_GROUP_TOKENS
+        if groups < 2 or B % groups or not self.remat:
+            groups = 1
+        blocks = Blocks if groups == 1 else nn.scan(
+            Blocks, variable_broadcast="params",
+            split_rngs={"params": False, "dropout": True})
+        split = lambda a: a if groups == 1 else a.reshape(
+            (groups, B // groups) + a.shape[1:])
+        _, (x, stats) = blocks(
+            self.sizes, self.num_layers, remat=self.remat,
+            deterministic=deterministic, dropout=self.dropout,
+            dtype=self.dtype, attention_kind=self.attention_kind,
+            name="layers")(None, (split(x), split(pad_mask)))
+        x = x.reshape((B,) + x.shape[-2:])
+        if stats and not self.is_initializing():
+            for key, v in stats.items():      # [groups,] layers, ...
+                self.sow(STATS, key, v if groups == 1 else v.sum(0))
+        x = RmsNorm(dtype=self.dtype, eps=self.sizes.norm_eps,
+                    name="ln_final")(x)
+        pooled = last_token(x.astype(jnp.float32), pad_mask)
+        return nn.Dense(self.out_dim, dtype=jnp.float32, name="proj")(pooled)
+
+
+def last_token(x: jnp.ndarray, pad_mask: jnp.ndarray) -> jnp.ndarray:
+    """[B, L, d] -> [B, d]: the row of the last non-pad position (position 0
+    for a row that is all pad)."""
+    L = pad_mask.shape[1]
+    last = jnp.max(jnp.where(pad_mask, jnp.arange(L)[None, :], 0), axis=1)
+    return jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]

@@ -43,12 +43,13 @@ def _relative_position_bucket(rel_pos: jnp.ndarray, num_buckets: int = 32,
 
 class RmsNorm(nn.Module):
     dtype: jnp.dtype = jnp.bfloat16
+    eps: float = 1e-6
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         xf = x.astype(jnp.float32)
         var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-        y = xf * jax.lax.rsqrt(var + 1e-6)
+        y = xf * jax.lax.rsqrt(var + self.eps)
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
         return (y * scale).astype(self.dtype)
 

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 # Row vectors (lse, delta) are stored [B, H, L, _LSE_LANES] with the value
@@ -55,7 +56,8 @@ _LSE_LANES = 8
 def reference_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         kv_mask: jnp.ndarray,
                         bias: Optional[jnp.ndarray] = None,
-                        seg: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                        seg: Optional[jnp.ndarray] = None,
+                        causal: bool = False) -> jnp.ndarray:
     """Plain-XLA attention; the kernel's oracle (and the bias-path backward).
 
     q: [B, H, L, Dh]; k, v: [B, H, S, Dh]; kv_mask: [B, S] (True = real
@@ -73,6 +75,9 @@ def reference_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if seg is not None:
         allowed = allowed & ((seg[:, :, None] == seg[:, None, :])
                              & (seg > 0)[:, None, :])[:, None]
+    if causal:
+        L, S = s.shape[-2:]
+        allowed = allowed & (jnp.arange(S)[None, :] <= jnp.arange(L)[:, None])
     s = jnp.where(allowed, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhls,bhsd->bhld", p.astype(v.dtype), v,
@@ -256,13 +261,21 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     kv_mask: jnp.ndarray, bias: Optional[jnp.ndarray] = None,
                     block_q: int = 128, block_kv: int = 128,
                     interpret: Optional[bool] = None,
-                    seg: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                    seg: Optional[jnp.ndarray] = None,
+                    causal: bool = False) -> jnp.ndarray:
     """Flash attention with optional T5 bias and optional packed-page
     segment ids `seg` [B, L] (sequence packing, train.pack_pages): scores
     are restricted to within-segment pairs, with the pairwise segment
     comparison computed per score tile inside the kernel — the packed
     path keeps the flash memory shape (no [B, L, S] mask in HBM) in
-    forward AND backward."""
+    forward AND backward. `causal=True` takes the KV-tiled kernels below
+    (online softmax, tiles above the diagonal skipped)."""
+    if causal:
+        if bias is not None or seg is not None:
+            raise ValueError("causal flash attention takes no bias and no "
+                             "segment ids")
+        return _flash_causal(q, k, v, kv_mask, min(block_q, block_kv),
+                             interpret)
     return _flash_attention(q, k, v, kv_mask, bias, seg, block_q, block_kv,
                             interpret)
 
@@ -563,3 +576,244 @@ def _bwd(block_q, block_kv, interpret, res, g):
 
 
 _flash_attention.defvjp(_fwd, _bwd)
+
+
+# -- causal: KV-tiled, online softmax, tiles above the diagonal skipped -------
+#
+# Grid (B, H, q tiles, kv tiles) with square tiles; the innermost dimension
+# runs in order, so the running max / sum / accumulator live in VMEM scratch
+# and the output block is written when the diagonal tile is done. A tile
+# above the diagonal (kv tile j > q tile i) is skipped by `pl.when`, and its
+# index maps repeat the diagonal's block, so it costs a grid step and neither
+# a DMA nor a matmul. Matmul operands keep the inputs' dtype (bfloat16 on the
+# chip) with float32 accumulation; outputs and gradients are written in it.
+
+def _causal_tile(mask_ref, i, j, block):
+    """[block, block] bool: key visible to query (causal, and not pad)."""
+    row = i * block + jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
+    col = j * block + jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
+    return (col <= row) & (mask_ref[0] > 0)
+
+
+def _scores(q, k, scale):
+    return scale * jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _causal_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
+                       m_sc, l_sc, acc_sc):
+    i, j = pl.program_id(2), pl.program_id(3)
+    block, dh = q_ref.shape[2], q_ref.shape[3]
+
+    @pl.when(j == 0)
+    def _():
+        m_sc[...] = jnp.full(m_sc.shape, _NEG_INF, jnp.float32)
+        l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+    @pl.when(j <= i)
+    def _():
+        v = v_ref[0, 0]
+        s = _scores(q_ref[0, 0], k_ref[0, 0], 1.0 / np.sqrt(dh))
+        s = jnp.where(_causal_tile(mask_ref, i, j, block), s, _NEG_INF)
+        m_old = m_sc[...]
+        m_new = jnp.maximum(m_old, s.max(axis=1, keepdims=True))
+        alpha = jnp.exp(m_old - m_new)
+        p = jnp.exp(s - m_new)
+        l_sc[...] = alpha * l_sc[...] + p.sum(axis=1, keepdims=True)
+        acc_sc[...] = alpha * acc_sc[...] + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_sc[...] = m_new
+
+    @pl.when(j == i)
+    def _():
+        l = jnp.maximum(l_sc[...], 1e-30)
+        o_ref[0, 0] = (acc_sc[...] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = jnp.broadcast_to(m_sc[...] + jnp.log(l),
+                                         (block, lse_ref.shape[3]))
+
+
+def _causal_ds(q_ref, k_ref, v_ref, mask_ref, g_ref, lse_ref, delta_ref,
+               i, j):
+    """(p, ds) of one tile from the saved lse: [block, block] float32."""
+    block, dh = q_ref.shape[2], q_ref.shape[3]
+    s = _scores(q_ref[0, 0], k_ref[0, 0], 1.0 / np.sqrt(dh))
+    s = jnp.where(_causal_tile(mask_ref, i, j, block), s, _NEG_INF)
+    p = jnp.exp(s - lse_ref[0, 0][:, 0:1])
+    dp = jax.lax.dot_general(                                 # g @ v^T
+        g_ref[0, 0], v_ref[0, 0], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return p, p * (dp - delta_ref[0, 0][:, 0:1])
+
+
+def _causal_dq_kernel(q_ref, k_ref, v_ref, mask_ref, g_ref, lse_ref,
+                      delta_ref, dq_ref, acc_sc):
+    i, j = pl.program_id(2), pl.program_id(3)
+    dh = q_ref.shape[3]
+
+    @pl.when(j == 0)
+    def _():
+        acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+    @pl.when(j <= i)
+    def _():
+        k = k_ref[0, 0]
+        _, ds = _causal_ds(q_ref, k_ref, v_ref, mask_ref, g_ref, lse_ref,
+                           delta_ref, i, j)
+        acc_sc[...] += jax.lax.dot_general(                   # ds @ k
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(j == i)
+    def _():
+        dq_ref[0, 0] = (acc_sc[...] / np.sqrt(dh)).astype(dq_ref.dtype)
+
+
+def _causal_dkv_kernel(q_ref, k_ref, v_ref, mask_ref, g_ref, lse_ref,
+                       delta_ref, dk_ref, dv_ref, dk_sc, dv_sc):
+    # grid (B, H, kv tiles, q tiles): j is the kv tile, i the q tile
+    j, i = pl.program_id(2), pl.program_id(3)
+    dh = q_ref.shape[3]
+
+    @pl.when(i == 0)
+    def _():
+        dk_sc[...] = jnp.zeros(dk_sc.shape, jnp.float32)
+        dv_sc[...] = jnp.zeros(dv_sc.shape, jnp.float32)
+
+    @pl.when(i >= j)
+    def _():
+        q, g = q_ref[0, 0], g_ref[0, 0]
+        p, ds = _causal_ds(q_ref, k_ref, v_ref, mask_ref, g_ref, lse_ref,
+                           delta_ref, i, j)
+        dv_sc[...] += jax.lax.dot_general(                    # p^T @ g
+            p.astype(g.dtype), g, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dk_sc[...] += jax.lax.dot_general(                    # ds^T @ q
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(i == pl.num_programs(3) - 1)
+    def _():
+        dk_ref[0, 0] = (dk_sc[...] / np.sqrt(dh)).astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_sc[...].astype(dv_ref.dtype)
+
+
+def _causal_params():
+    return pltpu.CompilerParams(dimension_semantics=(
+        "parallel", "parallel", "parallel", "arbitrary"))
+
+
+def _causal_forward(q, k, v, kv_mask, block, interpret):
+    """Returns (out [B,H,L,Dh] in q's dtype, lse [B,H,L] f32)."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    if q.shape != k.shape or q.shape != v.shape:
+        raise ValueError("causal flash attention is self-attention: q, k and "
+                         f"v share one shape, not {q.shape} {k.shape} "
+                         f"{v.shape}")
+    q, k, v, kv_mask, _, block, _, L, _ = _pad_inputs(
+        q, k, v, kv_mask, None, block, block)
+    B, H, Lp, Dh = q.shape
+    n = Lp // block
+    qspec = pl.BlockSpec((1, 1, block, Dh), lambda b, h, i, j: (b, h, i, 0))
+    kspec = pl.BlockSpec((1, 1, block, Dh),
+                         lambda b, h, i, j: (b, h, jnp.minimum(i, j), 0))
+    out, lse = pl.pallas_call(
+        _causal_fwd_kernel,
+        name="flash_fwd",
+        grid=(B, H, n, n),
+        in_specs=[qspec, kspec, kspec,
+                  pl.BlockSpec((1, 1, block),
+                               lambda b, h, i, j: (b, 0, jnp.minimum(i, j)))],
+        out_specs=[qspec,
+                   pl.BlockSpec((1, 1, block, _LSE_LANES),
+                                lambda b, h, i, j: (b, h, i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((B, H, Lp, Dh), q.dtype),
+                   jax.ShapeDtypeStruct((B, H, Lp, _LSE_LANES), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block, 1), jnp.float32),
+                        pltpu.VMEM((block, 1), jnp.float32),
+                        pltpu.VMEM((block, Dh), jnp.float32)],
+        compiler_params=_causal_params(),
+        interpret=interpret,
+    )(q, k, v, kv_mask.astype(jnp.int32)[:, None, :])
+    return out[:, :, :L], lse[:, :, :L, 0]
+
+
+def _causal_backward(q, k, v, kv_mask, g, out, lse, block, interpret):
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    q, k, v, kv_mask, _, block, _, L, _ = _pad_inputs(
+        q, k, v, kv_mask, None, block, block)
+    B, H, Lp, Dh = q.shape
+    n, pad = Lp // block, Lp - L
+    delta = jnp.einsum("bhld,bhld->bhl", g.astype(jnp.float32),
+                       out.astype(jnp.float32))
+    g = g.astype(q.dtype)
+    if pad:
+        g = jnp.pad(g, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        lse = jnp.pad(lse, ((0, 0), (0, 0), (0, pad)))
+        delta = jnp.pad(delta, ((0, 0), (0, 0), (0, pad)))
+    lse = jnp.broadcast_to(lse[..., None], lse.shape + (_LSE_LANES,))
+    delta = jnp.broadcast_to(delta[..., None], delta.shape + (_LSE_LANES,))
+    mask = kv_mask.astype(jnp.int32)[:, None, :]
+    tile = lambda pick: pl.BlockSpec(
+        (1, 1, block, Dh), lambda b, h, x, y: (b, h, pick(x, y), 0))
+    row = lambda pick: pl.BlockSpec(
+        (1, 1, block, _LSE_LANES), lambda b, h, x, y: (b, h, pick(x, y), 0))
+    mask_spec = lambda pick: pl.BlockSpec(
+        (1, 1, block), lambda b, h, x, y: (b, 0, pick(x, y)))
+    shape = jax.ShapeDtypeStruct((B, H, Lp, Dh), q.dtype)
+    acc = pltpu.VMEM((block, Dh), jnp.float32)
+
+    # dq: grid (.., q tile i, kv tile j); kv blocks stop at the diagonal
+    mine = lambda i, j: i
+    upto = lambda i, j: jnp.minimum(i, j)
+    dq = pl.pallas_call(
+        _causal_dq_kernel,
+        name="flash_dq",
+        grid=(B, H, n, n),
+        in_specs=[tile(mine), tile(upto), tile(upto), mask_spec(upto),
+                  tile(mine), row(mine), row(mine)],
+        out_specs=tile(mine),
+        out_shape=shape,
+        scratch_shapes=[acc],
+        compiler_params=_causal_params(),
+        interpret=interpret,
+    )(q, k, v, mask, g, lse, delta)
+
+    # dk, dv: grid (.., kv tile j, q tile i); q blocks start at the diagonal
+    mine = lambda j, i: j
+    frm = lambda j, i: jnp.maximum(i, j)
+    dk, dv = pl.pallas_call(
+        _causal_dkv_kernel,
+        name="flash_dkv",
+        grid=(B, H, n, n),
+        in_specs=[tile(frm), tile(mine), tile(mine), mask_spec(mine),
+                  tile(frm), row(frm), row(frm)],
+        out_specs=[tile(mine), tile(mine)],
+        out_shape=[shape, shape],
+        scratch_shapes=[acc, acc],
+        compiler_params=_causal_params(),
+        interpret=interpret,
+    )(q, k, v, mask, g, lse, delta)
+    return dq[:, :, :L], dk[:, :, :L], dv[:, :, :L]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _flash_causal(q, k, v, kv_mask, block, interpret):
+    return _causal_forward(q, k, v, kv_mask, block, interpret)[0]
+
+
+def _causal_fwd(q, k, v, kv_mask, block, interpret):
+    out, lse = _causal_forward(q, k, v, kv_mask, block, interpret)
+    return out, (q, k, v, kv_mask, out, lse)
+
+
+def _causal_bwd(block, interpret, res, g):
+    q, k, v, kv_mask, out, lse = res
+    return (*_causal_backward(q, k, v, kv_mask, g, out, lse, block,
+                              interpret), None)
+
+
+_flash_causal.defvjp(_causal_fwd, _causal_bwd)

@@ -2,9 +2,15 @@
 
 DP: every batch array is sharded on its leading dim over 'data'.
 TP: transformer matmuls are sharded over 'model' by the rules below, keyed
-on the param names in models/transformer.py. Everything unmatched is
-replicated. XLA propagates these annotations through the whole program and
-inserts the ICI collectives (the reference's NCCL role, BASELINE.json:5).
+on the param names in models/transformer.py.
+EP: the stacked kernels of a routed-expert layer (models/glm_moe.py,
+[experts held, in, out]) are sharded on their leading dim over 'expert'. No
+mesh of this repo has that axis yet (parallel/mesh.py builds data x model x
+seq), and an axis a mesh lacks is dropped from the spec: on one chip the
+layer holds its share whole and runs without the exchange.
+Everything unmatched is replicated. XLA propagates these annotations through
+the whole program and inserts the ICI collectives (the reference's NCCL role,
+BASELINE.json:5).
 """
 from __future__ import annotations
 
@@ -31,6 +37,8 @@ TP_RULES: List[Tuple[str, P]] = [
     # token embedding: shard the embed dim (gather output stays sharded on
     # the feature axis, feeding the TP matmuls without a reshard)
     (r".*/tok_embed/embedding$", P(None, "model")),
+    # routed experts: one chip's share is a slice of the expert dim
+    (r".*/moe/w_(gate|up|down)$", P("expert", None, None)),
 ]
 
 
@@ -52,7 +60,9 @@ def param_shardings(params: Any, mesh: Mesh) -> Any:
     """Pytree of NamedSharding matching `params`. With mesh model=1 every
     rule degenerates to replication, so the same code path serves pure-DP."""
     def _one(path, _leaf):
-        return NamedSharding(mesh, spec_for_param(_path_str(path)))
+        spec = spec_for_param(_path_str(path))
+        return NamedSharding(mesh, P(*(
+            a if a in mesh.axis_names else None for a in spec)))
     return jax.tree_util.tree_map_with_path(_one, params)
 
 

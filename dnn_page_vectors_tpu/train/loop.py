@@ -32,7 +32,8 @@ from dnn_page_vectors_tpu.parallel.mesh import fit_mesh_to_devices, make_mesh
 from dnn_page_vectors_tpu.parallel.sharding import (
     batch_sharding, param_shardings, put_global, replicated, shard_params,
     stacked_batch_sharding)
-from dnn_page_vectors_tpu.train.optimizer import make_optimizer
+from dnn_page_vectors_tpu.models.glm_moe import STATS as MOE_STATS
+from dnn_page_vectors_tpu.train.optimizer import SELECT_BIAS, make_optimizer
 from dnn_page_vectors_tpu.utils import faults, telemetry
 from dnn_page_vectors_tpu.utils.logging import MetricsLogger
 from dnn_page_vectors_tpu.utils.profiling import PipelineProfiler
@@ -45,9 +46,30 @@ class TrainState:
     step: jnp.ndarray          # int32 scalar
 
 
-def make_train_step(model, tx, loss_chunk: int = 0):
+def _logged(value):
+    """A step metric as the log line carries it: a scalar as a float, the
+    expert layers' counters as lists."""
+    return float(value) if np.ndim(value) == 0 \
+        else np.asarray(value).tolist()
+
+
+def moe_metrics(stats) -> Dict[str, jnp.ndarray]:
+    """The routed-expert layers' counters of one step, from what the shared
+    tower sowed (one entry per call, query then page: summed):
+    `moe/assignments_held` [layers, held], `moe/assignments_absent` [layers],
+    `moe/dropped` (scalar; 0 by construction, and counted)."""
+    tower = stats["query_tower"]
+    return {"moe/assignments_held": sum(tower["held"]),
+            "moe/assignments_absent": sum(tower["absent"]),
+            "moe/dropped": sum(tower["dropped"]).sum()}
+
+
+def make_train_step(model, tx, loss_chunk: int = 0, moe_stats: bool = False):
     """Build the (un-jitted) global-batch train step; caller jits with
     shardings + donation.
+
+    `moe_stats`: the towers have routed-expert layers (models/glm_moe.py),
+    whose counters join the step's metrics, reduced on the device.
 
     `loss_chunk` > 0 selects the fused/chunked contrastive loss
     (train.loss_chunk, models/losses.py): the [B, B(1+H)] logits never
@@ -59,17 +81,22 @@ def make_train_step(model, tx, loss_chunk: int = 0):
         rng = jax.random.fold_in(base_rng, state.step)
 
         def loss_fn(params):
-            q, p, neg, scale = model.apply(
+            out = model.apply(
                 params, batch["query"], batch["page"],
                 batch.get("neg_page"), deterministic=False,
                 rngs={"dropout": rng},
                 page_seg=batch.get("page_seg"),
-                page_pos=batch.get("page_pos"))
+                page_pos=batch.get("page_pos"),
+                mutable=[MOE_STATS] if moe_stats else False)
+            (q, p, neg, scale), stats = out if moe_stats else (out, None)
             # Flax names the towers' ops by module path; the loss and the
             # optimizer are no modules, so they get their scopes here
             with jax.named_scope("loss"):
-                return cosine_contrastive_loss(q, p, scale, neg,
-                                               chunk=loss_chunk)
+                loss, metrics = cosine_contrastive_loss(q, p, scale, neg,
+                                                        chunk=loss_chunk)
+            if moe_stats:
+                metrics = dict(metrics, **moe_metrics(stats[MOE_STATS]))
+            return loss, metrics
 
         (loss, metrics), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params)
@@ -116,7 +143,9 @@ class Trainer:
         self.mesh = make_mesh(fitted)
         self.model = build_two_tower(cfg, self.page_tok.vocab_size,
                                      mesh=self.mesh)
-        self.tx = make_optimizer(cfg.train)
+        self._moe = cfg.model.encoder == "glm4_moe_lite"
+        self.tx = make_optimizer(cfg.train,
+                                 no_decay=SELECT_BIAS if self._moe else None)
         self.hard_negative_lookup = hard_negative_lookup
         self._compiled = None
         self._compiled_multi = None
@@ -164,7 +193,8 @@ class Trainer:
     def compiled_step(self, state: TrainState):
         if self._compiled is None:
             step_fn = make_train_step(self.model, self.tx,
-                                      loss_chunk=self.cfg.train.loss_chunk)
+                                      loss_chunk=self.cfg.train.loss_chunk,
+                                      moe_stats=self._moe)
             state_sh = jax.tree_util.tree_map(lambda x: x.sharding, state)
             self._compiled = jax.jit(
                 step_fn,
@@ -232,7 +262,8 @@ class Trainer:
         step's, matching what a per-step loop would log at the boundary."""
         if self._compiled_multi is None:
             step_fn = make_train_step(self.model, self.tx,
-                                      loss_chunk=self.cfg.train.loss_chunk)
+                                      loss_chunk=self.cfg.train.loss_chunk,
+                                      moe_stats=self._moe)
 
             def multi(state, stacked, base_rng):
                 def body(st, batch):
@@ -344,7 +375,7 @@ class Trainer:
                 jax.block_until_ready(state.params)
                 t_mark, i_mark = time.perf_counter(), i
             if at_log:
-                metrics = {k: float(v) for k, v in metrics.items()}
+                metrics = {k: _logged(v) for k, v in metrics.items()}
                 with prof.stage("sync"):
                     # graftcheck: off=host-sync -- log-cadence drain:
                     # fires every log_every steps, not per step

@@ -26,6 +26,25 @@ def encoder_flops_per_example(m: ModelConfig, seq_len: int) -> float:
         per_tok_layer = 8 * d * d + 4 * L * d + mlp
         proj = 2 * d * m.out_dim          # pooled vector -> out_dim
         return float(L * m.num_layers * per_tok_layer + proj)
+    if m.encoder == "glm4_moe_lite":
+        # latent attention's five projections, causal scores counted once,
+        # the dense layers' SwiGLU; in expert layers the router, the shared
+        # expert and the EXPECTED share of assignments on the experts held
+        d, L, H = m.model_dim, seq_len, m.num_heads
+        qk = m.qk_nope_head_dim + m.qk_rope_head_dim
+        mla = 2 * (d * m.q_lora_rank + m.q_lora_rank * H * qk
+                   + d * (m.kv_lora_rank + m.qk_rope_head_dim)
+                   + m.kv_lora_rank * H * (m.qk_nope_head_dim + m.v_head_dim)
+                   + H * m.v_head_dim * d) \
+            + 2 * (L + 1) / 2 * H * (qk + m.v_head_dim)
+        held = (m.experts_held or m.n_routed_experts) / m.n_routed_experts
+        expert = 6 * d * m.moe_intermediate_size
+        moe = 2 * d * m.n_routed_experts \
+            + expert * (1 + m.num_experts_per_tok * held)
+        dense = min(m.first_k_dense_replace, m.num_layers)
+        return float(L * (m.num_layers * mla + dense * 6 * d * m.mlp_dim
+                          + (m.num_layers - dense) * moe)
+                     + 2 * d * m.out_dim)
     if m.encoder == "cdssm":
         E, C = m.embed_dim, m.conv_channels
         conv = sum(2 * w * E * C for w in m.conv_widths) * seq_len

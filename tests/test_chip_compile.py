@@ -65,3 +65,49 @@ def test_flash_compiles_for_v5e(one_chip, mode, bias, seg):
         lambda *a: jnp.sum(attn(*a)), (0, 1, 2, 4) if bias else (0, 1, 2))
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+# GLM-4.7-Flash's widths (config.py:glm47_flash_ep8): one group of 4 rows
+GLM_B, GLM_H, GLM_L, GLM_DH = 4, 20, 1024, 256
+
+
+@pytest.mark.parametrize("mode", ["forward", "grad"])
+def test_causal_flash_compiles_for_v5e_at_head_width_256(one_chip, mode):
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype,
+                                                     sharding=one_chip)
+    qkv = [shape((GLM_B, GLM_H, GLM_L, GLM_DH), jnp.bfloat16)] * 3
+    mask = shape((GLM_B, GLM_L), jnp.bool_)
+
+    def attn(q, k, v, kv_mask):
+        return flash_attention(q, k, v, kv_mask, block_q=512, block_kv=512,
+                               causal=True, interpret=False)
+
+    fn = attn if mode == "forward" else jax.grad(
+        lambda *a: jnp.sum(attn(*a).astype(jnp.float32)), (0, 1, 2))
+    text = jax.jit(fn).lower(*qkv, mask).compile().as_text()
+    assert text.count("tpu_custom_call") >= (1 if mode == "forward" else 3)
+
+
+@pytest.mark.parametrize("mode", ["forward", "grad"])
+def test_grouped_matmul_compiles_for_v5e_at_the_expert_widths(one_chip, mode):
+    from dnn_page_vectors_tpu.ops import grouped_matmul as gm
+    tokens, top_k, held, tile, d, ff = 4096, 4, 8, 256, 2048, 1536
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype,
+                                                     sharding=one_chip)
+
+    def experts(x, chosen, w_up, w_down, weight):
+        plan = gm.plan_rows(chosen, 0, held, tile)
+        h = gm.grouped_matmul(gm.permute(x, plan), w_up, plan, tile,
+                              interpret=False)
+        rows = gm.grouped_matmul(jax.nn.silu(h), w_down, plan, tile,
+                                 interpret=False)
+        return gm.unpermute(rows, weight, plan)
+
+    fn = experts if mode == "forward" else jax.grad(
+        lambda *a: jnp.sum(experts(*a)), (0, 2, 3, 4))
+    text = jax.jit(fn).lower(
+        shape((tokens, d), jnp.bfloat16), shape((tokens, top_k), jnp.int32),
+        shape((held, d, ff), jnp.bfloat16),
+        shape((held, ff, d), jnp.bfloat16),
+        shape((tokens, top_k), jnp.float32)).compile().as_text()
+    assert text.count("tpu_custom_call") >= (2 if mode == "forward" else 6)

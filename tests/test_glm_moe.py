@@ -1,0 +1,308 @@
+"""models/glm_moe.py (GLM-4.7-Flash as an embedding tower) at tiny widths:
+the program against the benchmark's plain reference, the share of the
+experts tied to the uncut layer, no assignment dropped, the last-token pool,
+and the selection bias (it selects, never weighs, and is held)."""
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmarks import weights_moe  # noqa: E402
+from benchmarks.reference import glm4_moe_lite as ref  # noqa: E402
+from dnn_page_vectors_tpu.config import get_config  # noqa: E402
+from dnn_page_vectors_tpu.models import glm_moe  # noqa: E402
+from dnn_page_vectors_tpu.models.factory import build_two_tower  # noqa: E402
+from dnn_page_vectors_tpu.models.losses import (  # noqa: E402
+    cosine_contrastive_loss)
+from dnn_page_vectors_tpu.train.loop import moe_metrics  # noqa: E402
+
+# hidden 64, 8 experts of width 32, 2 a token, ranks 16 / 24, 2 heads of
+# (12 + 4 | 16), 1 dense + 2 expert layers
+ARCH = {"num_attention_heads": 2, "qk_nope_head_dim": 12,
+        "qk_rope_head_dim": 4, "v_head_dim": 16, "kv_lora_rank": 24,
+        "rms_norm_eps": 1e-5, "rope_theta": 1e6, "num_experts_per_tok": 2,
+        "routed_scaling_factor": 1.8, "experts_held_start": 2,
+        "num_hidden_layers": 3, "first_k_dense_replace": 1}
+VOCAB = 100
+
+
+def _config(dtype="float32", attention="flash", held=4, start=2, **more):
+    ov = {"model.model_dim": 64, "model.mlp_dim": 96,
+          "model.moe_intermediate_size": 32, "model.num_heads": 2,
+          "model.q_lora_rank": 16, "model.kv_lora_rank": 24,
+          "model.qk_nope_head_dim": 12, "model.qk_rope_head_dim": 4,
+          "model.v_head_dim": 16, "model.n_routed_experts": 8,
+          "model.num_experts_per_tok": 2, "model.num_layers": 3,
+          "model.experts_held": held, "model.experts_held_start": start,
+          "model.out_dim": 32, "model.dtype": dtype,
+          "model.attention": attention, "data.vocab_size": VOCAB,
+          "data.page_len": 32, "data.query_len": 16}
+    ov.update(more)
+    return get_config("glm47_flash_ep8", ov)
+
+
+def _ids(seed=0):
+    rng = np.random.default_rng(seed)
+    q = rng.integers(1, VOCAB, (4, 16))
+    q[1, 9:] = 0                                   # padding at the end
+    p = rng.integers(1, VOCAB, (4, 32))
+    p[2, 20:] = 0
+    return jnp.asarray(q, jnp.int32), jnp.asarray(p, jnp.int32)
+
+
+def _model_and_params(cfg, seed=12345):
+    model = build_two_tower(cfg, VOCAB)
+    q, p = _ids()
+    tree = jax.eval_shape(model.init, jax.random.PRNGKey(0), q, p)
+    return model, weights_moe.make_params(tree, seed)
+
+
+def _program(model, params, q, p):
+    (qv, pv, _, scale), st = model.apply(params, q, p,
+                                         mutable=[glm_moe.STATS])
+    return cosine_contrastive_loss(qv, pv, scale, None)[0], (qv, pv, st)
+
+
+def _reference(params, q, p, arch=ARCH):
+    t = params["params"]["query_tower"]
+    qv, c1 = ref.tower(t, q, arch)
+    pv, c2 = ref.tower(t, p, arch)
+    loss = ref.towers.contrastive_loss(qv, pv, params["params"]["log_scale"])
+    return loss, (qv, pv, c1 + c2)
+
+
+# float32: rounding of another order of summation. bfloat16: 8 bits of
+# mantissa through 3 blocks at width 64 move a vector by about 2% and a
+# leaf's gradient by up to a quarter of its norm; the seed is one on which
+# no token's 2nd and 3rd router scores lie within that error of each other
+# (at this width one flipped expert moves a last-token vector by half).
+@pytest.mark.parametrize("dtype,attention,remat,seed,tol,grad_tol", [
+    ("float32", "flash", True, 12345, 1e-5, 3e-5),
+    ("float32", "dense", False, 12345, 1e-5, 3e-5),
+    ("bfloat16", "flash", True, 1, 0.05, 0.25)])
+def test_tower_equals_the_plain_reference(dtype, attention, remat, seed, tol,
+                                          grad_tol):
+    cfg = _config(dtype, attention, **{"model.remat_blocks": remat})
+    model, params = _model_and_params(cfg, seed)
+    q, p = _ids()
+    (l1, (q1, p1, st)), g1 = jax.jit(jax.value_and_grad(
+        lambda v: _program(model, v, q, p), has_aux=True))(params)
+    (l2, (q2, p2, counts)), g2 = jax.jit(jax.value_and_grad(
+        lambda v: _reference(v, q, p), has_aux=True))(params)
+    assert abs(float(l1) - float(l2)) <= tol * abs(float(l2))
+    for a, b in ((q1, q2), (p1, p2)):
+        assert float(jnp.abs(a - b).max()) <= tol * float(jnp.abs(b).max())
+    norm = lambda t: float(jnp.sqrt(jnp.sum(jnp.square(t))))
+    flat1 = jax.tree_util.tree_flatten_with_path(g1)[0]
+    for (path, a), b in zip(flat1, jax.tree_util.tree_leaves(g2)):
+        assert norm(a - b) <= grad_tol * max(norm(b), 1e-3), \
+            weights_moe.path_str(path)
+    m = moe_metrics(st[glm_moe.STATS])
+    assert int(m["moe/dropped"]) == 0
+    assert m["moe/assignments_held"].shape == (2, 4)
+    np.testing.assert_array_equal(m["moe/assignments_held"], counts)
+    tokens = q.size + p.size
+    np.testing.assert_array_equal(
+        m["moe/assignments_held"].sum(1) + m["moe/assignments_absent"],
+        [2 * tokens] * 2)
+
+
+def test_rows_in_groups_give_the_same_step(monkeypatch):
+    """A long batch goes through the blocks in groups of rows (a scan):
+    same vectors, same counters."""
+    cfg = _config()
+    model, params = _model_and_params(cfg)
+    q, p = _ids()
+    run = lambda: jax.jit(lambda v: _program(model, v, q, p))(params)
+    whole = run()
+    monkeypatch.setattr(glm_moe, "_ROW_GROUP_TOKENS", 64)   # 4 x 32 -> 2 groups
+    grouped = run()
+    np.testing.assert_allclose(grouped[1][1], whole[1][1], rtol=1e-5,
+                               atol=1e-6)
+    a, b = (moe_metrics(x[1][2][glm_moe.STATS]) for x in (grouped, whole))
+    for key in a:
+        np.testing.assert_array_equal(a[key], b[key])
+
+
+def _layer_params(seed=3, experts=8):
+    rng = np.random.default_rng(seed)
+    n = lambda *s: jnp.asarray(rng.normal(size=s) / np.sqrt(s[-2]),
+                               jnp.float32)
+    return {"router": {"kernel": n(64, experts)},
+            "select_bias": jnp.asarray(0.02 * rng.normal(size=experts),
+                                       jnp.float32),
+            "w_gate": n(experts, 64, 32), "w_up": n(experts, 64, 32),
+            "w_down": n(experts, 32, 64),
+            "shared": {"wi_0": {"kernel": n(64, 32)},
+                       "wi_1": {"kernel": n(64, 32)},
+                       "wo_mlp": {"kernel": n(32, 64)}}}
+
+
+def _share(p, start, held):
+    cut = lambda w: w[start:start + held]
+    return dict(p, w_gate=cut(p["w_gate"]), w_up=cut(p["w_up"]),
+                w_down=cut(p["w_down"]))
+
+
+def _layer(start, held):
+    return glm_moe.RoutedExperts(64, 32, 8, 2, 1.8, held, start,
+                                 dtype=jnp.float32)
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """Four chips with two experts each: their routed parts, plus the shared
+    expert counted once, are the whole layer as the reference computes it."""
+    p = _layer_params()
+    u = jnp.asarray(np.random.default_rng(4).normal(size=(2, 24, 64)),
+                    jnp.float32)
+    arch = dict(ARCH, experts_held_start=0)
+    whole, counts = ref._experts(p, u.reshape(48, 64), arch, ref.identity,
+                                 True)
+    shared = ref._swiglu(p["shared"], u.reshape(48, 64), ref.identity)
+    total, held = shared, []
+    for start in (0, 2, 4, 6):
+        y, st = jax.jit(_layer(start, 2).apply)(
+            {"params": _share(p, start, 2)}, u)
+        total = total + (y.reshape(48, 64) - shared)
+        held.append(st["held"])
+        assert int(st["dropped"]) == 0
+    np.testing.assert_allclose(total, whole, rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(jnp.concatenate(held), counts)
+    assert int(counts.sum()) == 48 * 2
+
+
+def test_no_assignment_is_dropped_when_every_token_picks_one_expert():
+    p = _layer_params()
+    p["select_bias"] = p["select_bias"].at[3].set(100.0)    # held: 2..5
+    u = jnp.asarray(np.random.default_rng(5).normal(size=(2, 24, 64)),
+                    jnp.float32)
+    y, st = _layer(2, 4).apply({"params": _share(p, 2, 4)}, u)
+    assert int(st["dropped"]) == 0 and int(st["held"][1]) == 48
+    assert int(st["held"].sum() + st["absent"]) == 96
+    want, _ = ref._experts(_share(p, 2, 4), u.reshape(48, 64),
+                           dict(ARCH, experts_held_start=2), ref.identity,
+                           True)
+    np.testing.assert_allclose(y.reshape(48, 64), want, rtol=1e-5, atol=1e-5)
+
+
+def test_the_bias_selects_and_never_weighs():
+    p = _layer_params()
+    u = jnp.asarray(np.random.default_rng(6).normal(size=(1, 24, 64)),
+                    jnp.float32)
+    arch = dict(ARCH, experts_held_start=0)
+    chosen0, weight0 = ref.route(p, u[0], arch)
+    moved = dict(p, select_bias=p["select_bias"].at[5].add(0.3))
+    chosen1, weight1 = ref.route(moved, u[0], arch)
+    assert (chosen0 != chosen1).any()                     # it selects
+    same = (chosen0 == chosen1).all(axis=1)
+    assert same.any()
+    np.testing.assert_array_equal(weight0[same], weight1[same])   # only that
+    # the program agrees, and no gradient reaches the bias
+    run = lambda q: _layer(0, 8).apply({"params": q}, u)[0]
+    np.testing.assert_allclose(
+        jax.jit(run)(moved).reshape(24, 64),
+        ref._experts(moved, u[0], arch, ref.identity, True)[0],
+        rtol=1e-5, atol=1e-5)
+    g = jax.jit(jax.grad(lambda q: jnp.sum(run(q) ** 2)))(p)
+    assert float(jnp.abs(g["select_bias"]).max()) == 0.0
+    assert float(jnp.abs(g["router"]["kernel"]).max()) > 0.0
+
+
+def test_a_train_step_leaves_the_bias_where_it_was(tmp_path):
+    from benchmarks import corpus
+    from dnn_page_vectors_tpu.train.loop import Trainer
+    cfg = _config(attention="dense",
+                  **{"train.batch_size": 4, "mesh.data": 1,
+                     "model.remat_blocks": False,
+                     "train.learning_rate": 1e-2, "train.warmup_steps": 1})
+    toks = tuple(corpus.HashTokenizer(VOCAB, n, 7, side)
+                 for side, n in enumerate((16, 32)))
+    trainer = Trainer(cfg, corpus=corpus.IdCorpus(64), tokenizers=toks,
+                      workdir=str(tmp_path))
+    state = trainer.init_state()
+    bias = lambda s: np.asarray(
+        s.params["params"]["query_tower"]["layers"]["block1_ffn"]["moe"][
+            "select_bias"])
+    gate = lambda s: np.asarray(
+        s.params["params"]["query_tower"]["layers"]["block1_ffn"]["moe"][
+            "w_gate"])
+    b0, g0 = bias(state), gate(state)
+    step, rng, batches = (trainer.compiled_step(state), trainer.base_rng(),
+                          trainer.batches())
+    for _ in range(3):
+        state, metrics = step(state, next(batches), rng)
+    batches.close()
+    np.testing.assert_array_equal(bias(state), b0)     # update exactly zero
+    assert np.abs(gate(state) - g0).max() > 0          # the rest trains
+    assert float(metrics["moe/dropped"]) == 0.0
+    assert metrics["moe/assignments_held"].shape == (2, 4)
+    assert metrics["moe/assignments_absent"].shape == (2,)
+
+
+@pytest.mark.parametrize("lengths", [[5, 1, 8], [8, 8, 0]])
+def test_last_token_pool_picks_the_last_non_pad_position(lengths):
+    x = jnp.arange(3 * 8 * 2, dtype=jnp.float32).reshape(3, 8, 2)
+    mask = jnp.arange(8)[None, :] < jnp.asarray(lengths)[:, None]
+    got = glm_moe.last_token(x, mask)
+    want = [x[i, max(n - 1, 0)] for i, n in enumerate(lengths)]
+    np.testing.assert_array_equal(got, jnp.stack(want))
+
+
+def test_rope_rotates_pairs_half_a_width_apart_and_keeps_norms():
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(1, 6, 2, 8)),
+                    jnp.float32)
+    y = glm_moe.rope(x, 1e6)
+    np.testing.assert_allclose(y[:, 0], x[:, 0], atol=1e-6)   # position 0
+    pair = lambda t, i: t[..., i] ** 2 + t[..., i + 4] ** 2
+    for i in range(4):
+        np.testing.assert_allclose(pair(y, i), pair(x, i), rtol=1e-5)
+    np.testing.assert_allclose(y, ref._rope(x, 1e6), atol=1e-6)
+
+
+def test_rms_norm_eps_is_a_field_and_defaults_to_1e_6():
+    from dnn_page_vectors_tpu.models.transformer import RmsNorm
+    assert RmsNorm().eps == 1e-6
+    x = jnp.full((1, 4), 1e-3, jnp.float32)
+    out = lambda eps: RmsNorm(dtype=jnp.float32, eps=eps).apply(
+        {"params": {"scale": jnp.ones(4)}}, x)
+    np.testing.assert_allclose(out(1e-6), x / np.sqrt(1e-6 + 1e-6), rtol=1e-6)
+    np.testing.assert_allclose(out(1e-5), x / np.sqrt(1e-6 + 1e-5), rtol=1e-6)
+
+
+def test_expert_rule_shards_the_stacked_kernels_where_the_mesh_has_the_axis():
+    from jax.sharding import Mesh, PartitionSpec as P
+    from dnn_page_vectors_tpu.parallel import sharding
+    path = "params/query_tower/layers/block1_ffn/moe/w_gate"
+    assert sharding.spec_for_param(path) == P("expert", None, None)
+    assert sharding.spec_for_param(path.replace("w_gate", "router/kernel")) \
+        == P()
+    devs = np.asarray(jax.devices()[:2])
+    tree = {"params": {"query_tower": {"layers": {"block1_ffn": {"moe": {
+        "w_gate": jnp.zeros((2, 4, 4))}}}}}}
+    leaf = lambda mesh: jax.tree_util.tree_leaves(
+        sharding.param_shardings(tree, mesh))[0].spec
+    assert leaf(Mesh(devs.reshape(2, 1, 1), ("data", "model", "seq"))) \
+        == P(None, None, None)                     # no such axis: held whole
+    assert leaf(Mesh(devs, ("expert",))) == P("expert", None, None)
+
+
+def test_preset_states_the_published_widths_and_the_share():
+    m = get_config("glm47_flash_ep8").model
+    assert (m.model_dim, m.num_heads, m.mlp_dim, m.moe_intermediate_size) \
+        == (2048, 20, 10240, 1536)
+    assert (m.q_lora_rank, m.kv_lora_rank, m.qk_nope_head_dim,
+            m.qk_rope_head_dim, m.v_head_dim) == (768, 512, 192, 64, 256)
+    assert (m.n_routed_experts, m.num_experts_per_tok,
+            m.routed_scaling_factor, m.first_k_dense_replace) \
+        == (64, 4, 1.8, 1)
+    assert (m.num_layers, m.experts_held, m.shared_towers, m.attention) \
+        == (5, 8, True, "flash")
+    from dnn_page_vectors_tpu.utils.flops import train_flops_per_pair
+    assert train_flops_per_pair(get_config("glm47_flash_ep8"), 32) > 1e12
