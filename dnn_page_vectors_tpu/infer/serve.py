@@ -12,8 +12,11 @@ most of every dispatch on padding. Three mechanisms recover that width:
 
   * `search_many(queries, k)` — vectorized multi-query search: one
     encode_batch over up to `query_batch` real queries, one fused per-shard
-    top-k + device merge, one packed transfer, results split per query;
-    larger lists tile over full buckets (one compiled shape throughout).
+    top-k + device merge, one packed transfer, results split per query
+    (each shard's scan hands back ONE packed int32 [B, 2k] array, scores'
+    bits then row ids — ops/topk.py:pack_topk — and the merge packs its
+    winners the same way); larger lists tile over full buckets (one
+    compiled shape throughout).
   * a dynamic micro-batcher (`serve.batch_window_ms` / `serve.max_batch`,
     start_batcher()): concurrent search() callers enqueue onto a bounded
     queue, a dispatcher thread coalesces whatever arrived within the window
@@ -125,7 +128,8 @@ from dnn_page_vectors_tpu.infer.bulk_embed import BulkEmbedder
 from dnn_page_vectors_tpu.infer.transport import DeadlineExceeded
 from dnn_page_vectors_tpu.infer.vector_store import VectorStore, read_ahead
 from dnn_page_vectors_tpu.ops.topk import (
-    merge_shard_topk, sharded_topk_fn, stage_shard, topk_over_store)
+    merge_shard_topk, pack_topk, sharded_topk_fn, stage_shard,
+    topk_over_store, unpack_topk)
 from dnn_page_vectors_tpu.utils import faults
 from dnn_page_vectors_tpu.utils.profiling import LatencyStats, PipelineProfiler
 from dnn_page_vectors_tpu.utils.telemetry import MetricsRegistry
@@ -1487,26 +1491,22 @@ class SearchService:
 
         @jax.named_scope("merge")    # names its ops; the program stays
         def merge(cands):            # `jit_merge`
-            # Device-side cross-shard merge, output PACKED into one fp32
-            # array: every host<->device round trip adds to per-query
-            # serving latency, so the k winners across all resident shards
-            # come back in a single transfer — scores in [:, :k], int32 combined ids bitcast into
-            # [:, k:].
-            scs = [s for s, _ in cands]
-            cat_s = jnp.concatenate(scs, axis=1)
+            # Device-side cross-shard merge of the scans' packed arrays,
+            # one [B, 2k] int32 a resident shard (ops/topk.py:pack_topk),
+            # packed the same way on the way out: every host<->device
+            # round trip adds to per-query serving latency, so the k
+            # winners across all resident shards come back in a single
+            # transfer.
+            parts = [unpack_topk(c) for c in cands]
+            cat_s = jnp.concatenate([s for s, _ in parts], axis=1)
             cat_i = jnp.concatenate(
                 [jnp.where(i >= 0, i + slot * rows, -1)
-                 for slot, (_, i) in enumerate(cands)], axis=1)
-            k = scs[0].shape[1]
+                 for slot, (_, i) in enumerate(parts)], axis=1)
+            k = parts[0][0].shape[1]
             top_s, pos = lax.top_k(cat_s, k)          # cat width S*k >= k
             top_i = jnp.take_along_axis(cat_i, pos, axis=1)
             top_i = jnp.where(jnp.isfinite(top_s), top_i, -1)
-            # pack as INT32, scores bitcast into int bits — NOT ids into
-            # float bits: small ids make denormal floats, and anything on
-            # the way that flushes denormals to zero would silently remap
-            # every result to page_ids[0]. Integers are byte-faithful.
-            return jnp.concatenate(
-                [lax.bitcast_convert_type(top_s, jnp.int32), top_i], axis=1)
+            return pack_topk(top_s, top_i)
 
         view.merge = jax.jit(merge)
 
@@ -2556,7 +2556,9 @@ class SearchService:
         mesh's 'data' axis, and `valid`, the shard's row count as an
         int32 scalar already on the device. Once the query blocks are up,
         a bucket moves nothing from host to device and runs one program
-        per shard plus the merge."""
+        per shard plus the merge. Each launch hands back ONE array (the
+        packed [B, 2k] of pack_topk), so the runtime makes one output
+        buffer a launch and the merge binds one argument a shard."""
         import jax.numpy as jnp
 
         nreal = next(iter(qblocks.values())).shape[0]
@@ -2599,8 +2601,7 @@ class SearchService:
             # graftcheck: off=host-sync -- THE one packed d2h per
             # bucket: the whole point of the merged [B, 2k] layout
             packed = np.asarray(packed)
-        top_s = np.ascontiguousarray(packed[:, :k]).view(np.float32)
-        top_i = packed[:, k:]
+        top_s, top_i = unpack_topk(packed)
         pids = np.where(top_i >= 0,
                         view.pid_table[np.clip(top_i, 0, None)], -1)
         best_s = np.where(np.isfinite(top_s), top_s, -np.inf).astype(

@@ -102,13 +102,37 @@ def chunked_topk(q: jnp.ndarray, pages: jnp.ndarray, k: int = 10,
     return _topk_scan(q, pages, k, chunk, jnp.int32(N))
 
 
+def pack_topk(scores: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """(scores [Bq, k] float32, idx [Bq, k] int32) as ONE int32 [Bq, 2k]:
+    the scores' bits in [:, :k], the ids in [:, k:]. A program that hands
+    back one array costs the host one output buffer a launch and one
+    transfer a pull. Packed as INT32, scores bitcast into int bits — NOT
+    ids into float bits: small ids make denormal floats, and anything on
+    the way that flushes denormals to zero would silently remap every
+    result to row 0. Integers are byte-faithful."""
+    return jnp.concatenate(
+        [lax.bitcast_convert_type(scores, jnp.int32), idx], axis=1)
+
+
+def unpack_topk(packed) -> Tuple:
+    """`pack_topk`'s inverse: (scores [Bq, k] float32, idx [Bq, k] int32).
+    A numpy array is split in place (two views, nothing copied), a jax
+    array — a tracer inside a jitted caller — by slice and bitcast."""
+    k = packed.shape[1] // 2
+    if isinstance(packed, np.ndarray):
+        return packed[:, :k].view(np.float32), packed[:, k:]
+    return (lax.bitcast_convert_type(packed[:, :k], jnp.float32),
+            packed[:, k:])
+
+
 _SHARDED_CACHE: Dict[Tuple, Tuple] = {}
 
 
 def _build_sharded_topk(mesh: Mesh, k: int, chunk: int, scaled: bool):
-    """Jitted (q, pages[, scales], valid) -> (scores, global row idx) with
-    pages (and int8 scales) row-sharded over 'data'. Cached per
-    (mesh, k, chunk, scaled); jit retraces per pages dtype within a key."""
+    """Jitted (q, pages[, scales], valid) -> packed [Bq, 2k] int32
+    (`pack_topk` of scores and global row idx) with pages (and int8
+    scales) row-sharded over 'data'. Cached per (mesh, k, chunk, scaled);
+    jit retraces per pages dtype within a key."""
     n_data = mesh.shape["data"]
 
     def run(q, pages_local, scales_local, valid):
@@ -148,10 +172,10 @@ def _build_sharded_topk(mesh: Mesh, k: int, chunk: int, scaled: bool):
             top_s, pos = lax.top_k(cat_s, kk)
             top_i = jnp.take_along_axis(cat_i, pos, axis=1)
             top_i = jnp.where(jnp.isfinite(top_s), top_i, -1)
-        return top_s, top_i
+            return pack_topk(top_s, top_i)
 
     # After the all_gather every shard computes the identical merge, so the
-    # P() outputs ARE replicated over 'data' — but that's a dynamic fact the
+    # P() output IS replicated over 'data' — but that's a dynamic fact the
     # static varying-axis checker can't infer; check_vma=False is the
     # documented escape hatch for exactly this collective-then-merge shape.
     if scaled:
@@ -161,7 +185,7 @@ def _build_sharded_topk(mesh: Mesh, k: int, chunk: int, scaled: bool):
         fn = lambda q, pages, valid: run(q, pages, None, valid)  # noqa: E731
         in_specs = (P(), P("data"), P())
     mapped = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                           out_specs=(P(), P()), check_vma=False)
+                           out_specs=P(), check_vma=False)
     return jax.jit(mapped)
 
 
@@ -170,9 +194,10 @@ def sharded_topk_fn(mesh: Mesh, k: int, chunk: int = 8192,
     """The jitted scan `sharded_topk` launches, for a caller that resolves
     it once and then launches it per shard on arguments it already holds
     on the device (`SearchService._dispatch_bucket`): (q, pages, valid),
-    or (q, pages, scales, valid) when `scaled`. The caller owns what the
-    wrapper checks: pages rows divide mesh 'data', `valid` is an int32
-    scalar."""
+    or (q, pages, scales, valid) when `scaled`, giving ONE packed int32
+    [Bq, 2k] array a launch (`pack_topk`), left on the device. The caller
+    owns what the wrapper checks: pages rows divide mesh 'data', `valid`
+    is an int32 scalar."""
     key = (mesh, int(k), int(chunk), bool(scaled))
     fn = _SHARDED_CACHE.get(key)
     if fn is None:
@@ -182,13 +207,15 @@ def sharded_topk_fn(mesh: Mesh, k: int, chunk: int = 8192,
 
 def sharded_topk(q: jnp.ndarray, pages, mesh: Mesh, k: int = 10,
                  chunk: int = 8192, valid: int | jax.Array | None = None,
-                 scales=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                 scales=None) -> Tuple[np.ndarray, np.ndarray]:
     """Top-k with pages [N, D] row-sharded over the mesh 'data' axis.
 
     N must divide by mesh 'data'; rows >= `valid` are padding (score -inf,
-    index -1). q is replicated. Returns replicated (scores, indices) with
-    indices global into the sharded row order. `pages` may be fp16 rows or
-    int8 codes with per-row `scales` [N] — widened on-device (_topk_scan).
+    index -1). q is replicated. Returns (scores, indices) ON THE HOST,
+    indices global into the sharded row order: the scan's one packed array
+    pulled in one transfer and split there (`unpack_topk`). `pages` may be
+    fp16 rows or int8 codes with per-row `scales` [N] — widened on-device
+    (_topk_scan).
 
     `valid` is a Python int, made into a device scalar HERE (one small
     program per call: fine for a sweep that also stages a shard per call),
@@ -202,7 +229,8 @@ def sharded_topk(q: jnp.ndarray, pages, mesh: Mesh, k: int = 10,
                          f"{mesh.shape['data']}; pad the input")
     v = valid if isinstance(valid, jax.Array) else jnp.int32(
         N if valid is None else valid)
-    return fn(q, pages, v) if scales is None else fn(q, pages, scales, v)
+    packed = fn(q, pages, v) if scales is None else fn(q, pages, scales, v)
+    return unpack_topk(np.asarray(packed))
 
 
 @partial(jax.jit, static_argnames=("k",))
@@ -349,7 +377,6 @@ def merge_shard_topk(q: jnp.ndarray, pages, page_ids: np.ndarray, valid: int,
         return best_s, best_i
     sc, idx = sharded_topk(q, pages, mesh, k=k, chunk=chunk, valid=valid,
                            scales=scales)
-    sc, idx = np.asarray(sc), np.asarray(idx)
     pids = np.where(
         idx >= 0, page_ids[np.clip(idx, 0, valid - 1)], -1)
     return merge_topk_host(best_s, best_i,

@@ -36,9 +36,12 @@ def launches(tmp_path):
     """`with launches() as seen:` records a profiler trace around its body
     and, once the body is done, fills `seen` from the host tracer's events:
     `programs`, how many compiled programs the CPU client was handed (one
-    `PjRtCpuExecutable::Execute` per launch, whatever the mesh), and
-    `jitted`, the names of the jitted functions that were called
-    (`PjitFunction(<name>)`): what the chip's trace counts per XLA module."""
+    `PjRtCpuExecutable::Execute` per launch, whatever the mesh), `jitted`,
+    the names of the jitted functions that were called
+    (`PjitFunction(<name>)`): what the chip's trace counts per XLA module,
+    and `pulls`, how many device arrays were brought to the host (jax's own
+    `np.asarray(jax.Array)` event, one per array: the CPU backend's
+    transfer guard lets a device-to-host copy pass, so it cannot count)."""
     import contextlib
     import glob
     import re
@@ -48,7 +51,7 @@ def launches(tmp_path):
     def record():
         import jax
         from jax.profiler import ProfileData
-        seen = {"programs": 0, "jitted": set()}
+        seen = {"programs": 0, "jitted": set(), "pulls": 0}
         trace_dir = tempfile.mkdtemp(prefix="launches_", dir=tmp_path)
         with jax.profiler.trace(trace_dir):
             yield seen
@@ -61,6 +64,8 @@ def launches(tmp_path):
                 for ev in line.events:
                     if ev.name == "PjRtCpuExecutable::Execute":
                         seen["programs"] += 1
+                    if ev.name == "np.asarray(jax.Array)":
+                        seen["pulls"] += 1
                     m = re.fullmatch(r"PjitFunction\((.*)\)", ev.name)
                     if m:
                         seen["jitted"].add(m.group(1))

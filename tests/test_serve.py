@@ -222,11 +222,15 @@ def test_bucket_is_one_launch_per_shard_and_moves_nothing(
     """Everything a shard's launch needs was made when the view was staged:
     a warmed bucket runs with host-to-device transfers disallowed (only the
     query block's explicit put goes up) and launches one scan per shard and
-    one merge, no other program; the answers are the streaming sweep's."""
+    one merge, no other program; each scan hands the merge ONE packed array,
+    so the merge binds as many arguments as there are resident shards; the
+    answers are the streaming sweep's, and bit for bit those of a bucket
+    that kept every shard's scores and row ids apart."""
     import jax
     from jax.sharding import Mesh
 
-    from dnn_page_vectors_tpu.ops.topk import merge_topk_host, topk_over_store
+    from dnn_page_vectors_tpu.ops.topk import (
+        merge_topk_host, sharded_topk, topk_over_store)
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     svc = _resident_view(str(tmp_path / "store"), mesh, case)
     view, k = svc._view, 7
@@ -239,12 +243,37 @@ def test_bucket_is_one_launch_per_shard_and_moves_nothing(
     blocks = svc._qv_blocks(view, qv)
     assert sorted(blocks) == stamps
     svc._collect_bucket(view, *svc._dispatch_bucket(view, blocks, k), k)
+    merge, merged = view.merge, []
+    view.merge = lambda cands: merged.append(cands) or merge(cands)
     with launches() as seen, jax.transfer_guard_host_to_device("disallow"):
         bucket = svc._dispatch_bucket(view, blocks, k)
     got_s, got_i = svc._collect_bucket(view, *bucket, k)
     assert seen["programs"] == len(view.shards) + 1
     assert seen["jitted"] == {"run" if case == "int8" else "<lambda>",
                               "merge"}
+    # the merge binds ONE array a resident shard: the scan's packed result
+    (cands,) = merged
+    leaves = jax.tree_util.tree_leaves(cands)
+    assert len(leaves) == len(view.shards)
+    assert all(isinstance(c, jax.Array) and c.dtype == np.int32
+               and c.shape == (svc.query_batch, 2 * k) for c in leaves)
+    # bit for bit the bucket of two arrays a shard: each shard's scores
+    # and row ids apart, side by side in shard order, the k best by a
+    # stable sort (lax.top_k's order on ties), through the id table
+    qs = bucket[1]
+    parts = [sharded_topk(qs[st], shard.pages, mesh, k=k, valid=shard.valid,
+                          scales=shard.scales)
+             for st, shard in zip(view.shard_steps, view.shards)]
+    cat_s = np.concatenate([s for s, _ in parts], axis=1)[:5]
+    cat_i = np.concatenate(
+        [np.where(i >= 0, i + slot * view.pad_rows, -1)
+         for slot, (_, i) in enumerate(parts)], axis=1)[:5]
+    pos = np.argsort(-cat_s, axis=1, kind="stable")[:, :k]
+    np.testing.assert_array_equal(
+        got_s.view(np.int32),
+        np.take_along_axis(cat_s, pos, axis=1).view(np.int32))
+    np.testing.assert_array_equal(
+        got_i, view.pid_table[np.take_along_axis(cat_i, pos, axis=1)])
     want_s = np.full((5, k), -np.inf, np.float32)
     want_i = np.full((5, k), -1, np.int64)
     for st in stamps:

@@ -585,8 +585,7 @@ def test_device_merge_carries_its_scope_and_keeps_its_name(served):
     svc = _svc(served, preload=4.0)
     try:
         view = svc._view
-        cands = [(jnp.zeros((4, 5)), jnp.zeros((4, 5), jnp.int32))
-                 for _ in view.shards]
+        cands = [jnp.zeros((4, 10), jnp.int32) for _ in view.shards]
         text = view.merge.lower(cands).as_text(debug_info=True)
     finally:
         svc.close()

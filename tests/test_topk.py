@@ -82,6 +82,126 @@ def test_sharded_topk_takes_valid_as_a_device_scalar(eight_devices, launches,
     assert seen["jitted"] == {"<lambda>", "convert_element_type"}
 
 
+def _exact_rows(seed, n, dim, scaled):
+    """Queries and rows whose dot products are exact in float32 whatever
+    the blocking (multiples of 1/64, sums far inside 24 bits), so that two
+    programs that chunk differently still give the same bits: float16
+    rows, or int8 codes with power-of-two scales."""
+    rng = np.random.default_rng(seed)
+    q = (rng.integers(-256, 257, (6, dim)) / 64).astype(np.float32)
+    if not scaled:
+        rows = (rng.integers(-128, 129, (n, dim)) / 64).astype(np.float16)
+        return q, rows, None, rows.astype(np.float32)
+    codes = rng.integers(-127, 128, (n, dim)).astype(np.int8)
+    scales = (2.0 ** rng.integers(-8, -4, n)).astype(np.float16)
+    return q, codes, scales, (codes.astype(np.float32)
+                              * scales.astype(np.float32)[:, None])
+
+
+@pytest.mark.parametrize("valid", [40, 5])
+@pytest.mark.parametrize("scaled", [False, True],
+                         ids=["float16", "int8_scales"])
+def test_scan_hands_back_one_packed_array(eight_devices, scaled, valid):
+    """What a launch of the jitted scan returns: ONE int32 [Bq, 2k] array,
+    the scores' bits then the row ids, for the unscaled and the scaled
+    program alike; unpacked it is `chunked_topk` over the valid rows bit
+    for bit, padding slots -inf / -1."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from dnn_page_vectors_tpu.ops.topk import sharded_topk_fn, unpack_topk
+    mesh = make_mesh(MeshConfig(data=2))
+    k = 9
+    q, rows, scales, wide = _exact_rows(11, 64, 16, scaled)
+    put = lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec))  # noqa: E731
+    args = [put(q, P()), put(rows, P("data"))]
+    if scaled:
+        args.append(put(scales, P("data")))
+    args.append(put(np.int32(valid), P()))
+    packed = sharded_topk_fn(mesh, k, chunk=16, scaled=scaled)(*args)
+    assert isinstance(packed, jax.Array)
+    assert packed.dtype == jnp.int32 and packed.shape == (6, 2 * k)
+    got_s, got_i = unpack_topk(np.asarray(packed))
+    want_s, want_i = chunked_topk(jnp.asarray(q), jnp.asarray(wide[:valid]),
+                                  k=k, chunk=16)
+    assert got_s.dtype == np.float32 and got_i.dtype == np.int32
+    np.testing.assert_array_equal(got_s.view(np.int32),
+                                  np.asarray(want_s).view(np.int32))
+    np.testing.assert_array_equal(got_i, np.asarray(want_i))
+    if valid < k:
+        assert np.isneginf(got_s[:, valid:]).all()
+        assert (got_i[:, valid:] == -1).all()
+    # the same split inside a jitted caller (the serving merge's use)
+    dev_s, dev_i = jax.jit(unpack_topk)(packed)
+    np.testing.assert_array_equal(np.asarray(dev_s).view(np.int32),
+                                  got_s.view(np.int32))
+    np.testing.assert_array_equal(np.asarray(dev_i), got_i)
+
+
+@pytest.mark.parametrize("scaled", [False, True],
+                         ids=["float16", "int8_scales"])
+def test_merge_shard_topk_pulls_one_array(eight_devices, launches, scaled):
+    """Folding a shard into the host merge brings ONE array down (the
+    packed scan result; scores and ids came separately before) and gives
+    what the two-array fold gave: `chunked_topk`'s scores and ids pulled
+    apart, mapped through the page ids and merged."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from dnn_page_vectors_tpu.ops.topk import merge_shard_topk
+    mesh = make_mesh(MeshConfig(data=2))
+    k, valid = 9, 50
+    q, rows, scales, wide = _exact_rows(12, 64, 16, scaled)
+    page_ids = np.arange(7000, 7000 + valid, dtype=np.int64)[::-1].copy()
+    best_s = np.sort(np.random.default_rng(5).normal(size=(6, k)).astype(
+        np.float32) * 20, axis=1)[:, ::-1].copy()
+    best_i = np.arange(6 * k, dtype=np.int64).reshape(6, k)
+    qd = jax.device_put(q, NamedSharding(mesh, P()))
+    pages = jax.device_put(rows, NamedSharding(mesh, P("data")))
+    scl = (None if scales is None
+           else jax.device_put(scales, NamedSharding(mesh, P("data"))))
+    merge_shard_topk(qd, pages, page_ids, valid, mesh, k, best_s, best_i,
+                     chunk=16, scales=scl)                         # warm
+    with launches() as seen:
+        got_s, got_i = merge_shard_topk(qd, pages, page_ids, valid, mesh, k,
+                                        best_s, best_i, chunk=16, scales=scl)
+    assert seen["pulls"] == 1
+    sc, idx = chunked_topk(jnp.asarray(q), jnp.asarray(wide[:valid]), k=k,
+                           chunk=16)
+    sc, idx = np.asarray(sc), np.asarray(idx)
+    want_s, want_i = merge_topk_host(
+        best_s, best_i, sc, np.where(idx >= 0, page_ids[idx], -1))
+    np.testing.assert_array_equal(got_s.view(np.int32),
+                                  want_s.view(np.int32))
+    np.testing.assert_array_equal(got_i, want_i)
+
+
+def test_scan_keeps_the_name_the_roofline_finds_it_by(eight_devices):
+    """`sharded_topk_roofline` finds the scan in a trace by its compiled
+    module's name (`benchmarks/workloads/bert_mini.serve_exact.json`,
+    `trace_modules.scan`, read here and never written): the unscaled scan
+    compiles under exactly that name, as the reducer strips it."""
+    import json
+    import os
+
+    import jax
+
+    from benchmarks import trace_reduce
+    from dnn_page_vectors_tpu.ops.topk import sharded_topk_fn
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "workloads",
+                           "bert_mini.serve_exact.json")) as f:
+        want = json.load(f)["trace_modules"]["scan"]
+    mesh = make_mesh(MeshConfig(data=1))
+    compiled = sharded_topk_fn(mesh, 10).lower(
+        jax.ShapeDtypeStruct((8, 16), jnp.float32),
+        jax.ShapeDtypeStruct((64, 16), jnp.float16),
+        jax.ShapeDtypeStruct((), jnp.int32)).compile()
+    name = compiled.runtime_executable().hlo_modules()[0].name
+    assert trace_reduce.module_name(f"{name}(1234567890)") == want
+    assert trace_reduce.module_name(name) == want
+
+
 def test_topk_over_store_matches_brute_force(eight_devices, tmp_path):
     """Streaming the store shard-by-shard over the mesh must equal one giant
     in-memory search — no step materializes the full store."""
