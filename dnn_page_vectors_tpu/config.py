@@ -231,7 +231,7 @@ class ServeConfig:
     index: str = "exact"
     # IVF lists probed per query: the recall-vs-cost dial. Expected scanned
     # fraction ~ nprobe/nlist; recall-vs-exact is measured, not assumed
-    # (evals.recall.recall_vs_exact, bench ann_recall_at_10).
+    # (evals.recall.recall_vs_exact, pinned by tests/test_ivf_index.py).
     nprobe: int = 8
     # IVF list count for `cli index` builds. 0 = auto (~sqrt(store rows)).
     nlist: int = 0

@@ -85,7 +85,7 @@ def recall_vs_exact(index, store: VectorStore, query_vecs: np.ndarray,
     each query's exact top-k (topk_over_store) that the IVF index also
     returns at this `nprobe`. This is the index-quality contract
     (docs/ANN.md) — independent of model quality, unlike gold-id recall —
-    and lands in the bench record as `ann_recall_at_10`."""
+    and is what tests/test_ivf_index.py holds an index to."""
     qv = np.asarray(query_vecs, np.float32)
     if qv.shape[0] == 0:
         return 0.0

@@ -10,7 +10,7 @@ rows of its top-`nprobe` lists from the store's memory-mapped shards (int8
 codes at stored width — dequant fuses into the re-rank matmul), and
 exact-reranks that candidate block with `ops.topk.rerank_candidates`.
 Recall-vs-exact is a measured contract (`evals.recall.recall_vs_exact`,
-bench `ann_recall_at_10`), not a hope.
+pinned by tests/test_ivf_index.py), not a hope.
 
 Layout (next to the store, same manifest machinery as VectorStore):
 
@@ -429,7 +429,7 @@ class IVFIndex:
 
         Returns (index, info) where info["action"] is "noop" |
         "incremental" | "rebuild" plus the decision inputs, so callers
-        (SearchService.refresh, cli refresh, bench) can count
+        (SearchService.refresh, cli refresh) can count
         incremental_updates vs full_rebuilds. Raises (IOError etc.) only
         when the write path itself fails — the manifest is untouched then,
         so readers keep the previous index generation.

@@ -50,8 +50,8 @@ which is this build-beside-then-publish swap.
 Host simulation vs production: a replica worker thread stands in for one
 serving host. On a multi-core host the scatter is real parallelism (the
 scan work runs under released-GIL device/numpy calls); on the 1-core
-build sandbox wall-clock threads cannot show multi-host scaling, so the
-bench's `partitioned_serve` phase uses `simulate()` — sequential
+build sandbox wall-clock threads cannot show multi-host scaling, so
+`simulate()` accounts for it instead (tests/test_partition.py) — sequential
 per-partition execution with critical-path accounting (simulated latency
 = max over partitions + the measured merge fold), the honest
 one-box simulation of P independent hosts.
@@ -166,7 +166,7 @@ class _PartitionReplica:
         return fut
 
     def run_inline(self, fn):
-        """Execute one task ON THE CALLER (the bench's host-simulation
+        """Execute one task ON THE CALLER (`simulate()`'s host-simulation
         mode): returns (result, seconds). Sequential execution keeps the
         per-partition timing free of same-core thread contention — the
         measured seconds are one simulated host's critical path."""
@@ -402,7 +402,7 @@ class PartitionSet:
 
     def simulate(self, qv: np.ndarray, n: int, k: int,
                  nprobe: Optional[int] = None, predicate=None) -> Dict:
-        """Host-simulation mode (bench `partitioned_serve` phase): run
+        """Host-simulation mode (one box standing in for P hosts): run
         every partition's task SEQUENTIALLY on the caller, timing each,
         then the merge fold. The simulated per-query latency is the
         critical path max(partition seconds) + merge seconds — what P

@@ -604,7 +604,7 @@ class WorkerGateway:
 
     def wait_for_workers(self, n: int, timeout_s: float = 30.0) -> bool:
         """Block until `n` workers are live (fleet-start barrier for
-        cli/bench) — False on timeout, after recording WHAT the barrier
+        cli loadtest) — False on timeout, after recording WHAT the barrier
         waited for and the fleet state it saw (`gateway_wait_timeout`
         event + the stats() wait_timeouts counter): a silent False is
         undebuggable once re-splits make barriers routine."""
@@ -1219,7 +1219,7 @@ class PartitionWorker:
         # only ships the VQUERY filter field after confirming the flag
         self.filters = bool(getattr(cfg.serve, "filters", True))
         self._block_cache_cap = 64   # per-link block-cache entries
-        # drill hook (tests, the bench hedge drill): added per-request
+        # drill hook (tests/test_net.py's hedge drill): added per-request
         # latency, so a deliberately slow replica provokes hedging
         self.slow_ms = float(slow_ms)
         if mesh is None:
@@ -1566,7 +1566,7 @@ class PartitionWorker:
             self._tear(ln.sock)
 
     def kill_connection(self) -> None:
-        """Drill hook (tests, the bench chaos drill): tear every live
+        """Drill hook (tests/test_chaos.py's kill drill): tear every live
         connection out from under its serve loop WITHOUT stopping the
         worker — the supervised link loops re-dial and re-REGISTER,
         which is exactly the recovery path the chaos drills measure."""

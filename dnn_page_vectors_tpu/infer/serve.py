@@ -766,7 +766,7 @@ class SearchService:
 
     # serving counters are registry instruments (docs/OBSERVABILITY.md);
     # these read-only windows keep the pre-registry attribute surface that
-    # tests, bench, and operator scripts already use
+    # tests, `cli loadtest` and operator scripts already use
     @property
     def cache_hits(self) -> int:
         return self._m_cache_hits.value
@@ -1891,7 +1891,7 @@ class SearchService:
         rebuilds are DEFERRED off the refresh() caller from here on — the
         worker builds the next index generation beside the live one.
         `threads=False` attaches without spawning workers (callers drive
-        `run_once()` themselves: the loadtest mutator, bench). Idempotent;
+        `run_once()` themselves: the loadtest mutator, tests). Idempotent;
         close() stops it."""
         if self._maintenance is None:
             from dnn_page_vectors_tpu.maintenance import MaintenanceService
@@ -2324,8 +2324,8 @@ class SearchService:
                      filters=None) -> tuple:
         """Raw retrieval for PRE-COMPUTED query vectors: (scores [n, k]
         fp32, page_ids [n, k] int64, -1-padded), skipping tokenize/encode
-        and snippet formatting. The bench's host-simulated partitioned
-        phase, the network front end's vector protocol, and vector-level
+        and snippet formatting. `PartitionSet.simulate`'s host-simulated
+        scatter, the network front end's vector protocol, and vector-level
         tests drive the full serving top-k (RPC fan-out, partitioned, or
         single-view) through this without a model."""
         k = k or self.cfg.eval.recall_k
@@ -2351,8 +2351,8 @@ class SearchService:
         whole retrieval of the single-view path. `scan_bytes` is the
         candidate payload this view scanned to answer: the ANN gather
         bytes, or the view's full row bytes on the exact path — the
-        per-partition critical-path byte count the partitioned bench
-        phase records (drops ~1/P under partitioning)."""
+        per-partition critical-path byte count `PartitionSet.simulate`
+        reports (drops ~1/P under partitioning)."""
         qv = np.asarray(qv, np.float32)
         blocks = self._qv_blocks(view, qv)
         if self._serve_index == "ivf":
@@ -2453,7 +2453,7 @@ class SearchService:
         `scan_bytes` counts the attribute words read (4 B/row over the
         view) plus the matching rows' stored payload: a predicate of
         selectivity s scans ~s× the unfiltered exact bytes — the number
-        bench.py's filtered phase records against its <=0.3x gate."""
+        tests/test_filtered.py holds to <=0.3x at selectivity 0.1."""
         row_bytes = view.store.row_bytes
         fallback = next(iter(blocks.values()))
         out_s = np.full((n, k), -np.inf, np.float32)

@@ -437,7 +437,7 @@ class SearchServer:
 def serve_in_background(svc, host: Optional[str] = None,
                         port: Optional[int] = None,
                         front_end: int = 0) -> SearchServer:
-    """One-call server hosting for cli/bench/tests: binds (serve.listen
+    """One-call server hosting for the cli and tests: binds (serve.listen
     unless overridden), runs the loop on a daemon thread, returns the
     handle (`.host` / `.port` / `.close()`). `front_end` labels this
     server's slot in a scale-out tier (cli loadtest --front-ends)."""

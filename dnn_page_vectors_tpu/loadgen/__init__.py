@@ -7,8 +7,8 @@
     read from the PR-7 telemetry registry, and the binary search for
     "qps @ p99 < X ms".
 
-Entry points: `cli loadtest` (one-shot report), the bench `slo` phase
-(regression-gated trajectory), and `tests/test_loadgen.py` (the `slo`
+Entry points: `cli loadtest` (one-shot report: `run_trial` and the
+`find_qps_at_p99` search) and `tests/test_loadgen.py` (the `slo`
 marker).
 """
 from dnn_page_vectors_tpu.loadgen.driver import (

@@ -1,7 +1,7 @@
 """Seeded workload models for the SLO harness (docs/SERVING.md).
 
 A production latency objective is meaningless without saying what traffic
-it holds under — and the bench serve phase's "N threads hammer as fast as
+it holds under — and "N threads hammer the service as fast as
 they can" is CLOSED-loop traffic: when the service slows down, the
 offered load politely slows down with it, which is exactly the
 coordination that hides latency cliffs (the coordinated-omission trap).
@@ -30,8 +30,8 @@ compiled top-k shapes the way mixed tenants would.
 Determinism: everything derives from ONE integer seed. `schedule()` and
 `worker_stream()` re-derive their RNG from (seed, call parameters) on
 every call, so two runs with the same seed produce IDENTICAL offered-load
-schedules — the property the acceptance test pins and the reason a bench
-regression between rounds means the SERVICE changed, not the traffic.
+schedules — the property the acceptance test pins and the reason a
+difference between two runs means the SERVICE changed, not the traffic.
 
 The optional `Mutator` wraps an append/refresh callable with a period, so
 the driver can exercise the zero-downtime hot-swap path (docs/UPDATES.md)
@@ -301,7 +301,7 @@ def make_workload(shape: str, *, seed: int = 0, distinct: int = 64,
                   think_s: float = 0.0,
                   filter_scenarios: Optional[FilterScenarios] = None
                   ) -> Workload:
-    """One factory for the CLI/bench/driver: shape name -> Workload."""
+    """One factory for the CLI and the driver: shape name -> Workload."""
     mix = QueryMix(distinct, alpha=alpha, profile=profile,
                    filter_scenarios=filter_scenarios)
     if shape == "poisson":

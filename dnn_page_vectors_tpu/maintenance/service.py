@@ -247,7 +247,7 @@ class MaintenanceService:
     def run_once(self) -> Dict:
         """One synchronous pass of every pillar (janitor first so a
         crashed prior run's debris never confuses the triggers) — the
-        `cli maintain --once` / bench / loadgen-mutator entry point.
+        `cli maintain --once` / loadgen-mutator / tests entry point.
         Works with or without the background threads running."""
         out: Dict[str, Dict] = {}
         with self._mlock:

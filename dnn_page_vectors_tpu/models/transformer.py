@@ -84,12 +84,11 @@ class Attention(nn.Module):
         head_dim = self.model_dim // self.num_heads
         B, L, _ = x.shape
         # Three separate projections, DELIBERATELY not fused into one [d,3d]
-        # dot: measured on v5e (round 4), the fused dot wins 2.7x in
-        # isolation (x read once, wider N) but LOSES 2-10% inside the full
-        # model — the post-matmul q/k/v slices materialize three [B,L,H,Dh]
-        # copies and XLA already overlaps the separate dots with neighboring
-        # work. Interleaved A/B at bench shapes: fused 59.8/15.7 ms
-        # (train/embed), separate 59.0/14.1 ms. See docs/MFU.md.
+        # dot: a fused dot reads x once and has a wider N, so it wins in
+        # isolation, but inside the full model its post-matmul q/k/v slices
+        # materialize three [B,L,H,Dh] copies, and XLA already overlaps the
+        # separate dots with neighboring work (docs/MFU.md "What does not
+        # help"; not measured on this code).
         dense = lambda name: nn.Dense(self.model_dim, use_bias=self.use_bias,
                                       dtype=self.dtype, name=name)
         shape = (B, L, self.num_heads, head_dim)
@@ -166,9 +165,9 @@ class Block(nn.Module):
         h = norm("ln_mlp")(x)
         if self.variant == "t5":  # gated GELU, no biases (mT5 geometry)
             # separate gate/value dots, DELIBERATELY not fused into one
-            # [d, 2*mlp] projection: measured at mT5-base geometry on v5e
-            # (round 4), the fused variant's post-matmul de-interleave made
-            # the forward 34% slower (62.7 vs 46.8 ms). See docs/MFU.md.
+            # [d, 2*mlp] projection: the fused variant needs a post-matmul
+            # de-interleave of gate and value, a relayout of the widest
+            # activation of the block. See docs/MFU.md "What does not help".
             wi0 = nn.Dense(self.mlp_dim, use_bias=False, dtype=self.dtype,
                            name="wi_0")(h)
             wi1 = nn.Dense(self.mlp_dim, use_bias=False, dtype=self.dtype,

@@ -119,7 +119,7 @@ class Trainer:
                  hard_negative_lookup=None, workdir: Optional[str] = None,
                  tokenizers: Optional[Tuple[Any, Any]] = None):
         """`tokenizers=(query_tok, page_tok)` bypasses build_tokenizer —
-        anything with .vocab_size and .encode_batch works. Used by bench.py
+        anything with .vocab_size and .encode_batch works. A caller uses it
         to drive true-vocab-size embedding tables with synthetic ids
         (training a 250k SentencePiece is data prep, not step cost)."""
         self.cfg = cfg
@@ -334,7 +334,7 @@ class Trainer:
         pages_per_step = cfg.train.batch_size
         n_dev = self.mesh.devices.size
         # MFU next to pages/sec/chip so every logged rate is interpretable
-        # against hardware peak (same analytic counts as bench.py)
+        # against hardware peak (analytic counts: utils/flops.py)
         from dnn_page_vectors_tpu.utils.flops import (
             device_peak_flops, train_flops_per_pair)
         peak = device_peak_flops(self.mesh.devices.flat[0])

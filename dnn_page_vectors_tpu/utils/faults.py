@@ -19,8 +19,8 @@ survives actual failures. This module supplies both halves of the proof:
 
   * module-level fault COUNTERS — every injected fault, retry, shard
     quarantine, checkpoint rollback, and serve degradation bumps a named
-    counter, surfaced through the metrics logs (train/embed/serve) and the
-    bench record so recovery-path activity is observable, not silent.
+    counter, surfaced through the metrics logs (train/embed/serve) and
+    the CLI's JSON lines so recovery-path activity is observable.
 
 Injection points (op names):
   shard_write    write_shard data-file write (check; inside retry) — both

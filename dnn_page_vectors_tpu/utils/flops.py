@@ -1,12 +1,12 @@
-"""Analytic FLOP counts + per-chip peak-FLOPs table, for MFU reporting.
+"""Analytic FLOP counts + per-chip peak tables, for MFU reporting.
 
-The bench (bench.py) reports model FLOPs utilisation next to pages/sec/chip
-so a throughput number is interpretable — without an analytic FLOPs/step
-nobody can tell whether a measured rate is 5% or 50% of the hardware peak
-(VERDICT round 1, weak #7). Counts follow the standard convention: one
-multiply-accumulate = 2 FLOPs; embedding gathers, softmax, layernorm and
-other vector ops are excluded (they are bandwidth-, not FLOP-, bound and
-conventionally left out of MFU math).
+`Trainer.train` logs model FLOPs utilisation next to pages/sec/chip so a
+throughput number is interpretable — without an analytic FLOPs/step
+nobody can tell whether a measured rate is 5% or 50% of the hardware peak.
+Counts follow the standard convention: one multiply-accumulate = 2 FLOPs;
+embedding gathers, softmax, layernorm and other vector ops are excluded
+(they are bandwidth-, not FLOP-, bound and conventionally left out of MFU
+math).
 """
 from __future__ import annotations
 
@@ -78,8 +78,8 @@ def train_flops_per_pair(cfg: Config, batch_size: int,
     per-page page-tower cost is the row cost / pack. This is the row the
     device actually computes (segment masking zeroes scores, it does not
     skip tiles), so MFU stays an honest achieved-FLOPs ratio; the
-    packing WIN shows up as pages/sec — and in useful-FLOPs terms via
-    bench.py's long_pack phase (docs/MFU.md "packing accounting")."""
+    packing WIN shows up as pages/sec, and in useful-FLOPs terms by the
+    accounting of docs/MFU.md "Packing accounting"."""
     m, d = cfg.model, cfg.data
     H = cfg.train.hard_negatives
     pack = max(1, cfg.train.pack_pages if pack is None else pack)
@@ -95,87 +95,6 @@ def train_flops_per_pair(cfg: Config, batch_size: int,
 def embed_flops_per_page(cfg: Config) -> float:
     """Matmul FLOPs to embed one page (forward only)."""
     return encoder_flops_per_example(cfg.model, cfg.data.page_len)
-
-
-# ---------------------------------------------------------------------------
-# Roofline accounting (round 11, docs/MFU.md "roofline methodology"):
-# MFU against the bf16 matmul peak is the wrong lens for encoders that
-# barely matmul — kim_cnn/lstm spend their step in the [vocab, E]
-# embedding gather/scatter and short convolutions/recurrences, so 3% "MFU"
-# reads as a bug when it is the workload. The meaningful utilization
-# number is achieved rate vs the ANALYTIC ROOFLINE: the lower of the
-# compute ceiling (peak_flops / flops_per_pair) and the memory ceiling
-# (peak_hbm_bw / bytes_per_pair). The bench reports <phase>_roofline_util
-# plus which wall binds next to every MFU column.
-# ---------------------------------------------------------------------------
-
-def encoder_param_count(m: ModelConfig, vocab_size: int) -> float:
-    """Approximate parameter count of ONE tower (embedding included)."""
-    if m.encoder in ("bert", "t5"):
-        d, ff = m.model_dim, m.mlp_dim
-        mlp = 3 * d * ff if m.encoder == "t5" else 2 * d * ff
-        per_layer = 4 * d * d + mlp
-        return float(vocab_size * d + m.num_layers * per_layer
-                     + d * m.out_dim)
-    if m.encoder in ("cdssm", "kim_cnn"):
-        E, C = m.embed_dim, m.conv_channels
-        conv = sum(w * E * C for w in m.conv_widths)
-        return float(vocab_size * E + conv
-                     + len(m.conv_widths) * C * m.out_dim)
-    if m.encoder == "lstm":
-        H = m.model_dim
-        per_dir, e_in = 0.0, m.embed_dim
-        for _ in range(m.num_layers):
-            per_dir += e_in * 4 * H + H * 4 * H
-            e_in = 2 * H
-        return float(vocab_size * m.embed_dim + 2 * per_dir
-                     + 2 * H * m.out_dim)
-    raise ValueError(f"no param model for encoder {m.encoder!r}")
-
-
-def _act_bytes_per_example(m: ModelConfig, seq_len: int) -> float:
-    """Rough activation HBM traffic per sequence, fwd+bwd (2-byte compute
-    dtype; passes counted from the fused-op structure, not per-op)."""
-    if m.encoder in ("bert", "t5"):
-        d, ff = m.model_dim, m.mlp_dim
-        # per layer: ~10 passes over [L, d] (attn in/out, residuals, LN,
-        # fwd+bwd) + ~6 over the [L, ff] MLP hidden (fwd gelu + bwd)
-        per_tok = m.num_layers * (10 * d + 6 * ff) + 4 * d
-        return float(seq_len * per_tok * 2)
-    if m.encoder in ("cdssm", "kim_cnn"):
-        E, C = m.embed_dim, m.conv_channels
-        per_tok = 3 * E + 4 * len(m.conv_widths) * C
-        return float(seq_len * per_tok * 2)
-    if m.encoder == "lstm":
-        H = m.model_dim
-        # gate math runs f32 (4 bytes); x_proj [L, 4H] both directions
-        per_tok = m.embed_dim * 2 + 2 * (4 * H + 2 * H) * 4
-        return float(seq_len * per_tok * m.num_layers)
-    raise ValueError(f"no activation model for encoder {m.encoder!r}")
-
-
-def train_bytes_per_pair(cfg: Config, batch_size: int) -> float:
-    """Analytic HBM bytes per (query, page) pair for one optimizer step:
-    embedding-table gather (fwd) + dense-grad scatter/update (bwd),
-    activation traffic for both towers, and the batch-amortized
-    parameter + adamw-moment traffic. Deliberately coarse (a roofline
-    denominator, not a simulator) — assumptions in docs/MFU.md."""
-    m, d = cfg.model, cfg.data
-    H = cfg.train.hard_negatives
-    vocab = (d.trigram_buckets if d.tokenizer == "trigram" else d.vocab_size)
-    embed_width = m.model_dim if m.encoder in ("bert", "t5") else m.embed_dim
-    tokens = d.query_len + (1 + H) * d.page_len
-    # gather fwd (2B compute dtype) + scatter-add bwd (read+write f32)
-    embed_traffic = tokens * embed_width * (2 + 2 * 4)
-    acts = (_act_bytes_per_example(m, d.query_len)
-            + (1 + H) * _act_bytes_per_example(m, d.page_len))
-    # params: read fwd + read bwd + f32 grad write + adamw update
-    # (p, m, v read+write) ≈ 10 f32-equivalent accesses, amortized over
-    # the batch; two towers unless shared
-    towers = 1 if m.shared_towers else 2
-    params = towers * encoder_param_count(m, vocab)
-    opt = params * 4 * 10 / max(1, batch_size)
-    return float(embed_traffic + acts + opt)
 
 
 # Per-chip peak HBM bandwidth (bytes/s) by device_kind substring.
@@ -212,18 +131,6 @@ def _peak_for(device, table, what: str) -> Optional[float]:
 def device_peak_hbm_bps(device) -> Optional[float]:
     """Per-device peak HBM bandwidth in bytes/s, or None off-TPU."""
     return _peak_for(device, _PEAK_HBM, "peak HBM bandwidth")
-
-
-def roofline(flops_per_pair: float, bytes_per_pair: float,
-             peak_flops: Optional[float], peak_bw: Optional[float]):
-    """(ceiling pairs/sec, binding wall) — the lower of the compute and
-    memory ceilings; None when the device peaks are unknown (CPU)."""
-    if not peak_flops or not peak_bw:
-        return None, None
-    compute = peak_flops / max(flops_per_pair, 1.0)
-    memory = peak_bw / max(bytes_per_pair, 1.0)
-    return (min(compute, memory),
-            "compute" if compute <= memory else "bandwidth")
 
 
 # Per-chip peak dense bf16 FLOP/s by `jax.Device.device_kind` substring.

@@ -19,8 +19,8 @@ def enable_compile_cache() -> None:
     """Turn on JAX's persistent compilation cache for this process. Where
     JAX_COMPILATION_CACHE_DIR is set, jax already reads it and nothing is
     set in code; otherwise the cache lives at `_REPO_CACHE`. Called once
-    by every entry point (cli, chip_smoke.py, the bench worker,
-    __graft_entry__) before the first compile."""
+    by every entry point (cli with its partition-worker command,
+    chip_smoke.py, __graft_entry__) before the first compile."""
     if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         return
     import jax

@@ -79,7 +79,7 @@ class PipelineProfiler:
         """Byte volume moved by a stage (h2d/d2h transfers): with the
         stage's cumulative seconds this makes the achieved MB/s of a
         transfer stage computable from one metrics line —
-        `embed_d2h_mbytes_per_sec` in the bulk-embed log and bench."""
+        `embed_d2h_mbytes_per_sec` in the bulk-embed log."""
         with self._lock:
             self._bytes[name] = self._bytes.get(name, 0) + int(nbytes)
 

@@ -429,8 +429,8 @@ def test_cli_loadtest_chaos_record_shape(served_wd, capsys):
     plan echoed, offered/sheds/errors accounting, availability (sheds
     excluded from the denominator), and the injected-fault counters.
     In-process transport crosses no wire, so availability is 1.0 and
-    errors 0 — the record SHAPE is the pin; the wire numbers are the
-    bench chaos_serve drill's job."""
+    errors 0 — the record SHAPE is the pin; the wire's own pins are
+    the two drills above (kill and rejoin, seeded wire faults)."""
     from dnn_page_vectors_tpu import cli
     cli.main(["loadtest", "--config", "cdssm_toy", "--workdir", served_wd,
               "--shape", "poisson", "--p99-ms", "500", "--seed", "5",

@@ -3,8 +3,8 @@ toy corpus, single-process CPU' (BASELINE.json:7) — trained end-to-end until
 Recall@10 beats random by a wide margin, exercising train -> bulk-embed ->
 vector store -> retrieval eval as one pipeline.
 
-Shrunk from 10k pages to 600 so the CPU run stays fast; the full-size run is
-bench.py's job.
+Shrunk from 10k pages to 600 so the CPU run stays fast; full-size runs are
+made on the chip (`chip_smoke.py`, `benchmarks/`).
 """
 import numpy as np
 

@@ -154,6 +154,26 @@ class _FailingCorpus:
         return self._inner.query_text(i)
 
 
+@pytest.mark.parametrize("dtype", ["float16", "int8"])
+def test_embed_from_jsonl_text_stores_every_page(tmp_path, dtype):
+    """The from-text sweep over a jsonl corpus on disk (corpus read ->
+    tokenizer workers -> device -> store writer) leaves every page in the
+    store, at fp16 and at the device-quantized int8 width."""
+    from dnn_page_vectors_tpu.data.synth import write_synth_jsonl
+    n = CFG_OVERRIDES["data.num_pages"]
+    path = write_synth_jsonl(str(tmp_path / "synth.jsonl"), n, seed=7,
+                             page_len=24, query_len=8)
+    cfg = get_config("cdssm_toy", {**CFG_OVERRIDES,
+                                   "data.corpus": f"jsonl:{path}"})
+    trainer = Trainer(cfg, workdir=str(tmp_path))
+    emb = _embedder(trainer, trainer.init_state(), cfg)
+    store = VectorStore(str(tmp_path / "store"), dim=cfg.model.out_dim,
+                        shard_size=cfg.eval.store_shard_size, dtype=dtype)
+    emb.embed_corpus(trainer.corpus, store, workers=3)
+    assert store.num_vectors == n
+    assert sorted(store.completed_shards()) == [0, 1, 2]
+
+
 def test_worker_exception_reraises_and_no_false_complete_shard(tmp_path):
     """Contract 2: the failure lands in shard 1 (pages 256..), so shard 0
     may complete but the failing shard — and anything after — must not be

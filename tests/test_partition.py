@@ -7,8 +7,8 @@ exact-fallback partitions mixed, and under a concurrent refresh hammer
 availability half: health-based routing sheds on restage / degraded /
 queue budget, and a partition whose replicas are ALL degraded still
 answers (never an empty slice), with the counters and events asserted.
-The host-simulation accounting behind the bench `partitioned_serve`
-phase (critical-path seconds, per-partition scan bytes) is pinned here
+The host-simulation accounting of `PartitionSet.simulate`
+(critical-path seconds, per-partition scan bytes) is pinned here
 too."""
 import threading
 import time
@@ -135,7 +135,7 @@ def test_partitioned_matches_single_partition_exact(served):
     qis = [0, 7, 42, 123, 299, 5, 13, 77, 200, 250]
     queries = [trainer.corpus.query_text(qi) for qi in qis]
     base = svc1.search_many(queries, k=10)
-    for P, R in ((2, 1), (4, 1), (2, 2)):
+    for P, R in ((2, 1), (4, 1), (2, 2), (1, 2), (4, 2)):
         svc = SearchService(_cfg(partitions=P, replicas=R), emb,
                             trainer.corpus, store, preload_hbm_gb=4.0)
         assert svc.partition_set is not None
@@ -435,7 +435,7 @@ def test_no_mixed_result_sets_under_partitioned_refresh(served, tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# host-simulation accounting (the bench partitioned_serve phase)
+# host-simulation accounting (PartitionSet.simulate)
 # ---------------------------------------------------------------------------
 
 def test_host_simulation_critical_path_and_scan_bytes(served):

@@ -440,7 +440,7 @@ def test_ann_topk_span_carries_index_attributes(served):
 def test_windowed_slo_gauges_move_across_bursts(served):
     """Two serve bursts: the windowed qps/p99 gauges change between them
     (the live SLO view tracks traffic), while the since-boot metrics keys
-    the bench and dashboards already pin stay present and the snapshot
+    that dashboards and `cli loadtest` read stay present and the snapshot
     stays json-serializable."""
     _, trainer, _, _ = served
     svc = _svc(served)
