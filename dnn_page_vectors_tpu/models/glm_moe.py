@@ -30,11 +30,13 @@ Device-side scopes (docs/OBSERVABILITY.md): `mla`, `mla.flash`, `moe`,
 `moe.router`, `moe.dispatch`, `moe.experts`, `moe.shared`, `moe.combine`.
 Counters are sown into the `moe_stats` collection by the tower, once per
 call: `held` [expert layers, experts_held] assignments per held expert,
-`absent` and `dropped` [expert layers].
+`absent`, `dropped` and `worst_case` (calls that needed the worst-case
+buffers) [expert layers].
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import flax.linen as nn
 import jax
@@ -177,7 +179,8 @@ class RoutedExperts(nn.Module):
     @nn.compact
     def __call__(self, x: jnp.ndarray):
         """[B, L, d] -> (FFN(x), this call's counters: `held` [experts_held]
-        assignments per held expert, `absent`, `dropped`)."""
+        assignments per held expert, `absent`, `dropped`, `worst_case`:
+        1 where the routing needed the worst-case buffers)."""
         B, L, d = x.shape
         E, k, H = (self.n_routed_experts, self.num_experts_per_tok,
                    self.experts_held)
@@ -204,24 +207,129 @@ class RoutedExperts(nn.Module):
             picked = jnp.take_along_axis(s, chosen, axis=1)   # [T, k]
             weight = self.routed_scaling_factor * picked / (
                 picked.sum(-1, keepdims=True) + 1e-20)
+        # The sorted buffers have room for the load this chip expects; a
+        # call whose routing needs more goes over buffers with room for the
+        # worst case, and is counted (`_routed`). Where the two sizes are
+        # one (half of the experts held, or more) there is one path.
+        tiles = gm.expected_tiles(B * L, k, H, E, _EXPERT_TILE)
+        worst = gm.num_tiles(B * L, k, H, _EXPERT_TILE)
         with jax.named_scope("moe.dispatch"):
             plan = gm.plan_rows(chosen, self.experts_held_start, H,
-                                _EXPERT_TILE)
-            rows = gm.permute(u, plan)
-        with jax.named_scope("moe.experts"):
-            mm = lambda a, w: gm.grouped_matmul(a, w.astype(self.dtype),
-                                                plan, _EXPERT_TILE)
-            h = nn.silu(mm(rows, w_gate)) * mm(rows, w_up)
-            rows = mm(h, w_down)
-        with jax.named_scope("moe.combine"):
-            routed = gm.unpermute(rows, weight, plan)         # [T, d] f32
+                                _EXPERT_TILE, tiles)
+        kernels = (w_gate, w_up, w_down)
+        if tiles == worst:
+            fallback = jnp.zeros((), jnp.int32)
+            with jax.named_scope("moe.experts"):
+                kernels = tuple(w.astype(self.dtype) for w in kernels)
+            routed, placed = _routed_part(_row_ops(), u, weight, kernels,
+                                          plan)
+        else:
+            fallback = 1 - gm.fits(plan).astype(jnp.int32)
+            routed, placed = _routed(_row_ops(), worst, u, weight, kernels,
+                                     plan)
         with jax.named_scope("moe.shared"):
             shared = SwiGlu(self.mlp_dim, d, dtype=self.dtype,
                             name="shared")(u)
         stats = {"held": plan.sizes, "absent": plan.absent,
-                 "dropped": B * L * k - plan.absent
-                 - plan.valid.sum(dtype=jnp.int32)}
+                 "dropped": B * L * k - plan.absent - placed,
+                 "worst_case": fallback}
         return (shared + routed.astype(self.dtype)).reshape(B, L, d), stats
+
+
+def _row_ops() -> tuple:
+    """The tile and the three operations of `ops/grouped_matmul.py` as the
+    module has them at the call: the static argument `how` of the jitted
+    parts below, so that a caller who replaces one (the tests plant faults
+    so, and pick the kernels' mode) gets a trace of its own."""
+    return _EXPERT_TILE, gm.permute, gm.grouped_matmul, gm.unpermute
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _routed_part(how: tuple, u, weight, kernels, plan: gm.RowPlan):
+    """Dispatch -> the held experts' grouped SwiGLU -> combine, over a
+    buffer of the plan's size: ([T, d] float32, the rows it placed).
+    Jitted (as `_pull` is) so that the expert layers of a tower, which call
+    it with the same shapes, are traced and lowered once: a step program
+    holds it for two buffer sizes, forward and backward."""
+    tile, permute, grouped_matmul, unpermute = how
+    w_gate, w_up, w_down = kernels
+    with jax.named_scope("moe.dispatch"):
+        rows = permute(u, plan)
+    with jax.named_scope("moe.experts"):
+        mm = lambda a, w: grouped_matmul(a, w, plan, tile)
+        rows = mm(nn.silu(mm(rows, w_gate)) * mm(rows, w_up), w_down)
+    with jax.named_scope("moe.combine"):
+        routed = unpermute(rows, weight, plan)                # [T, d] f32
+    return routed, plan.valid.sum(dtype=jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _pull(how: tuple, u, weight, kernels, plan: gm.RowPlan, g_routed):
+    """`_routed_part`'s forward again, and its cotangents for u, weight and
+    kernels."""
+    _, vjp, _ = jax.vjp(lambda *a: _routed_part(how, *a, plan), u, weight,
+                        kernels, has_aux=True)
+    return vjp(g_routed)
+
+
+def _either_size(how: tuple, worst: int, part):
+    """The two branches of a `cond` over the buffer's size: `part`
+    (`_routed_part` or `_pull`: the plan is their fourth array argument)
+    with the plan as it is, and with the plan laid out over `worst` tiles.
+    Each ends in a barrier: XLA otherwise moves what the two branches have
+    in common out of the `cond` (the weighted sums over k and their masks,
+    [T, k, d] arrays, become outputs of it, written and read back) and
+    compiles the step to other roundings than the one-path program's."""
+    def whole(u, weight, kernels, plan, *rest):
+        with jax.named_scope("moe.dispatch"):
+            plan = gm.with_tiles(plan, how[0], worst)
+        return part(how, u, weight, kernels, plan, *rest)
+
+    held = lambda f: lambda *a: jax.lax.optimization_barrier(f(*a))
+    return held(functools.partial(part, how)), held(whole)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _routed(how: tuple, worst: int, u, weight, kernels, plan: gm.RowPlan):
+    """`_routed_part` over the plan's buffer where the routing fits it, and
+    over one of `worst` tiles where it does not: the same rows in the same
+    tiles either way. `kernels` as they are stored (float32).
+
+    It carries its own vjp because JAX differentiates a `cond` by handing
+    every branch's residuals out of it, each branch writing zeros for the
+    other's: the expected branch would write worst-case-sized zeros on
+    every call, and copies of the operands that both save. Here nothing
+    crosses a `cond`: the backward pass is a second `cond` whose branches
+    run their forward again and pull the cotangent back. Under
+    `model.remat_blocks` that forward is the one the block's recomputation
+    would run anyway; without it, it is one forward more."""
+    return jax.lax.cond(gm.fits(plan),
+                        *_either_size(how, worst, _routed_part),
+                        u, weight, kernels, plan)
+
+
+def _routed_fwd(how, worst, u, weight, kernels, plan):
+    return (_routed(how, worst, u, weight, kernels, plan),
+            (u, weight, kernels, plan))
+
+
+def _routed_bwd(how, worst, res, g):
+    u, weight, kernels, plan = res
+    du, dweight, dkernels = jax.lax.cond(
+        gm.fits(plan), *_either_size(how, worst, _pull),
+        u, weight, kernels, plan, g[0])
+    # The kernels' gradients leave the branches as the float32 sums the
+    # kernel wrote and are rounded to the compute dtype here, as the
+    # transpose of a cast to it rounds them: outside the `cond` XLA fuses
+    # the rounding into the sum over the row groups, inside it is a pass of
+    # its own over every kernel (the barrier keeps it outside).
+    dkernels = jax.lax.optimization_barrier(dkernels)
+    with jax.named_scope("moe.experts"):
+        dkernels = tuple(d.astype(u.dtype).astype(d.dtype) for d in dkernels)
+    return du, dweight, dkernels, None
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
 
 
 class MixHalf(nn.Module):
@@ -328,9 +436,7 @@ class GlmMoeEncoder(nn.Module):
                      name="tok_embed")(ids)
         # A block is independent row by row. With recomputation on, a long
         # batch goes through the blocks in groups of rows, in sequence, so
-        # that only one group's activations are live at a time: the expert
-        # layer's sorted buffers have room for the worst case, 8 times the
-        # expected load of an eighth of the experts.
+        # that only one group's activations are live at a time.
         groups = B * L // _ROW_GROUP_TOKENS
         if groups < 2 or B % groups or not self.remat:
             groups = 1

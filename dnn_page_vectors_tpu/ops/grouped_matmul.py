@@ -10,11 +10,20 @@ kernel needs no row masks:
     out[r] = x[r] @ w[expert_of_tile(r // tile_m)]
 
 The number of assignments that land on the held experts is data, the buffer
-is not: it has room for the worst case (every token sending min(k, held)
-assignments here), and tiles past the last one in use are skipped. A skipped
-tile's index maps point at the last tile in use, so it costs a grid step and
-neither a DMA nor a matmul; its rows of the output are never written and
-never read (`unpermute` reads only rows that hold an assignment).
+is not. The kernels skip tiles past the last one in use: a skipped tile's
+index maps point at the last tile in use, so it costs a grid step and neither
+a DMA nor a matmul; its rows of the output are never written and never read
+(`unpermute` reads only rows that hold an assignment). What XLA runs between
+the kernels (the gathers, the elementwise passes, the plan's scatters) runs
+over the whole buffer, so the buffer is sized for the load a chip expects:
+`expected_tiles` has room for twice the held experts' even share of the
+assignments, in whole tiles, plus one tile of padding a group. `plan_rows`
+lays rows out in as many tiles as it is told and reports how many the routing
+needs (`n_active`, `fits`); a caller whose routing needs more than the
+expected buffer has takes the same rows over a buffer with room for the worst
+case (`with_tiles` and `num_tiles`: every token sending min(k, held)
+assignments here) and counts that call (models/glm_moe.py:RoutedExperts), so
+no assignment is ever dropped.
 
 Backward is two more launches of the same shape: dx = dy @ w[e]^T (the same
 kernel with the kernel transposed in the dot), and dw[e] = sum over e's tiles
@@ -50,7 +59,9 @@ class RowPlan(NamedTuple):
     held expert; absent: assignments sent to experts that are not here;
     tile_group / tile_row / tile_first [n_tiles], n_active [1]: the grid's
     scalars (expert of tile i, its row block, whether it opens its group;
-    tiles past n_active repeat the last one in use)."""
+    tiles past n_active repeat the last one in use). n_active is what the
+    routing needs, whatever the buffer holds: a plan is good for its buffer
+    only where that many tiles are there (`fits`)."""
     dest: jnp.ndarray
     held: jnp.ndarray
     src: jnp.ndarray
@@ -69,29 +80,71 @@ def num_tiles(tokens: int, top_k: int, held: int, tile_m: int) -> int:
     return -(-tokens * min(top_k, held) // tile_m) + held
 
 
-def plan_rows(expert: jnp.ndarray, held_start: int, held: int,
-              tile_m: int) -> RowPlan:
+def expected_tiles(tokens: int, top_k: int, held: int, experts: int,
+                   tile_m: int) -> int:
+    """Tiles for the load a chip expects: twice the held experts' even share
+    of the assignments (tokens * top_k * held / experts rows), in whole
+    tiles, plus one tile of padding a group; never more than the worst
+    case."""
+    return min(-(-2 * tokens * top_k * held // (experts * tile_m)) + held,
+               num_tiles(tokens, top_k, held, tile_m))
+
+
+def plan_rows(expert: jnp.ndarray, held_start: int, held: int, tile_m: int,
+              n_tiles: Optional[int] = None) -> RowPlan:
     """`expert` [T, k] int32: the global expert index of every assignment.
     A stable counting sort by held expert (one-hot cumulative sums; no
-    comparison sort), then each group padded to whole tiles."""
+    comparison sort), then each group padded to whole tiles, in a buffer of
+    `n_tiles` tiles (default: the worst case). Rows that a smaller buffer
+    has no room for are left out of `src` / `valid`: the caller checks
+    `fits` before it uses such a plan."""
     T, k = expert.shape
     A = T * k
-    n_tiles = num_tiles(T, k, held, tile_m)
-    M = n_tiles * tile_m
+    if n_tiles is None:
+        n_tiles = num_tiles(T, k, held, tile_m)
     local = expert.reshape(A) - held_start
     is_held = (local >= 0) & (local < held)
     key = jnp.where(is_held, local, held)                     # [A]
     onehot = (key[:, None] == jnp.arange(held)[None, :]).astype(jnp.int32)
     sizes = onehot.sum(0)                                     # [H]
     rank = ((jnp.cumsum(onehot, axis=0) - onehot) * onehot).sum(1)
-    tiles = jnp.maximum(-(-sizes // tile_m), 1)               # [H]
-    ends = jnp.cumsum(tiles)
-    starts = ends - tiles
-    n_active = ends[-1]
+    starts, _ = _tile_spans(sizes, tile_m)
     dest = jnp.where(is_held,
                      starts[jnp.minimum(key, held - 1)] * tile_m + rank, 0)
-    drop = jnp.where(is_held, dest, M)                # out of bounds: dropped
-    token = jnp.arange(A, dtype=jnp.int32) // k
+    return _lay_out(dest.reshape(T, k).astype(jnp.int32),
+                    is_held.reshape(T, k), sizes, A - sizes.sum(), tile_m,
+                    n_tiles)
+
+
+def with_tiles(plan: RowPlan, tile_m: int, n_tiles: int) -> RowPlan:
+    """The same rows in a buffer of `n_tiles` tiles: where a row lies does
+    not depend on the buffer's size, what the buffer holds does."""
+    return _lay_out(plan.dest, plan.held, plan.sizes, plan.absent, tile_m,
+                    n_tiles)
+
+
+def fits(plan: RowPlan) -> jnp.ndarray:
+    """Whether the plan's buffer has a tile for every tile the routing
+    needs (scalar bool)."""
+    return plan.n_active[0] <= plan.tile_row.shape[0]
+
+
+def _tile_spans(sizes, tile_m: int):
+    """First tile of each group and the one past its last: [H] each."""
+    tiles = jnp.maximum(-(-sizes // tile_m), 1)
+    ends = jnp.cumsum(tiles)
+    return ends - tiles, ends
+
+
+def _lay_out(dest, is_held, sizes, absent, tile_m: int,
+             n_tiles: int) -> RowPlan:
+    T, k = dest.shape
+    held = sizes.shape[0]
+    M = n_tiles * tile_m
+    starts, ends = _tile_spans(sizes, tile_m)
+    n_active = ends[-1]
+    drop = jnp.where(is_held, dest, M).reshape(T * k)  # out of bounds: dropped
+    token = jnp.arange(T * k, dtype=jnp.int32) // k
     src = jnp.zeros((M,), jnp.int32).at[drop].set(token, mode="drop")
     valid = jnp.zeros((M,), jnp.bool_).at[drop].set(True, mode="drop")
     i = jnp.arange(n_tiles, dtype=jnp.int32)
@@ -99,9 +152,8 @@ def plan_rows(expert: jnp.ndarray, held_start: int, held: int,
     group = jnp.minimum(jnp.searchsorted(ends, i, side="right"),
                         held - 1).astype(jnp.int32)
     return RowPlan(
-        dest=dest.reshape(T, k).astype(jnp.int32),
-        held=is_held.reshape(T, k), src=src, valid=valid,
-        sizes=sizes, absent=A - sizes.sum(),
+        dest=dest, held=is_held, src=src, valid=valid,
+        sizes=sizes, absent=absent,
         tile_group=jnp.where(active, group, held - 1),
         tile_row=jnp.where(active, i, n_active - 1).astype(jnp.int32),
         tile_first=(active & (i == starts[group])).astype(jnp.int32),
@@ -280,17 +332,19 @@ def _tgmm(x, g, plan: RowPlan, held: int, tile_m: int, interpret: bool):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _grouped_matmul(x, w, plan, tile_m, interpret):
-    return _gmm(x, w, plan, tile_m, False, interpret)
+    return _gmm(x, w.astype(x.dtype), plan, tile_m, False, interpret)
 
 
 def _gm_fwd(x, w, plan, tile_m, interpret):
-    return _gmm(x, w, plan, tile_m, False, interpret), (x, w, plan)
+    cast = w.astype(x.dtype)
+    return (_gmm(x, cast, plan, tile_m, False, interpret),
+            (x, cast, plan, jnp.zeros((0,), w.dtype)))
 
 
 def _gm_bwd(tile_m, interpret, res, g):
-    x, w, plan = res
+    x, w, plan, stored = res
     dx = _gmm(g, w, plan, tile_m, True, interpret).astype(x.dtype)
-    dw = _tgmm(x, g, plan, w.shape[0], tile_m, interpret).astype(w.dtype)
+    dw = _tgmm(x, g, plan, w.shape[0], tile_m, interpret).astype(stored.dtype)
     return dx, dw, None
 
 
@@ -302,7 +356,11 @@ def grouped_matmul(x: jnp.ndarray, w: jnp.ndarray, plan: RowPlan,
                    ) -> jnp.ndarray:
     """[M, K] rows in `plan`'s layout times the stacked kernels [H, K, N] of
     the experts held -> [M, N] in x's dtype (float32 accumulation). Rows of
-    tiles past the last one in use are left unwritten."""
+    tiles past the last one in use are left unwritten. Kernels stored wider
+    than x (float32 parameters under bfloat16 rows) are cast to x's dtype
+    here, and their gradient comes back as wide as they are: the float32
+    sums as `moe_tgmm` leaves them, where the transpose of a cast made by
+    the caller would have rounded them to x's dtype."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     return _grouped_matmul(x, w, plan, tile_m, interpret)

@@ -57,11 +57,15 @@ def moe_metrics(stats) -> Dict[str, jnp.ndarray]:
     """The routed-expert layers' counters of one step, from what the shared
     tower sowed (one entry per call, query then page: summed):
     `moe/assignments_held` [layers, held], `moe/assignments_absent` [layers],
-    `moe/dropped` (scalar; 0 by construction, and counted)."""
+    `moe/dropped` (scalar; 0 by construction, and counted),
+    `moe/worst_case_calls` (scalar: the (row group, layer) calls whose
+    routing needed more than the expected-load buffers and took the
+    worst-case ones; no assignment is lost either way)."""
     tower = stats["query_tower"]
     return {"moe/assignments_held": sum(tower["held"]),
             "moe/assignments_absent": sum(tower["absent"]),
-            "moe/dropped": sum(tower["dropped"]).sum()}
+            "moe/dropped": sum(tower["dropped"]).sum(),
+            "moe/worst_case_calls": sum(tower["worst_case"]).sum()}
 
 
 def make_train_step(model, tx, loss_chunk: int = 0, moe_stats: bool = False):

@@ -88,15 +88,19 @@ def test_causal_flash_compiles_for_v5e_at_head_width_256(one_chip, mode):
     assert text.count("tpu_custom_call") >= (1 if mode == "forward" else 3)
 
 
-@pytest.mark.parametrize("mode", ["forward", "grad"])
-def test_grouped_matmul_compiles_for_v5e_at_the_expert_widths(one_chip, mode):
+# a buffer of 72 tiles (the worst case) and one of 24 (the expected load)
+@pytest.mark.parametrize("mode,n_tiles", [
+    ("forward", None), ("grad", None), ("forward", 24), ("grad", 24)])
+def test_grouped_matmul_compiles_for_v5e_at_the_expert_widths(one_chip, mode,
+                                                              n_tiles):
     from dnn_page_vectors_tpu.ops import grouped_matmul as gm
     tokens, top_k, held, tile, d, ff = 4096, 4, 8, 256, 2048, 1536
+    assert gm.expected_tiles(tokens, top_k, held, 64, tile) == 24
     shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype,
                                                      sharding=one_chip)
 
     def experts(x, chosen, w_up, w_down, weight):
-        plan = gm.plan_rows(chosen, 0, held, tile)
+        plan = gm.plan_rows(chosen, 0, held, tile, n_tiles)
         h = gm.grouped_matmul(gm.permute(x, plan), w_up, plan, tile,
                               interpret=False)
         rows = gm.grouped_matmul(jax.nn.silu(h), w_down, plan, tile,
@@ -111,3 +115,28 @@ def test_grouped_matmul_compiles_for_v5e_at_the_expert_widths(one_chip, mode):
         shape((held, ff, d), jnp.bfloat16),
         shape((tokens, top_k), jnp.float32)).compile().as_text()
     assert text.count("tpu_custom_call") >= (2 if mode == "forward" else 6)
+
+
+def test_routed_layer_compiles_for_v5e_with_both_buffer_sizes(one_chip,
+                                                              monkeypatch):
+    """The layer's value-and-grad at the cell's widths: one program holding
+    the expected-load path and the worst-case fallback, the kernels compiled
+    by Mosaic at both sizes (forward 3 + backward 3 forward again and 6
+    more, for each size)."""
+    import functools
+    from dnn_page_vectors_tpu.models import glm_moe
+    from dnn_page_vectors_tpu.ops import grouped_matmul as gm
+    monkeypatch.setattr(gm, "grouped_matmul", functools.partial(
+        gm.grouped_matmul, interpret=False))
+    layer = glm_moe.RoutedExperts(2048, 1536, 64, 4, 1.8, 8, 0,
+                                  dtype=jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((GLM_B, GLM_L, 2048), jnp.bfloat16,
+                             sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0), x))
+    text = jax.jit(jax.value_and_grad(
+        lambda p, v: jnp.sum(layer.apply(p, v)[0].astype(jnp.float32)),
+        (0, 1))).lower(params, x).compile().as_text()
+    assert text.count(" conditional(") >= 2             # forward, backward
+    assert text.count("tpu_custom_call") >= 2 * (3 + 3 + 6)

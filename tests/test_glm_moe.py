@@ -1,7 +1,9 @@
 """models/glm_moe.py (GLM-4.7-Flash as an embedding tower) at tiny widths:
 the program against the benchmark's plain reference, the share of the
-experts tied to the uncut layer, no assignment dropped, the last-token pool,
-and the selection bias (it selects, never weighs, and is held)."""
+experts tied to the uncut layer, no assignment dropped, the expected-load
+buffers against the worst-case ones (bit for bit, and the fallback
+counted), the last-token pool, and the selection bias (it selects, never
+weighs, and is held)."""
 import os
 import sys
 
@@ -21,6 +23,7 @@ from dnn_page_vectors_tpu.models import glm_moe  # noqa: E402
 from dnn_page_vectors_tpu.models.factory import build_two_tower  # noqa: E402
 from dnn_page_vectors_tpu.models.losses import (  # noqa: E402
     cosine_contrastive_loss)
+from dnn_page_vectors_tpu.ops import grouped_matmul as gm  # noqa: E402
 from dnn_page_vectors_tpu.train.loop import moe_metrics  # noqa: E402
 
 # hidden 64, 8 experts of width 32, 2 a token, ranks 16 / 24, 2 heads of
@@ -131,6 +134,41 @@ def test_rows_in_groups_give_the_same_step(monkeypatch):
         np.testing.assert_array_equal(a[key], b[key])
 
 
+# 2 of 8 experts held, tiles of 8, row groups of 64 tokens: the queries are
+# one group (64 tokens: 6 tiles expected, 18 at worst), the pages two (10
+# and 18), so a step makes 2 expert layers x 3 calls
+@pytest.mark.parametrize("favoured,calls", [(0.0, 0), (100.0, 6)],
+                         ids=["as_routed", "all_on_the_held_experts"])
+def test_tower_counts_the_calls_that_took_the_worst_case(monkeypatch,
+                                                         favoured, calls):
+    monkeypatch.setattr(glm_moe, "_EXPERT_TILE", 8)
+    monkeypatch.setattr(glm_moe, "_ROW_GROUP_TOKENS", 64)
+    cfg = _config(held=2, **{"model.remat_blocks": True})
+    model, params = _model_and_params(cfg)
+    layers = params["params"]["query_tower"]["layers"]
+    for name in ("block1_ffn", "block2_ffn"):
+        bias = layers[name]["moe"]["select_bias"]
+        layers[name]["moe"]["select_bias"] = bias.at[2:4].add(favoured)
+    q, p = _ids()
+    (l1, (q1, p1, st)), g1 = jax.jit(jax.value_and_grad(
+        lambda v: _program(model, v, q, p), has_aux=True))(params)
+    (l2, (q2, p2, counts)), g2 = jax.jit(jax.value_and_grad(
+        lambda v: _reference(v, q, p), has_aux=True))(params)
+    m = moe_metrics(st[glm_moe.STATS])
+    assert int(m["moe/worst_case_calls"]) == calls
+    assert int(m["moe/dropped"]) == 0
+    np.testing.assert_array_equal(m["moe/assignments_held"], counts)
+    if favoured:
+        assert int(m["moe/assignments_absent"].sum()) == 0
+    assert abs(float(l1) - float(l2)) <= 1e-5 * abs(float(l2))
+    np.testing.assert_allclose(p1, p2, rtol=1e-4, atol=1e-5)
+    norm = lambda t: float(jnp.sqrt(jnp.sum(jnp.square(t))))
+    flat1 = jax.tree_util.tree_flatten_with_path(g1)[0]
+    for (path, a), b in zip(flat1, jax.tree_util.tree_leaves(g2)):
+        assert norm(a - b) <= 3e-5 * max(norm(b), 1e-3), \
+            weights_moe.path_str(path)
+
+
 def _layer_params(seed=3, experts=8):
     rng = np.random.default_rng(seed)
     n = lambda *s: jnp.asarray(rng.normal(size=s) / np.sqrt(s[-2]),
@@ -178,18 +216,167 @@ def test_the_shares_add_up_to_the_uncut_layer():
     assert int(counts.sum()) == 48 * 2
 
 
-def test_no_assignment_is_dropped_when_every_token_picks_one_expert():
+def _worst_case_calls(st):
+    """One layer call's counters as the tower sows them, through the step's
+    `moe_metrics`."""
+    return int(moe_metrics({"query_tower": {k: (v[None],) for k, v in
+                                            st.items()}})
+               ["moe/worst_case_calls"])
+
+
+# 48 tokens, 2 a token, experts 2 and 3 held of 8, tiles of 8: the expected
+# buffer has 2 * 24 / 8 + 2 = 8 tiles, the worst case 14
+@pytest.mark.parametrize("favoured,fallback", [((3,), 0), ((2, 3), 1)],
+                         ids=["one_expert", "both_held_experts"])
+def test_no_assignment_is_dropped_when_every_token_picks_one_expert(
+        monkeypatch, favoured, fallback):
+    monkeypatch.setattr(glm_moe, "_EXPERT_TILE", 8)
     p = _layer_params()
-    p["select_bias"] = p["select_bias"].at[3].set(100.0)    # held: 2..5
+    for e in favoured:
+        p["select_bias"] = p["select_bias"].at[e].set(100.0)
     u = jnp.asarray(np.random.default_rng(5).normal(size=(2, 24, 64)),
                     jnp.float32)
-    y, st = _layer(2, 4).apply({"params": _share(p, 2, 4)}, u)
+    y, st = _layer(2, 2).apply({"params": _share(p, 2, 2)}, u)
     assert int(st["dropped"]) == 0 and int(st["held"][1]) == 48
     assert int(st["held"].sum() + st["absent"]) == 96
-    want, _ = ref._experts(_share(p, 2, 4), u.reshape(48, 64),
+    # both held experts picked by every token is the worst case itself: the
+    # call takes the fallback, and says so
+    assert _worst_case_calls(st) == fallback
+    assert (int(st["absent"]) == 0) == bool(fallback)
+    want, _ = ref._experts(_share(p, 2, 2), u.reshape(48, 64),
                            dict(ARCH, experts_held_start=2), ref.identity,
                            True)
     np.testing.assert_allclose(y.reshape(48, 64), want, rtol=1e-5, atol=1e-5)
+
+
+def _steered(loads, seed=7):
+    """Layer parameters and [2, 32, 64] inputs whose routing puts loads[0]
+    rows on expert 2 (the first tokens) and loads[1] on expert 3 (the last
+    ones), the other choices on experts 0 and 1: the router reads the first
+    8 input dims, one an expert."""
+    p = _layer_params(seed)
+    router = np.zeros((64, 8), np.float32)
+    router[np.arange(8), np.arange(8)] = 1.0
+    p["router"] = {"kernel": jnp.asarray(router)}
+    p["select_bias"] = jnp.zeros(8, jnp.float32)
+    u = np.random.default_rng(seed).normal(size=(64, 64)).astype(np.float32)
+    t = np.arange(64)
+    first = np.where(t < loads[0], 2, 0)
+    second = np.where(t >= 64 - loads[1], 3, 1)
+    u[:, :8] = -4.0
+    u[t, first], u[t, second] = 4.0, 3.0
+    return _share(p, 2, 2), jnp.asarray(u.reshape(2, 32, 64))
+
+
+def _value_grads_counters(params, u):
+    def loss(q, v):
+        y, st = _layer(2, 2).apply({"params": q}, v)
+        return jnp.sum(y ** 2), (y, st)
+    (_, (y, st)), grads = jax.jit(jax.value_and_grad(
+        loss, (0, 1), has_aux=True))(params, u)
+    return y, grads, st
+
+
+# 64 tokens, 2 a token, experts 2 and 3 held of 8, tiles of 8: the expected
+# buffer has 2 * 32 / 8 + 2 = 10 tiles, the worst case 18
+@pytest.mark.parametrize("loads,fallback", [
+    ((16, 16), 0), ((40, 40), 0), ((41, 40), 1), ((64, 64), 1)],
+    ids=["under", "at", "one_row_over", "every_token_picks_held"])
+def test_expected_and_worst_case_buffers_give_the_same_layer_bit_for_bit(
+        monkeypatch, loads, fallback):
+    monkeypatch.setattr(glm_moe, "_EXPERT_TILE", 8)
+    params, u = _steered(loads)
+    y, grads, st = _value_grads_counters(params, u)
+    # one path, over worst-case buffers, as it was before there were two
+    monkeypatch.setattr(gm, "expected_tiles",
+                        lambda t, k, held, experts, tile:
+                        gm.num_tiles(t, k, held, tile))
+    y0, grads0, st0 = _value_grads_counters(params, u)
+    np.testing.assert_array_equal(y, y0)
+    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
+    assert len(flat) == 9                  # 8 parameters and the input
+    for (path, a), b in zip(flat, jax.tree_util.tree_leaves(grads0)):
+        np.testing.assert_array_equal(a, b, err_msg=str(path))
+        if "select_bias" not in str(path):
+            assert float(jnp.abs(a).max()) > 0, path
+    for key in ("held", "absent", "dropped"):
+        np.testing.assert_array_equal(st[key], st0[key])
+    np.testing.assert_array_equal(st["held"], loads)
+    assert int(st["dropped"]) == 0
+    assert (_worst_case_calls(st), _worst_case_calls(st0)) == (fallback, 0)
+
+
+def _conds(jaxpr, found=None):
+    """Every `cond` of a jaxpr and of what it calls, a kernel's body (its
+    `pl.when`) left out."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            continue
+        if eqn.primitive.name == "cond":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _conds(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("held,conds", [(8, 0), (4, 0), (2, 2)])
+def test_where_the_two_sizes_are_one_there_is_no_cond(monkeypatch, held,
+                                                      conds):
+    """Half of the experts or more: the expected-load buffer is the worst
+    case's, one path and no branch; under that, one `cond` forward and one
+    backward."""
+    monkeypatch.setattr(glm_moe, "_EXPERT_TILE", 8)
+    p = _share(_layer_params(), 0, held)
+    u = jnp.zeros((2, 24, 64), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q: jnp.sum(_layer(0, held).apply({"params": q}, u)[0])))(p)
+    assert len(_conds(jaxpr.jaxpr)) == conds
+
+
+def _rows_of(jaxpr, rows, seen=0):
+    """How many variables of a jaxpr, and of what it calls, have `rows` as
+    a dimension."""
+    has = lambda v: rows in getattr(getattr(v, "aval", None), "shape", ())
+    seen += sum(map(has, jaxpr.invars + jaxpr.constvars))
+    for eqn in jaxpr.eqns:
+        seen += sum(map(has, eqn.outvars))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            seen = _rows_of(sub, rows, seen)
+    return seen
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_no_worst_case_sized_array_on_the_expected_path_at_the_cells_widths(
+        remat):
+    """A row group of `glm47_flash_ep8` (4,096 tokens, 8 of 64 experts of
+    width 1,536 on a hidden 2,048, 4 a token), shapes only: the worst case's
+    18,432 rows appear in the fallback's branches alone, not in the
+    expected branch, nor in what a `cond` takes or hands out (JAX would put
+    each branch's residuals there, as zeros from the other branch)."""
+    layer = glm_moe.RoutedExperts(2048, 1536, 64, 4, 1.8, 8, 0,
+                                  dtype=jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((4, 1024, 2048), jnp.bfloat16)
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+    assert gm.num_tiles(4096, 4, 8, 256) * 256 == 18432
+    assert gm.expected_tiles(4096, 4, 8, 64, 256) * 256 == 6144
+    apply = jax.checkpoint(layer.apply) if remat else layer.apply
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(
+        lambda p, v: jnp.sum(apply(p, v)[0].astype(jnp.float32)),
+        (0, 1)))(params, x).jaxpr
+    conds = _conds(jaxpr)
+    assert len(conds) == 2                              # forward, backward
+    for eqn in conds:
+        worst, expected = (b.jaxpr for b in eqn.params["branches"])
+        assert _rows_of(worst, 18432) > 0
+        assert _rows_of(expected, 18432) == 0 and _rows_of(expected, 6144) > 0
+        for v in eqn.invars + eqn.outvars:
+            assert 18432 not in v.aval.shape, v.aval
+    # and nowhere outside the two conds
+    outside = _rows_of(jaxpr, 18432) - sum(
+        _rows_of(b.jaxpr, 18432) for eqn in conds
+        for b in eqn.params["branches"])
+    assert outside == 0
 
 
 def test_the_bias_selects_and_never_weighs():
