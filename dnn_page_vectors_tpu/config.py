@@ -49,7 +49,7 @@ class DataConfig:
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     """Encoder zoo settings. `encoder` selects the family."""
-    # cdssm | kim_cnn | lstm | bert | t5 | glm4_moe_lite
+    # cdssm | kim_cnn | lstm | bert | t5 | glm4_moe_lite | granitemoehybrid
     encoder: str = "cdssm"
     embed_dim: int = 128             # token/word embedding width
     out_dim: int = 128               # final vector dimension (both towers)
@@ -91,6 +91,27 @@ class ModelConfig:
     experts_held_start: int = 0
     # recompute each block's activations in the backward pass
     remat_blocks: bool = False
+    # granitemoehybrid (models/granite_hybrid.py): the published keys under
+    # their published names (mlp_dim is its intermediate_size, every routed
+    # expert's width; num_layers follows from layer_types; n_routed_experts
+    # is its num_local_experts; num_heads its num_attention_heads)
+    layer_types: Tuple[str, ...] = ()          # "mamba" | "attention" a layer
+    num_key_value_heads: int = 8
+    attention_multiplier: float = 0.0078125    # the score scale, not 1/sqrt(d)
+    embedding_multiplier: float = 12.0
+    residual_multiplier: float = 0.22
+    shared_intermediate_size: int = 1536
+    mamba_n_heads: int = 128
+    mamba_d_head: int = 64
+    mamba_d_state: int = 128
+    mamba_expand: int = 2
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256
+    # What BulkEmbedder / SearchService hold a tower's matrices in
+    # (infer/bulk_embed.py:hold_weights): float32 as trained, or bfloat16
+    # (cast once at construction; what the tower computes with in float32
+    # stays float32). Training always holds float32.
+    weights_dtype: str = "float32"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -215,6 +236,13 @@ class ServeConfig:
     # Most queries one coalesced dispatch may carry (tiled over full
     # compiled buckets inside search_many). Bounds per-dispatch latency.
     max_batch: int = 32
+    # Compiled width of the query ENCODE: the rows a call is padded to, and
+    # the most it carries (more misses tile over it). 0 = the `query_batch`
+    # bucket that the top-k scan uses too. A tower whose queries are long
+    # (a whole page) wants 1: padding one 1,024-token query to eight costs
+    # eight encodes, and such a tower's encode takes as long a query in a
+    # wider call. A multiple of the mesh's data axis.
+    encode_batch: int = 0
     # Bounded request queue between callers and the dispatcher thread: a
     # full queue backpressures callers instead of buffering unboundedly.
     max_queue: int = 256
@@ -583,7 +611,8 @@ def _nested_replace(cfg: Config, overrides: Dict[str, Any]) -> Config:
             elif isinstance(current, float):
                 value = float(value)
             elif isinstance(current, tuple):
-                value = tuple(int(x) for x in str(value).split(","))
+                value = tuple(int(x) if x.lstrip("-").isdigit() else x
+                              for x in str(value).split(","))
         elif isinstance(value, list):
             value = tuple(value)
         section = dataclasses.replace(section, **{parts[1]: value})
@@ -728,6 +757,36 @@ def glm47_flash_ep8() -> Config:
     )
 
 
+def granite4_h_small_ep2() -> Config:
+    """Granite-4.0-H-Small (ibm-granite, `granitemoehybrid`) as ONE shared
+    tower for SERVING, cut to one chip's share of a layer that 2 chips hold
+    together (expert parallel: 36 of the 72 routed experts here; mixer,
+    attention, router and shared expert replicated; half of the 100,352
+    embedding rows): the first period of ten layers (nine Mamba-2, one
+    attention) of the published 40, every width as published, weights held
+    in bfloat16. Whole pages as queries: 1,024 tokens, encoded one a call
+    (benchmarks/configs/granite4_h_small_ep2.json states the cut; 16 bytes
+    a parameter of training state do not fit one chip)."""
+    period = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
+    return Config(
+        name="granite4_h_small_ep2",
+        data=DataConfig(tokenizer="wordpiece", corpus="toy",
+                        num_pages=1_048_576, vocab_size=50_176,
+                        page_len=1024, query_len=1024),
+        model=ModelConfig(encoder="granitemoehybrid", num_layers=10,
+                          layer_types=period, num_heads=32,
+                          num_key_value_heads=8, model_dim=4096, mlp_dim=768,
+                          out_dim=1024, attention="flash", dropout=0.0,
+                          shared_towers=True, n_routed_experts=72,
+                          num_experts_per_tok=10, experts_held=36,
+                          experts_held_start=0, weights_dtype="bfloat16"),
+        mesh=MeshConfig(data=1),
+        train=TrainConfig(batch_size=4, steps=1_000, learning_rate=1e-4),
+        eval=EvalConfig(embed_batch_size=4),
+        serve=ServeConfig(max_batch=4, encode_batch=1, query_cache_size=0),
+    )
+
+
 CONFIGS = {
     "cdssm_toy": cdssm_toy,
     "kim_cnn_v5e8": kim_cnn_v5e8,
@@ -737,6 +796,7 @@ CONFIGS = {
     "mt5_multilingual": mt5_multilingual,
     "bert_long_sp": bert_long_sp,
     "glm47_flash_ep8": glm47_flash_ep8,
+    "granite4_h_small_ep2": granite4_h_small_ep2,
 }
 
 

@@ -22,9 +22,12 @@ from dnn_page_vectors_tpu.config import Config
 from dnn_page_vectors_tpu.data.loader import iter_corpus_batches, prefetch_to_device
 from dnn_page_vectors_tpu.data.toy import ToyCorpus
 from dnn_page_vectors_tpu.infer.vector_store import VectorStore
+from dnn_page_vectors_tpu.models.glm_moe import STATS as MOE_STATS
+from dnn_page_vectors_tpu.models.glm_moe import _EXPERT_TILE
 from dnn_page_vectors_tpu.models.losses import l2_normalize
 from dnn_page_vectors_tpu.parallel.sharding import (
-    batch_sharding, replicated, shard_params, stacked_batch_sharding)
+    _path_str, batch_sharding, replicated, shard_params,
+    stacked_batch_sharding)
 from dnn_page_vectors_tpu.utils import faults
 from dnn_page_vectors_tpu.utils.logging import MetricsLogger
 from dnn_page_vectors_tpu.utils.profiling import PipelineProfiler
@@ -150,7 +153,50 @@ def _stack_batches(it, k: int):
         yield _emit(group + [pad] * (k - len(group)))
 
 
+# What a tower computes with in float32 whatever its weights are held in:
+# every 1-D leaf (norm scales, biases, a state-space layer's A_log / D /
+# dt_bias, the selection bias), the router (a rounding there moves the
+# selection) and the float32 output projection.
+_KEPT_FLOAT32 = ("router", "proj")
+
+
+def hold_weights(params, dtype: str):
+    """The tree as inference holds it: with `dtype` "bfloat16" every float32
+    matrix (embedding, projections, stacked expert kernels) is cast ONCE,
+    here; a leaf that already arrives in bfloat16 stays the array it is, and
+    the leaves named above stay float32. "float32": the tree as it is."""
+    if dtype == "float32":
+        return params
+    if dtype != "bfloat16":
+        raise ValueError(f"model.weights_dtype {dtype!r}: want float32 or "
+                         "bfloat16")
+
+    def one(path, leaf):
+        if leaf.dtype != jnp.float32 or leaf.ndim < 2 or any(
+                k in _KEPT_FLOAT32 for k in _path_str(path).split("/")):
+            return leaf
+        return leaf.astype(jnp.bfloat16)
+
+    return jax.tree_util.tree_map_with_path(one, params)
+
+
+def _encode_counters(ids, stats):
+    """What one encode call of a tower that sows `moe_stats` counted, on the
+    device: ([4] int32 in ENCODE_COUNTERS' order: non-pad tokens, assignments
+    on the experts held, tiles of the grouped product in use, assignments
+    dropped, summed over the layers; [layers, experts held] int32, the
+    assignments per held expert those sums were made from)."""
+    held = sum(stats["held"]).astype(jnp.int32)
+    tiles = jnp.maximum(-(-held // _EXPERT_TILE), 1)
+    sums = jnp.stack([(ids > 0).sum(), held.sum(), tiles.sum(),
+                      sum(stats["dropped"]).sum()]).astype(jnp.int32)
+    return sums, held
+
+
 class BulkEmbedder:
+    ENCODE_COUNTERS = ("tokens", "moe_assignments_held", "moe_tiles_used",
+                       "moe_dropped")
+
     def __init__(self, cfg: Config, model, params, page_tok, mesh,
                  query_tok=None):
         self.cfg = cfg
@@ -165,7 +211,8 @@ class BulkEmbedder:
             from dnn_page_vectors_tpu.parallel.multihost import (
                 host_replicated_copy)
             params = host_replicated_copy(params)
-        self.params = shard_params(params, mesh)
+        self.params = shard_params(
+            hold_weights(params, cfg.model.weights_dtype), mesh)
         self.page_tok = page_tok
         self.query_tok = query_tok
         self.mesh = mesh
@@ -188,6 +235,24 @@ class BulkEmbedder:
         self._encode_query = jax.jit(
             lambda p, x: _encode(p, x, "encode_query"),
             in_shardings=(None, batch_sharding(mesh)), out_shardings=out_sh)
+        # A tower that sows its routed layers' counters (`moe_stats`; the
+        # tower says so itself) hands them back beside the vectors, reduced
+        # on the device (_encode_counters). Decided here, once; every other
+        # tower keeps the program above. Callers go through
+        # encode_query_call, which gives both kinds one shape.
+        self.counts_encode = getattr(model.query_tower, "sows_moe_stats",
+                                     False)
+        if self.counts_encode:
+            def _encode_counted(params, ids):
+                vecs, sown = model.apply(params, ids, deterministic=True,
+                                         method="encode_query",
+                                         mutable=[MOE_STATS])
+                return l2_normalize(vecs), _encode_counters(
+                    ids, sown[MOE_STATS]["query_tower"])
+
+            self._encode_query = jax.jit(
+                _encode_counted, in_shardings=(None, batch_sharding(mesh)),
+                out_shardings=(out_sh, replicated(mesh)))
         # Fused sweep: E batches per dispatch ([E, B, ...] -> [E, B, D] via
         # lax.map). Same per-batch compute, so vectors are identical to the
         # per-batch path. embed_corpus dispatches eval.embed_stack batches
@@ -252,8 +317,18 @@ class BulkEmbedder:
         scorer directly and is never bulk traffic."""
         return np.asarray(self._encode_page(self.params, self._put(ids)))
 
+    def encode_query_call(self, ids: np.ndarray, params=None):
+        """One call of the compiled query encode on host ids [B, L], left on
+        the device: (unit vectors [B, D], what the call counted). The second
+        is _encode_counters' pair for a tower that sows `moe_stats` and None
+        for every other. `params`: the serving step's tree (default: the
+        embedder's own)."""
+        out = self._encode_query(self.params if params is None else params,
+                                 self._put(ids))
+        return out if self.counts_encode else (out, None)
+
     def embed_queries(self, ids: np.ndarray) -> np.ndarray:
-        return np.asarray(self._encode_query(self.params, self._put(ids)))
+        return np.asarray(self.encode_query_call(ids)[0])
 
     def embed_texts(self, texts, tower: str = "query",
                     batch_size: Optional[int] = None) -> np.ndarray:

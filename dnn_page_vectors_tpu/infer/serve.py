@@ -526,6 +526,10 @@ class SearchService:
                                          window_s=window_s)
         self._m_cache_misses = reg.counter("serve.cache_misses",
                                            window_s=window_s)
+        # what a tower with routed experts counts per encode call (the
+        # embedder reduces them on the device; BulkEmbedder.encode_query_call)
+        self._m_encode = {name: reg.counter("encode." + name)
+                          for name in BulkEmbedder.ENCODE_COUNTERS}
         self._m_ann_lists = reg.counter("serve.ann_lists_scanned")
         self._m_ann_reranked = reg.counter("serve.ann_candidates_reranked")
         self._m_ann_fallbacks = reg.counter("serve.ann_fallbacks")
@@ -704,6 +708,13 @@ class SearchService:
         n_data = max(embedder.mesh.shape.get("data", 1), 1)
         self._n_data = n_data
         self.query_batch = query_batch or -(-8 // n_data) * n_data
+        # the encode's compiled width (serve.encode_batch); by default the
+        # bucket the scan uses
+        self._encode_batch = cfg.serve.encode_batch or self.query_batch
+        if self._encode_batch < 0 or self._encode_batch % n_data:
+            raise ValueError(
+                f"serve.encode_batch {self._encode_batch} must be a "
+                f"positive multiple of the mesh's data axis ({n_data})")
         self.warm_latency_ms: Optional[float] = None
         self._preload_gb = preload_hbm_gb
         self._refresh_lock = threading.Lock()   # one refresh at a time
@@ -998,6 +1009,17 @@ class SearchService:
         self.registry.event("recompile", {"program": program, **shape},
                             trace_id=cur.trace_id if cur is not None
                             else None)
+
+    def _count_encode(self, counts) -> None:
+        """What one encode call counted (BulkEmbedder.encode_query_call's
+        second value) into the registry's `encode.*`: the four sums are
+        pulled, nothing else; None (a tower that counts nothing) is a
+        no-op."""
+        if counts is None:
+            return
+        for counter, n in zip(self._m_encode.values(),
+                              np.asarray(counts[0]).tolist()):
+            counter.inc(n)
 
     # -- hot-swap refresh (docs/UPDATES.md) --------------------------------
     def refresh(self, update_index: Optional[bool] = None) -> Dict:
@@ -1833,7 +1855,7 @@ class SearchService:
             else:
                 alias.append((i, j))
         tok = self.embedder.query_tok or self.embedder.page_tok
-        B = self.query_batch
+        B = self._encode_batch
         for s in range(0, len(uniq), B):
             grp = uniq[s: s + B]
             with self._stage("tokenize", queries=len(grp)):
@@ -1845,10 +1867,9 @@ class SearchService:
             self._note_dispatch_shape("encode_query", batch=B,
                                       tokens=int(enc.shape[1]))
             with self._stage("encode", queries=len(grp)):
-                vecs = np.asarray(
-                    self.embedder._encode_query(params,
-                                                self.embedder._put(enc)),
-                    np.float32)[: len(grp)]
+                vecs, counts = self.embedder.encode_query_call(enc, params)
+                vecs = np.asarray(vecs, np.float32)[: len(grp)]
+            self._count_encode(counts)
             out[grp] = vecs
         for i, j in alias:
             out[i] = out[j]

@@ -9,6 +9,8 @@ import jax.numpy as jnp
 from dnn_page_vectors_tpu.config import Config
 from dnn_page_vectors_tpu.models.cdssm import CdssmEncoder
 from dnn_page_vectors_tpu.models.glm_moe import GlmMoeEncoder, GlmSizes
+from dnn_page_vectors_tpu.models.granite_hybrid import (GraniteHybridEncoder,
+                                                        GraniteSizes)
 from dnn_page_vectors_tpu.models.kim_cnn import KimCnnEncoder
 from dnn_page_vectors_tpu.models.lstm import LstmEncoder
 from dnn_page_vectors_tpu.models.transformer import TransformerEncoder
@@ -69,6 +71,30 @@ def _build_encoder(cfg: Config, vocab_size: int, name: str,
                              dropout=m.dropout, remat=m.remat_blocks,
                              attention_kind=m.attention, dtype=dtype,
                              name=name)
+    if m.encoder == "granitemoehybrid":
+        if len(m.layer_types) != m.num_layers:
+            raise ValueError(f"model.layer_types names {len(m.layer_types)} "
+                             f"layers, model.num_layers is {m.num_layers}")
+        sizes = GraniteSizes(
+            model_dim=m.model_dim, layer_types=tuple(m.layer_types),
+            num_heads=m.num_heads, num_kv_heads=m.num_key_value_heads,
+            attention_multiplier=m.attention_multiplier,
+            embedding_multiplier=m.embedding_multiplier,
+            residual_multiplier=m.residual_multiplier,
+            mamba_n_heads=m.mamba_n_heads, mamba_d_head=m.mamba_d_head,
+            mamba_d_state=m.mamba_d_state, mamba_expand=m.mamba_expand,
+            mamba_d_conv=m.mamba_d_conv,
+            mamba_chunk_size=m.mamba_chunk_size, moe_mlp_dim=m.mlp_dim,
+            shared_mlp_dim=m.shared_intermediate_size,
+            n_routed_experts=m.n_routed_experts,
+            num_experts_per_tok=m.num_experts_per_tok,
+            experts_held=m.experts_held or m.n_routed_experts,
+            experts_held_start=m.experts_held_start,
+            norm_eps=m.rms_norm_eps)
+        return GraniteHybridEncoder(vocab_size=vocab_size, sizes=sizes,
+                                    out_dim=m.out_dim,
+                                    attention_kind=m.attention, dtype=dtype,
+                                    name=name)
     raise ValueError(f"unknown encoder {cfg.model.encoder!r}")
 
 

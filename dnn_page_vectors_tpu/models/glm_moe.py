@@ -25,6 +25,12 @@ FFN, expert layers: s = sigmoid(u Wr) in float32; S = top-k of s + b;
       w_i = scale * s_i / (sum_{j in S} s_j + 1e-20);
       E_shared(u) + sum_{i in S, i held} w_i E_i(u). b selects and never
       weighs, and takes no gradient. No token is dropped.
+How a token's router scores become a selection and weights is the part of
+the expert layer that varies by architecture (`RoutedExperts.router`): the
+above is `sigmoid_noaux_tc`; `softmax_topk` (models/granite_hybrid.py) takes
+the k largest raw logits and a softmax over those k alone, with no bias and
+no scaling. From the row plan on (`plan_rows`, `permute`, the grouped
+products, `unpermute`, the counters) the layer has one body.
 
 Device-side scopes (docs/OBSERVABILITY.md): `mla`, `mla.flash`, `moe`,
 `moe.router`, `moe.dispatch`, `moe.experts`, `moe.shared`, `moe.combine`.
@@ -50,6 +56,7 @@ STATS = "moe_stats"          # the counters' collection
 _EXPERT_TILE = 256           # rows per tile of the grouped product
 _ROW_GROUP_TOKENS = 4096     # tokens in a group of rows (GlmMoeEncoder)
 _FLASH_BLOCK = 512           # square tile of the causal flash kernels
+ROUTERS = ("sigmoid_noaux_tc", "softmax_topk")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -175,6 +182,8 @@ class RoutedExperts(nn.Module):
     experts_held: int
     experts_held_start: int
     dtype: jnp.dtype = jnp.bfloat16
+    router: str = "sigmoid_noaux_tc"    # how scores become a selection
+    shared_dim: int = 0                 # the shared expert's width; 0: mlp_dim
 
     @nn.compact
     def __call__(self, x: jnp.ndarray):
@@ -195,18 +204,26 @@ class RoutedExperts(nn.Module):
         w_gate = self.param("w_gate", stacked, (H, d, self.mlp_dim))
         w_up = self.param("w_up", stacked, (H, d, self.mlp_dim))
         w_down = self.param("w_down", stacked, (H, self.mlp_dim, d))
-        bias = self.param("select_bias", nn.initializers.zeros, (E,))
+        if self.router not in ROUTERS:
+            raise ValueError(f"unknown router {self.router!r} "
+                             f"(want one of {ROUTERS})")
+        if self.router == "sigmoid_noaux_tc":
+            bias = self.param("select_bias", nn.initializers.zeros, (E,))
 
         with jax.named_scope("moe.router"):
             logits = nn.Dense(E, use_bias=False, dtype=jnp.float32,
                               precision="highest",
                               name="router")(u.astype(jnp.float32))
-            s = jax.nn.sigmoid(logits)                        # [T, E] f32
-            _, chosen = jax.lax.top_k(
-                s + jax.lax.stop_gradient(bias)[None, :], k)
-            picked = jnp.take_along_axis(s, chosen, axis=1)   # [T, k]
-            weight = self.routed_scaling_factor * picked / (
-                picked.sum(-1, keepdims=True) + 1e-20)
+            if self.router == "sigmoid_noaux_tc":
+                s = jax.nn.sigmoid(logits)                    # [T, E] f32
+                _, chosen = jax.lax.top_k(
+                    s + jax.lax.stop_gradient(bias)[None, :], k)
+                picked = jnp.take_along_axis(s, chosen, axis=1)   # [T, k]
+                weight = self.routed_scaling_factor * picked / (
+                    picked.sum(-1, keepdims=True) + 1e-20)
+            else:   # softmax_topk: over the selected alone, held or not
+                picked, chosen = jax.lax.top_k(logits, k)
+                weight = jax.nn.softmax(picked, axis=-1)
         # The sorted buffers have room for the load this chip expects; a
         # call whose routing needs more goes over buffers with room for the
         # worst case, and is counted (`_routed`). Where the two sizes are
@@ -228,8 +245,8 @@ class RoutedExperts(nn.Module):
             routed, placed = _routed(_row_ops(), worst, u, weight, kernels,
                                      plan)
         with jax.named_scope("moe.shared"):
-            shared = SwiGlu(self.mlp_dim, d, dtype=self.dtype,
-                            name="shared")(u)
+            shared = SwiGlu(self.shared_dim or self.mlp_dim, d,
+                            dtype=self.dtype, name="shared")(u)
         stats = {"held": plan.sizes, "absent": plan.absent,
                  "dropped": B * L * k - plan.absent - placed,
                  "worst_case": fallback}
@@ -425,6 +442,9 @@ class GlmMoeEncoder(nn.Module):
     remat: bool = False           # recompute each half block in the backward
     dtype: jnp.dtype = jnp.bfloat16
     attention_kind: str = "flash"
+    # no field: what Trainer and BulkEmbedder ask a tower before they apply
+    # it with the `moe_stats` collection mutable
+    sows_moe_stats = True
 
     @nn.compact
     def __call__(self, ids: jnp.ndarray,

@@ -147,7 +147,7 @@ class Trainer:
         self.mesh = make_mesh(fitted)
         self.model = build_two_tower(cfg, self.page_tok.vocab_size,
                                      mesh=self.mesh)
-        self._moe = cfg.model.encoder == "glm4_moe_lite"
+        self._moe = getattr(self.model.query_tower, "sows_moe_stats", False)
         self.tx = make_optimizer(cfg.train,
                                  no_decay=SELECT_BIAS if self._moe else None)
         self.hard_negative_lookup = hard_negative_lookup

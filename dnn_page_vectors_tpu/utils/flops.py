@@ -45,6 +45,29 @@ def encoder_flops_per_example(m: ModelConfig, seq_len: int) -> float:
         return float(L * (m.num_layers * mla + dense * 6 * d * m.mlp_dim
                           + (m.num_layers - dense) * moe)
                      + 2 * d * m.out_dim)
+    if m.encoder == "granitemoehybrid":
+        # per layer the mixer (two projections and the chunked recurrence:
+        # within-chunk products over the visible pairs once, the chunks'
+        # states, the carried state's part of the output) or grouped-query
+        # attention (causal scores counted once), then the router, the
+        # shared expert and the EXPECTED share of assignments held
+        d, L = m.model_dim, seq_len
+        H, P, N = m.mamba_n_heads, m.mamba_d_head, m.mamba_d_state
+        Q = min(m.mamba_chunk_size, L)
+        n = -(-L // Q)
+        scan = n * Q * (Q + 1) / 2 * (2 * N + 2 * H * P) \
+            + 2 * (n - 1) * 2 * Q * H * P * N
+        mamba = L * (2 * d * (2 * H * P + 2 * N + H) + 2 * H * P * d) + scan
+        dh = d // m.num_heads
+        attn = L * (4 * d * d + 4 * d * m.num_key_value_heads * dh) \
+            + 4 * dh * m.num_heads * L * (L + 1) / 2
+        held = (m.experts_held or m.n_routed_experts) / m.n_routed_experts
+        moe = L * (2 * d * m.n_routed_experts
+                   + 6 * d * m.shared_intermediate_size
+                   + m.num_experts_per_tok * held * 6 * d * m.mlp_dim)
+        n_mamba = sum(t == "mamba" for t in m.layer_types)
+        return float(n_mamba * mamba + (m.num_layers - n_mamba) * attn
+                     + m.num_layers * moe + 2 * d * m.out_dim)
     if m.encoder == "cdssm":
         E, C = m.embed_dim, m.conv_channels
         conv = sum(2 * w * E * C for w in m.conv_widths) * seq_len

@@ -140,3 +140,45 @@ def test_routed_layer_compiles_for_v5e_with_both_buffer_sizes(one_chip,
         (0, 1))).lower(params, x).compile().as_text()
     assert text.count(" conditional(") >= 2             # forward, backward
     assert text.count("tpu_custom_call") >= 2 * (3 + 3 + 6)
+
+
+# Granite-4.0-H-Small's widths (config.py:granite4_h_small_ep2): queries of
+# 1,024 tokens in buckets of 1 and 4, weights held in bfloat16
+@pytest.mark.parametrize("kind,bucket", [("mamba", 1), ("mamba", 4),
+                                         ("attention", 4)])
+def test_hybrid_block_compiles_for_v5e_at_the_published_widths(
+        one_chip, monkeypatch, kind, bucket):
+    """One layer's forward as the serve path runs it: the grouped product
+    at hidden 4,096 / width 768 / 36 held / 10 a token (one path, no
+    `cond`: the expected-load buffer is the worst case's), the causal flash
+    kernel under 32 query and 8 key/value heads of 128, and the chunked
+    scan's products, within the chip's memory."""
+    import functools
+    from dnn_page_vectors_tpu.config import get_config
+    from dnn_page_vectors_tpu.infer.bulk_embed import hold_weights
+    from dnn_page_vectors_tpu.models import granite_hybrid
+    from dnn_page_vectors_tpu.models.factory import build_two_tower
+    from dnn_page_vectors_tpu.ops import flash_attention as fa
+    from dnn_page_vectors_tpu.ops import grouped_matmul as gm
+    monkeypatch.setattr(gm, "grouped_matmul", functools.partial(
+        gm.grouped_matmul, interpret=False))
+    monkeypatch.setattr(fa, "flash_attention", functools.partial(
+        fa.flash_attention, interpret=False))
+    sizes = build_two_tower(get_config("granite4_h_small_ep2"),
+                            50_176).query_tower.sizes
+    block = granite_hybrid.HybridBlock(sizes, kind, dtype=jnp.bfloat16)
+    h = jax.ShapeDtypeStruct((bucket, 1024, 4096), jnp.bfloat16,
+                             sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((bucket, 1024), jnp.bool_, sharding=one_chip)
+    held = jax.eval_shape(
+        lambda t: hold_weights(t, "bfloat16"),
+        jax.eval_shape(block.init, jax.random.PRNGKey(0), h, mask))
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        held)
+    compiled = jax.jit(lambda p, x, m: block.apply(p, x, m)[0]).lower(
+        params, h, mask).compile()
+    text = compiled.as_text()
+    assert " conditional(" not in text
+    assert text.count("tpu_custom_call") == (4 if kind == "attention" else 3)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30

@@ -91,6 +91,34 @@ def test_search_many_matches_sequential_on_both_paths(served):
     assert svc.search_many([], k=10) == []
 
 
+def test_a_narrower_encode_width_gives_the_same_answers(served):
+    """`serve.encode_batch`: the encode's one compiled width. Five misses
+    at width 2 are three calls (the last padded by one row), and nothing
+    about the answers changes; 0 keeps the scan's bucket."""
+    cfg, trainer, emb, store = served
+    wide = SearchService(cfg, emb, trainer.corpus, store, preload_hbm_gb=4.0)
+    narrow = SearchService(
+        get_config("cdssm_toy", dict(_OV, **{"serve.encode_batch": 2})),
+        emb, trainer.corpus, store, preload_hbm_gb=4.0)
+    assert wide._encode_batch == wide.query_batch == 8
+    queries = [trainer.corpus.query_text(qi) for qi in (0, 7, 42, 123, 299)]
+    calls = []
+    real = emb.encode_query_call
+    emb.encode_query_call = lambda ids, params=None: (
+        calls.append(ids.shape[0]), real(ids, params))[1]
+    try:
+        got = narrow.search_many(queries, k=10)
+    finally:
+        del emb.encode_query_call
+    assert calls == [2, 2, 2]
+    for a, b in zip(got, wide.search_many(queries, k=10)):
+        _assert_same(a, b)
+    with pytest.raises(ValueError, match="encode_batch"):
+        SearchService(
+            get_config("cdssm_toy", dict(_OV, **{"serve.encode_batch": -1})),
+            emb, trainer.corpus, store, preload_hbm_gb=0.0)
+
+
 def test_search_many_degraded_matches_streaming_under_faults(served,
                                                              tmp_path):
     """A quarantined shard (corrupt bytes) + a staging fault (seeded
