@@ -48,6 +48,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from dnn_page_vectors_tpu.models.transformer import RmsNorm
 from dnn_page_vectors_tpu.ops import grouped_matmul as gm
@@ -55,6 +56,23 @@ from dnn_page_vectors_tpu.ops import grouped_matmul as gm
 STATS = "moe_stats"          # the counters' collection
 _EXPERT_TILE = 256           # rows per tile of the grouped product
 _ROW_GROUP_TOKENS = 4096     # tokens in a group of rows (GlmMoeEncoder)
+# What a recomputed half block keeps from its first forward (`Blocks`), by
+# `checkpoint_name`. The tower names four groups of values that cost a kernel
+# or a wide product to make again and little to hold (bytes a token and layer
+# at the published widths): flash's output and log-sum-exp
+# (`ops/flash_attention.py:CAUSAL_RESIDUALS`, 10,320), the two up-products
+# of the dense layer's SwiGLU (`mlp_*`, 40,960) and of a shared expert's
+# (`shared_*`, 6,144), and MLA's two down-projections (`mla_*`, 2,688). Not
+# q, k and v by head (30 KB).
+# LISTED are the groups the step program of `glm47_flash_ep8` has room for
+# (PERF.md section 6, PR 36): the compiler sizes that program at 11.65 GiB of
+# a chip's 15.75 with nothing kept and at 12.97 with this list. Somewhere
+# between 13.1 and 13.6 GiB it starts to make room by recomputing on its own
+# (XLA's rematerialization), which costs more than a longer list saves (the
+# dense pair beside these, or flash's pair alone: a slower step), and from
+# 15.75 on it refuses the program (the four groups together). The runtime's
+# `peak_bytes_in_use` sees none of this: it reads 9.4 GB with or without.
+_KEPT = ("mla_q_a", "mla_kv_a", "shared_gate", "shared_up")
 _FLASH_BLOCK = 512           # square tile of the causal flash kernels
 ROUTERS = ("sigmoid_noaux_tc", "softmax_topk")
 
@@ -96,6 +114,9 @@ def rope(x: jnp.ndarray, theta: float) -> jnp.ndarray:
 
 
 class SwiGlu(nn.Module):
+    """(silu(x Wg) * (x Wu)) Wd. The two up-products carry names a
+    recomputation can keep (`_KEPT`); the elementwise pass after them it
+    makes again."""
     mlp_dim: int
     model_dim: int
     dtype: jnp.dtype = jnp.bfloat16
@@ -104,9 +125,11 @@ class SwiGlu(nn.Module):
     def __call__(self, x):
         dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=self.dtype,
                                          name=name)
-        h = nn.silu(dense(self.mlp_dim, "wi_0")(x)) \
-            * dense(self.mlp_dim, "wi_1")(x)
-        return dense(self.model_dim, "wo_mlp")(h)
+        gate = checkpoint_name(dense(self.mlp_dim, "wi_0")(x),
+                               f"{self.name}_gate")
+        up = checkpoint_name(dense(self.mlp_dim, "wi_1")(x),
+                             f"{self.name}_up")
+        return dense(self.model_dim, "wo_mlp")(nn.silu(gate) * up)
 
 
 class MlaAttention(nn.Module):
@@ -131,9 +154,14 @@ class MlaAttention(nn.Module):
                                          name=name)
         norm = lambda name: RmsNorm(dtype=self.dtype, eps=self.norm_eps,
                                     name=name)
-        cq = norm("q_norm")(dense(self.q_lora_rank, "wq_a")(x))
+        # the products carry the names, not the normed latents: a norm's
+        # backward reads its input, so keeping what it returns would spare
+        # the second forward the norm and not the product
+        cq = norm("q_norm")(checkpoint_name(
+            dense(self.q_lora_rank, "wq_a")(x), "mla_q_a"))
         q = dense(H * (nope + rp), "wq_b")(cq).reshape(B, L, H, nope + rp)
-        kv = dense(self.kv_lora_rank + rp, "wkv_a")(x)
+        kv = checkpoint_name(dense(self.kv_lora_rank + rp, "wkv_a")(x),
+                             "mla_kv_a")
         ckv = norm("kv_norm")(kv[..., :self.kv_lora_rank])
         k_rope = rope(kv[..., None, self.kv_lora_rank:], self.rope_theta)
         kv = dense(H * (nope + vd), "wkv_b")(ckv).reshape(B, L, H, nope + vd)
@@ -403,7 +431,12 @@ class Blocks(nn.Module):
     """All the blocks, for one group of rows; a scan body, (carry, (x,
     pad_mask)) -> (carry, (y, the expert layers' counters stacked by
     layer)). With `remat` each half block is recomputed in the backward
-    pass, which then holds one half's activations at a time."""
+    pass from its input and the values `_KEPT` lists, which the first
+    forward hands over: the backward then holds one half's other
+    activations at a time and does not make a listed product twice.
+    Everything else in a half (norms, the up-projections to heads, RoPE,
+    flash's forward, the relayouts around it, the router, the plan, the
+    routed part, a SwiGLU's elementwise pass) is made again."""
     sizes: GlmSizes
     num_layers: int
     remat: bool = False
@@ -415,8 +448,10 @@ class Blocks(nn.Module):
     @nn.compact
     def __call__(self, carry, xs):
         x, pad_mask = xs
-        mix = nn.remat(MixHalf) if self.remat else MixHalf
-        ffn = nn.remat(FfnHalf) if self.remat else FfnHalf
+        mix, ffn = MixHalf, FfnHalf
+        if self.remat:
+            keep = jax.checkpoint_policies.save_only_these_names(*_KEPT)
+            mix, ffn = (nn.remat(half, policy=keep) for half in (mix, ffn))
         common = dict(deterministic=self.deterministic, dropout=self.dropout,
                       dtype=self.dtype)
         stats = []
@@ -439,7 +474,10 @@ class GlmMoeEncoder(nn.Module):
     num_layers: int
     out_dim: int
     dropout: float = 0.0
-    remat: bool = False           # recompute each half block in the backward
+    # recompute each half block in the backward, but for what `_KEPT` lists
+    # (8.8 KB a token and expert layer, 2.7 KB for a dense one, beside the 8
+    # KB of the halves' inputs): memory for tokens in flight, not for weights
+    remat: bool = False
     dtype: jnp.dtype = jnp.bfloat16
     attention_kind: str = "flash"
     # no field: what Trainer and BulkEmbedder ask a tower before they apply

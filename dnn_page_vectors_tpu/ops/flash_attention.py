@@ -41,6 +41,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -805,8 +806,18 @@ def _flash_causal(q, k, v, kv_mask, block, interpret):
     return _causal_forward(q, k, v, kv_mask, block, interpret)[0]
 
 
+# The names the causal forward's two outputs carry into the residuals: a
+# recomputation whose policy lists them (`jax.checkpoint_policies.
+# save_only_these_names`) keeps both and does not launch `flash_fwd` a second
+# time. Outside a recomputation `checkpoint_name` is the identity. (The
+# sparse tower's list, `models/glm_moe.py:_KEPT`, leaves them out for now:
+# its step program has no room for 10 KB a token and layer.)
+CAUSAL_RESIDUALS = ("flash_out", "flash_lse")
+
+
 def _causal_fwd(q, k, v, kv_mask, block, interpret):
     out, lse = _causal_forward(q, k, v, kv_mask, block, interpret)
+    out, lse = map(checkpoint_name, (out, lse), CAUSAL_RESIDUALS)
     return out, (q, k, v, kv_mask, out, lse)
 
 

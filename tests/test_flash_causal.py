@@ -1,11 +1,14 @@
 """The causal flash kernels (KV-tiled, tiles above the diagonal skipped)
 against dense causal attention at head width 256: forward and gradients,
 with padding, at lengths that are and are not whole tiles."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dnn_page_vectors_tpu.ops import flash_attention as fa
 from dnn_page_vectors_tpu.ops.flash_attention import (
     flash_attention, reference_attention)
 
@@ -61,3 +64,39 @@ def test_future_keys_do_not_reach_the_past():
     b = f(k.at[:, :, 20:].add(3.0), v.at[:, :, 20:].add(-2.0))
     np.testing.assert_array_equal(a[:, :, :20], b[:, :, :20])
     assert float(jnp.abs(a[:, :, 20:] - b[:, :, 20:]).max()) > 1e-3
+
+
+def test_the_residuals_names_are_inert_where_nothing_is_recomputed(
+        monkeypatch):
+    """`out` and `lse` go into the residuals under names that a
+    recomputation's policy can list (models/glm_moe.py:Blocks). Without a
+    recomputation they are the identity: the forward-only call lowers to a
+    text that holds neither name, and values and gradients are those of the
+    kernels with the names taken off, bit for bit."""
+    rng = np.random.default_rng(2)
+    q, k, v = (jnp.asarray(rng.normal(size=(2, 2, 40, 256)), jnp.float32)
+               for _ in range(3))
+    mask = jnp.asarray(np.arange(40)[None, :] < np.array([40, 29])[:, None])
+
+    def lower_and_run():
+        f = lambda q, k, v: flash_attention(q, k, v, mask, causal=True,
+                                            block_q=16, block_kv=16)
+        loss = lambda q, k, v: jnp.sum(jnp.sin(f(q, k, v)))
+        grad = jax.grad(loss, (0, 1, 2))
+        # but for the numbers the lowering gives its private functions
+        text, grad_text = (
+            re.sub(r"@(\w+?)_\d+\b", r"@\1",
+                   jax.jit(g).lower(q, k, v).as_text())
+            for g in (f, grad))
+        return text, grad_text, f(q, k, v), grad(q, k, v)
+
+    text, grad_text, out, grads = lower_and_run()
+    monkeypatch.setattr(fa, "checkpoint_name", lambda x, name: x)
+    text0, grad_text0, out0, grads0 = lower_and_run()
+    assert fa.CAUSAL_RESIDUALS == ("flash_out", "flash_lse")
+    for name in fa.CAUSAL_RESIDUALS:
+        assert name not in text and name not in grad_text
+    assert (text, grad_text) == (text0, grad_text0)
+    np.testing.assert_array_equal(out, out0)
+    for a, b in zip(grads, grads0):
+        np.testing.assert_array_equal(a, b)

@@ -4,6 +4,7 @@ experts tied to the uncut layer, no assignment dropped, the expected-load
 buffers against the worst-case ones (bit for bit, and the fallback
 counted), the last-token pool, and the selection bias (it selects, never
 weighs, and is held)."""
+import collections
 import os
 import sys
 
@@ -377,6 +378,137 @@ def test_no_worst_case_sized_array_on_the_expected_path_at_the_cells_widths(
         _rows_of(b.jaxpr, 18432) for eqn in conds
         for b in eqn.params["branches"])
     assert outside == 0
+
+
+def _calls(jaxpr, counts=None):
+    """Pallas calls by kernel name, and `dot_general`s outside kernels, in
+    a jaxpr and in what it calls."""
+    counts = collections.Counter() if counts is None else counts
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            counts[eqn.params["name"]] += 1
+            continue
+        counts["dot_general"] += eqn.primitive.name == "dot_general"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _calls(sub, counts)
+    return counts
+
+
+# Every value a half block names for its recomputation, by group, and the
+# products (or the kernel) a kept group spares the second forward at one
+# site of one layer
+NAMED = {"flash": ("flash_out", "flash_lse"),
+         "dense": ("mlp_gate", "mlp_up"),
+         "shared": ("shared_gate", "shared_up"),
+         "latents": ("mla_q_a", "mla_kv_a")}
+EVERY_NAME = sum(NAMED.values(), ())
+
+
+def _groups_kept():
+    assert set(glm_moe._KEPT) <= set(EVERY_NAME)
+    return {g for g, names in NAMED.items()
+            if set(names) <= set(glm_moe._KEPT)}
+
+
+# the file's widths; 3 layers (1 dense, 2 expert) x (queries + pages) = 6
+# sites of causal flash (the pages' groups are one scan body). `cond`: 2 of
+# 8 experts held, so the routed part is `_routed`, whose backward runs its
+# own forward again. bfloat16: a kept value is rounded to its 8 bits of
+# mantissa where XLA lets a value it makes again ride in float32 through
+# the fusion that reads it, so there the gradients agree to rounding (2.3%
+# of a leaf's norm at most over 3 seeds) and not to the bit; the loss does
+@pytest.mark.parametrize("dtype,row_group,held,grad_tol,every", [
+    ("float32", 4096, 4, 0, False), ("float32", 4096, 4, 0, True),
+    ("float32", 64, 4, 0, True), ("bfloat16", 4096, 4, 0.05, True),
+    ("float32", 64, 2, 0, False)],
+    ids=["as_listed", "every_name", "row_groups", "bfloat16", "cond"])
+def test_a_recomputed_half_keeps_the_named_values(monkeypatch, dtype,
+                                                  row_group, held, grad_tol,
+                                                  every):
+    """With `remat_blocks` the second forward of a half block makes nothing
+    again that `_KEPT` lists: no product of a listed SwiGLU pair or latent,
+    and with flash's pair listed no second `flash_fwd` (one forward a site,
+    as many as `flash_dq` and `flash_dkv`), where a recomputation that
+    keeps nothing launches two. Loss and every gradient leaf are that
+    recomputation's bit for bit in float32, a kept value being the bits
+    the second forward would have made. `every`: with every name the
+    towers give listed, whatever the tree's list admits."""
+    monkeypatch.setattr(glm_moe, "_ROW_GROUP_TOKENS", row_group)
+    if held == 2:
+        monkeypatch.setattr(glm_moe, "_EXPERT_TILE", 8)
+    if every:
+        monkeypatch.setattr(glm_moe, "_KEPT", EVERY_NAME)
+    groups = _groups_kept()
+    cfg = _config(dtype, held=held, **{"model.remat_blocks": True})
+    model, params = _model_and_params(cfg)
+    q, p = _ids()
+
+    def trace_and_run():
+        # a function of its own each time: a trace is cached by function
+        step = jax.value_and_grad(lambda v: _program(model, v, q, p)[0])
+        return (_calls(jax.make_jaxpr(step)(params).jaxpr),
+                jax.jit(step)(params))
+
+    kept, (loss, grads) = trace_and_run()
+    monkeypatch.setattr(glm_moe, "_KEPT", ())
+    nothing, (loss0, grads0) = trace_and_run()
+    assert nothing["flash_fwd"] == 12
+    assert kept["flash_fwd"] == (6 if "flash" in groups else 12)
+    for calls in (kept, nothing):
+        assert (calls["flash_dq"], calls["flash_dkv"]) == (6, 6)
+        for name in ("moe_gmm", "moe_tgmm"):     # the routed part: as before
+            assert calls[name] == nothing[name] > 0
+    # gate and up of 1 dense and 2 expert layers, `wq_a` and `wkv_a` of 3,
+    # at 2 sites each
+    spared = 2 * (2 * 1 * ("dense" in groups) + 2 * 2 * ("shared" in groups)
+                  + 2 * 3 * ("latents" in groups))
+    assert nothing["dot_general"] - kept["dot_general"] == spared
+    assert float(loss) == float(loss0)
+    norm = lambda t: float(jnp.sqrt(jnp.sum(jnp.square(t))))
+    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
+    for (path, a), b in zip(flat, jax.tree_util.tree_leaves(grads0)):
+        assert norm(a - b) <= grad_tol * max(norm(b), 1e-3), \
+            weights_moe.path_str(path)
+
+
+@pytest.mark.parametrize("kept", ["as_listed", "every_name", "nothing"])
+def test_what_a_recomputed_block_keeps_at_the_cells_widths(monkeypatch, kept):
+    """One row group of `glm47_flash_ep8` through a dense and an expert
+    block, shapes only: every value of a token's size or more that the
+    forward hands the backward, in bytes a token. The figures are those of
+    PERF.md's memory reckoning (section 6, PR 36)."""
+    from jax._src.ad_checkpoint import saved_residuals
+    from dnn_page_vectors_tpu.models.factory import _build_encoder
+    if kept != "as_listed":
+        monkeypatch.setattr(glm_moe, "_KEPT",
+                            EVERY_NAME if kept == "every_name" else ())
+    tower = _build_encoder(get_config("glm47_flash_ep8"), 19360, "tower")
+    blocks = glm_moe.Blocks(tower.sizes, 2, remat=True, dtype=tower.dtype)
+    B, L = 4, 1024
+    x = jax.ShapeDtypeStruct((B, L, 2048), jnp.bfloat16)
+    mask = jnp.ones((B, L), bool)
+    params = jax.eval_shape(blocks.init, jax.random.PRNGKey(0), None,
+                            (x, mask))
+    saved = saved_residuals(
+        lambda v, x: blocks.apply(v, None, (x, mask))[1][0], params, x)
+    found = collections.Counter(
+        (aval.shape, aval.size * aval.dtype.itemsize // (B * L))
+        for aval, src in saved
+        if "from the argument" not in src and aval.size >= B * L)
+    # a half's input (the block's own input is an argument) and the mask
+    want = collections.Counter({((B, L, 2048), 4096): 3, ((B, L), 1): 1})
+    # a group's values over the two blocks, and the sites that make them
+    sizes = {"flash": ({((B, 20, L, 256), 10240): 2, ((B, 20, L), 80): 2}, 2),
+             "dense": ({((B, L, 10240), 20480): 2}, 1),
+             "shared": ({((B * L, 1536), 3072): 2}, 1),
+             "latents": ({((B, L, 768), 1536): 2, ((B, L, 576), 1152): 2}, 2)}
+    per_token = {g: sum(size * n for (_, size), n in values.items()) // sites
+                 for g, (values, sites) in sizes.items()}
+    assert per_token == {"flash": 10320, "dense": 40960, "shared": 6144,
+                         "latents": 2688}
+    for group in _groups_kept():
+        want.update(sizes[group][0])
+    assert found == want
 
 
 def test_the_bias_selects_and_never_weighs():

@@ -316,6 +316,44 @@ def test_bulk_embedder_holds_the_matrices_in_bfloat16(arrives):
     assert int(sums[1]) == int(held.sum()) and int(sums[3]) == 0
 
 
+def test_the_recomputations_names_are_inert_in_a_tower_that_recomputes_nothing(
+        monkeypatch):
+    """The shared expert's `SwiGlu` and the causal flash forward name values
+    for the sparse tower's half-block recomputation (models/glm_moe.py:
+    `_KEPT`). This tower recomputes nothing: its counted encode lowers to
+    the text it has with the names taken off, which holds none of them, and
+    a step's loss and gradients are the same bits."""
+    from dnn_page_vectors_tpu.ops import flash_attention
+    cfg = _config("bfloat16", **{"model.weights_dtype": "bfloat16",
+                                 "mesh.data": 1})
+    model, params = _model_and_params(cfg, weights_dtype="bfloat16")
+    q, p = _ids()
+    ids = jnp.zeros((2, 16), jnp.int32)
+
+    def lower_and_step():
+        emb = _embedder(cfg, params, model)
+        assert emb.counts_encode
+        text = emb._encode_query.lower(emb.params, ids).as_text()
+        # but for the numbers the lowering gives its private functions
+        return (re.sub(r"@(\w+?)_\d+\b", r"@\1", text),
+                jax.jit(jax.value_and_grad(
+                    lambda v: _program(model, v, q, p)[0]))(params))
+
+    text, (loss, grads) = lower_and_step()
+    for module in (glm_moe, flash_attention):
+        monkeypatch.setattr(module, "checkpoint_name", lambda x, name: x)
+    text0, (loss0, grads0) = lower_and_step()
+    assert text == text0
+    assert not [name for name in ("shared_gate", "shared_up")
+                + flash_attention.CAUSAL_RESIDUALS if name in text]
+    assert float(loss) == float(loss0)
+    flat = jax.tree_util.tree_flatten_with_path(grads)[0]
+    for (path, a), b in zip(flat, jax.tree_util.tree_leaves(grads0)):
+        np.testing.assert_array_equal(
+            np.asarray(a, np.float32), np.asarray(b, np.float32),
+            err_msg=weights.path_str(path))
+
+
 def test_a_float32_tower_stays_float32_bit_for_bit():
     """`bert_mini`'s preset: `hold_weights` hands back the tree it was
     given, and the encode hands back vectors alone."""
