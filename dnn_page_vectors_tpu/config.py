@@ -50,6 +50,7 @@ class DataConfig:
 class ModelConfig:
     """Encoder zoo settings. `encoder` selects the family."""
     # cdssm | kim_cnn | lstm | bert | t5 | glm4_moe_lite | granitemoehybrid
+    # | falcon_h1
     encoder: str = "cdssm"
     embed_dim: int = 128             # token/word embedding width
     out_dim: int = 128               # final vector dimension (both towers)
@@ -107,6 +108,21 @@ class ModelConfig:
     mamba_expand: int = 2
     mamba_d_conv: int = 4
     mamba_chunk_size: int = 256
+    # falcon_h1 (models/falcon_h1.py): the published keys under their
+    # published names, beside the mamba_*, num_key_value_heads, rope_theta,
+    # rms_norm_eps and embedding_multiplier above (mlp_dim is its
+    # intermediate_size, num_layers its num_hidden_layers, num_heads its
+    # num_attention_heads). mamba_n_groups is read by granitemoehybrid too.
+    head_dim: int = 128
+    mamba_d_ssm: int = 4096                    # the mixer's inner width
+    mamba_n_groups: int = 1
+    ssm_in_multiplier: float = 1.0
+    ssm_multipliers: Tuple[float, ...] = ()    # z, x, B, C, dt
+    ssm_out_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    mlp_multipliers: Tuple[float, ...] = ()    # gate, down
     # What BulkEmbedder / SearchService hold a tower's matrices in
     # (infer/bulk_embed.py:hold_weights): float32 as trained, or bfloat16
     # (cast once at construction; what the tower computes with in float32
@@ -587,6 +603,17 @@ class Config:
         return dataclasses.replace(self, **kw)
 
 
+def _tuple_item(text: str):
+    """One item of a comma-separated tuple on the command line: an int, a
+    float (the multipliers), or the text itself (layer types)."""
+    for kind in (int, float):
+        try:
+            return kind(text)
+        except ValueError:
+            pass
+    return text
+
+
 def _nested_replace(cfg: Config, overrides: Dict[str, Any]) -> Config:
     """Apply dotted-path overrides, e.g. {"train.steps": 10}."""
     for path, value in overrides.items():
@@ -611,8 +638,7 @@ def _nested_replace(cfg: Config, overrides: Dict[str, Any]) -> Config:
             elif isinstance(current, float):
                 value = float(value)
             elif isinstance(current, tuple):
-                value = tuple(int(x) if x.lstrip("-").isdigit() else x
-                              for x in str(value).split(","))
+                value = tuple(_tuple_item(x) for x in str(value).split(","))
         elif isinstance(value, list):
             value = tuple(value)
         section = dataclasses.replace(section, **{parts[1]: value})
@@ -787,6 +813,47 @@ def granite4_h_small_ep2() -> Config:
     )
 
 
+def falcon_h1_34b_pp12() -> Config:
+    """Falcon-H1-34B-Instruct (tiiuae, `falcon_h1`) as ONE shared tower for
+    SERVING: the first six whole layers of the published 72 (the first of
+    twelve pipeline stages, one chip a stage, which also holds the whole
+    embedding), every width, head, group and all 261,120 rows as published,
+    weights held in bfloat16. In every block a Mamba-2 mixer (32 heads of
+    128 in 2 groups, state 256, chunk 128) beside grouped-query attention
+    (20 + 4 heads of 128, rotary at theta 1e11), then a dense SwiGLU of
+    21,504; twelve muP multipliers. Whole pages as queries: 1,024 tokens,
+    encoded one a call (benchmarks/configs/falcon_h1_34b_pp12.json states
+    the cut; at 16 bytes a parameter of training state four layers and an
+    eighth of the vocabulary are 30 GB, twice one chip)."""
+    return Config(
+        name="falcon_h1_34b_pp12",
+        data=DataConfig(tokenizer="wordpiece", corpus="toy",
+                        num_pages=1_048_576, vocab_size=261_120,
+                        page_len=1024, query_len=1024),
+        model=ModelConfig(
+            encoder="falcon_h1", num_layers=6, num_heads=20,
+            num_key_value_heads=4, head_dim=128, model_dim=5120,
+            mlp_dim=21_504, out_dim=1024, attention="flash", dropout=0.0,
+            shared_towers=True, rope_theta=1e11, rms_norm_eps=1e-5,
+            mamba_n_heads=32, mamba_d_head=128, mamba_d_ssm=4096,
+            mamba_d_state=256, mamba_n_groups=2, mamba_d_conv=4,
+            mamba_chunk_size=128, mamba_expand=2,
+            embedding_multiplier=5.656854249492381, ssm_in_multiplier=0.25,
+            ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369,
+                             0.5, 0.3535533905932738),
+            ssm_out_multiplier=0.08838834764831845,
+            attention_in_multiplier=1.0,
+            attention_out_multiplier=0.0375,
+            key_multiplier=0.011048543456039804,
+            mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
+            weights_dtype="bfloat16"),
+        mesh=MeshConfig(data=1),
+        train=TrainConfig(batch_size=4, steps=1_000, learning_rate=1e-4),
+        eval=EvalConfig(embed_batch_size=4),
+        serve=ServeConfig(max_batch=4, encode_batch=1, query_cache_size=0),
+    )
+
+
 CONFIGS = {
     "cdssm_toy": cdssm_toy,
     "kim_cnn_v5e8": kim_cnn_v5e8,
@@ -797,6 +864,7 @@ CONFIGS = {
     "bert_long_sp": bert_long_sp,
     "glm47_flash_ep8": glm47_flash_ep8,
     "granite4_h_small_ep2": granite4_h_small_ep2,
+    "falcon_h1_34b_pp12": falcon_h1_34b_pp12,
 }
 
 

@@ -181,11 +181,15 @@ def hold_weights(params, dtype: str):
 
 
 def _encode_counters(ids, stats):
-    """What one encode call of a tower that sows `moe_stats` counted, on the
-    device: ([4] int32 in ENCODE_COUNTERS' order: non-pad tokens, assignments
-    on the experts held, tiles of the grouped product in use, assignments
-    dropped, summed over the layers; [layers, experts held] int32, the
-    assignments per held expert those sums were made from)."""
+    """What one encode call counted, on the device: ([4] int32 in
+    ENCODE_COUNTERS' order: non-pad tokens, assignments on the experts held,
+    tiles of the grouped product in use, assignments dropped, summed over
+    the layers; [layers, experts held] int32, the assignments per held
+    expert those sums were made from). `stats` is what the tower sowed into
+    `moe_stats`; None, from a tower without routed layers, leaves the three
+    sums at 0 and the second value None."""
+    if stats is None:
+        return jnp.zeros(4, jnp.int32).at[0].set((ids > 0).sum()), None
     held = sum(stats["held"]).astype(jnp.int32)
     tiles = jnp.maximum(-(-held // _EXPERT_TILE), 1)
     sums = jnp.stack([(ids > 0).sum(), held.sum(), tiles.sum(),
@@ -235,20 +239,23 @@ class BulkEmbedder:
         self._encode_query = jax.jit(
             lambda p, x: _encode(p, x, "encode_query"),
             in_shardings=(None, batch_sharding(mesh)), out_shardings=out_sh)
-        # A tower that sows its routed layers' counters (`moe_stats`; the
-        # tower says so itself) hands them back beside the vectors, reduced
-        # on the device (_encode_counters). Decided here, once; every other
-        # tower keeps the program above. Callers go through
-        # encode_query_call, which gives both kinds one shape.
-        self.counts_encode = getattr(model.query_tower, "sows_moe_stats",
-                                     False)
+        # A tower that asks to have its encode counted (it says so itself:
+        # `counts_encode_tokens`, or `sows_moe_stats` where it sows its
+        # routed layers' counters into `moe_stats`) gets the counts handed
+        # back beside the vectors, reduced on the device (_encode_counters):
+        # the tokens always, the routed layers' three where sown. Decided
+        # here, once; every other tower keeps the program above. Callers go
+        # through encode_query_call, which gives all kinds one shape.
+        sows = getattr(model.query_tower, "sows_moe_stats", False)
+        self.counts_encode = sows or getattr(
+            model.query_tower, "counts_encode_tokens", False)
         if self.counts_encode:
             def _encode_counted(params, ids):
                 vecs, sown = model.apply(params, ids, deterministic=True,
                                          method="encode_query",
                                          mutable=[MOE_STATS])
                 return l2_normalize(vecs), _encode_counters(
-                    ids, sown[MOE_STATS]["query_tower"])
+                    ids, sown[MOE_STATS]["query_tower"] if sows else None)
 
             self._encode_query = jax.jit(
                 _encode_counted, in_shardings=(None, batch_sharding(mesh)),
@@ -320,9 +327,9 @@ class BulkEmbedder:
     def encode_query_call(self, ids: np.ndarray, params=None):
         """One call of the compiled query encode on host ids [B, L], left on
         the device: (unit vectors [B, D], what the call counted). The second
-        is _encode_counters' pair for a tower that sows `moe_stats` and None
-        for every other. `params`: the serving step's tree (default: the
-        embedder's own)."""
+        is _encode_counters' pair for a tower that asks to be counted and
+        None for every other. `params`: the serving step's tree (default:
+        the embedder's own)."""
         out = self._encode_query(self.params if params is None else params,
                                  self._put(ids))
         return out if self.counts_encode else (out, None)

@@ -526,8 +526,9 @@ class SearchService:
                                          window_s=window_s)
         self._m_cache_misses = reg.counter("serve.cache_misses",
                                            window_s=window_s)
-        # what a tower with routed experts counts per encode call (the
-        # embedder reduces them on the device; BulkEmbedder.encode_query_call)
+        # what a tower that asks for it has counted per encode call: its
+        # tokens, and its routed layers' three where it has any (the embedder
+        # reduces them on the device; BulkEmbedder.encode_query_call)
         self._m_encode = {name: reg.counter("encode." + name)
                           for name in BulkEmbedder.ENCODE_COUNTERS}
         self._m_ann_lists = reg.counter("serve.ann_lists_scanned")

@@ -8,6 +8,8 @@ import jax.numpy as jnp
 
 from dnn_page_vectors_tpu.config import Config
 from dnn_page_vectors_tpu.models.cdssm import CdssmEncoder
+from dnn_page_vectors_tpu.models.falcon_h1 import (FalconH1Encoder,
+                                                   FalconH1Sizes)
 from dnn_page_vectors_tpu.models.glm_moe import GlmMoeEncoder, GlmSizes
 from dnn_page_vectors_tpu.models.granite_hybrid import (GraniteHybridEncoder,
                                                         GraniteSizes)
@@ -83,7 +85,7 @@ def _build_encoder(cfg: Config, vocab_size: int, name: str,
             residual_multiplier=m.residual_multiplier,
             mamba_n_heads=m.mamba_n_heads, mamba_d_head=m.mamba_d_head,
             mamba_d_state=m.mamba_d_state, mamba_expand=m.mamba_expand,
-            mamba_d_conv=m.mamba_d_conv,
+            mamba_d_conv=m.mamba_d_conv, mamba_n_groups=m.mamba_n_groups,
             mamba_chunk_size=m.mamba_chunk_size, moe_mlp_dim=m.mlp_dim,
             shared_mlp_dim=m.shared_intermediate_size,
             n_routed_experts=m.n_routed_experts,
@@ -95,6 +97,28 @@ def _build_encoder(cfg: Config, vocab_size: int, name: str,
                                     out_dim=m.out_dim,
                                     attention_kind=m.attention, dtype=dtype,
                                     name=name)
+    if m.encoder == "falcon_h1":
+        sizes = FalconH1Sizes(
+            model_dim=m.model_dim, mlp_dim=m.mlp_dim, num_heads=m.num_heads,
+            num_kv_heads=m.num_key_value_heads, head_dim=m.head_dim,
+            rope_theta=m.rope_theta, mamba_n_heads=m.mamba_n_heads,
+            mamba_d_head=m.mamba_d_head, mamba_d_ssm=m.mamba_d_ssm,
+            mamba_d_state=m.mamba_d_state, mamba_n_groups=m.mamba_n_groups,
+            mamba_d_conv=m.mamba_d_conv,
+            mamba_chunk_size=m.mamba_chunk_size,
+            embedding_multiplier=m.embedding_multiplier,
+            ssm_in_multiplier=m.ssm_in_multiplier,
+            ssm_multipliers=tuple(m.ssm_multipliers),
+            ssm_out_multiplier=m.ssm_out_multiplier,
+            attention_in_multiplier=m.attention_in_multiplier,
+            key_multiplier=m.key_multiplier,
+            attention_out_multiplier=m.attention_out_multiplier,
+            mlp_multipliers=tuple(m.mlp_multipliers),
+            norm_eps=m.rms_norm_eps)
+        return FalconH1Encoder(vocab_size=vocab_size, sizes=sizes,
+                               num_layers=m.num_layers, out_dim=m.out_dim,
+                               attention_kind=m.attention, dtype=dtype,
+                               name=name)
     raise ValueError(f"unknown encoder {cfg.model.encoder!r}")
 
 

@@ -113,23 +113,35 @@ def rope(x: jnp.ndarray, theta: float) -> jnp.ndarray:
                            axis=-1).astype(x.dtype)
 
 
+def times(x: jnp.ndarray, m: float) -> jnp.ndarray:
+    """x * m in x's dtype; a multiplier of 1 is not multiplied in."""
+    return x if m == 1.0 else x * jnp.asarray(m, x.dtype)
+
+
 class SwiGlu(nn.Module):
-    """(silu(x Wg) * (x Wu)) Wd. The two up-products carry names a
-    recomputation can keep (`_KEPT`); the elementwise pass after them it
-    makes again."""
+    """down * ((silu(gate * (x Wg)) * (x Wu)) Wd), `gate` and `down` two
+    constant multipliers (models/falcon_h1.py; 1.0 is not multiplied in).
+    The two up-products carry names a recomputation can keep (`_KEPT`); the
+    elementwise pass after them it makes again."""
     mlp_dim: int
     model_dim: int
     dtype: jnp.dtype = jnp.bfloat16
+    gate_multiplier: float = 1.0
+    down_multiplier: float = 1.0
 
     @nn.compact
     def __call__(self, x):
         dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=self.dtype,
                                          name=name)
-        gate = checkpoint_name(dense(self.mlp_dim, "wi_0")(x),
-                               f"{self.name}_gate")
-        up = checkpoint_name(dense(self.mlp_dim, "wi_1")(x),
-                             f"{self.name}_up")
-        return dense(self.model_dim, "wo_mlp")(nn.silu(gate) * up)
+        with jax.named_scope("mlp.gate_up"):
+            gate = checkpoint_name(dense(self.mlp_dim, "wi_0")(x),
+                                   f"{self.name}_gate")
+            up = checkpoint_name(dense(self.mlp_dim, "wi_1")(x),
+                                 f"{self.name}_up")
+            h = nn.silu(times(gate, self.gate_multiplier)) * up
+        with jax.named_scope("mlp.down"):
+            return times(dense(self.model_dim, "wo_mlp")(h),
+                         self.down_multiplier)
 
 
 class MlaAttention(nn.Module):

@@ -28,20 +28,33 @@ Routed  models/glm_moe.py:RoutedExperts with `router="softmax_topk"`: the k
         out. Shared: the same SwiGLU at `shared_intermediate_size`.
 
 The causal flash kernels scale scores by 1/sqrt(head_dim), so q is scaled by
-attention_multiplier * sqrt(head_dim) before them; the key/value heads are
-repeated to the query heads' count (one layer in ten; an index map in the
-kernel would save two small copies).
+attention_multiplier * sqrt(head_dim) before them (not at all where that is
+1); the key/value heads are repeated to the query heads' count (an index map
+in the kernel would save two small copies).
+
+`Mamba2Mixer` and `GqaAttention` are the one mixer and the one attention of
+BOTH hybrid towers (models/falcon_h1.py builds them too). What differs
+arrives as sizes, and a size at its default adds no operation, so this tower
+lowers to the program it had before the other came: the mixer takes
+`mamba_n_groups` (B and C [groups x N]; head h reads group h // (heads /
+groups); the gated norm is by group), an inner width of heads x d_head
+whatever `mamba_expand` says, `ssm_in_multiplier` on its input and
+`ssm_multipliers` over the projection's segments [z | x | B | C | dt]; the
+attention takes `head_dim` (0: hidden / heads), `rope_theta` (0: no
+positions; else rotary over the whole head, half-split pairing),
+`key_multiplier` on k, and the score scale `attention_multiplier`.
 
 Device-side scopes (docs/OBSERVABILITY.md): `mamba`, `mamba.in_proj`,
 `mamba.conv`, `mamba.ssd`, `mamba.gate_norm`, `mamba.out_proj`, `attn`,
-`attn.flash`, and the expert layer's `moe`, `moe.*`. Counters are sown into
+`attn.qkv`, `attn.rope` (where there is rotary), `attn.flash`, `attn.out`,
+and the expert layer's `moe`, `moe.*`. Counters are sown into
 `moe_stats` as the GLM tower sows them (one entry per layer, stacked).
 """
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Tuple
+from typing import Any, Tuple
 
 import flax.linen as nn
 import jax
@@ -49,7 +62,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from dnn_page_vectors_tpu.models.glm_moe import (STATS, _FLASH_BLOCK,
-                                                 RoutedExperts, last_token)
+                                                 RoutedExperts, last_token,
+                                                 rope, times)
 from dnn_page_vectors_tpu.models.transformer import RmsNorm
 from dnn_page_vectors_tpu.ops.ssd_scan import ssd_scan
 
@@ -80,6 +94,14 @@ class GraniteSizes:
     experts_held: int
     experts_held_start: int = 0
     norm_eps: float = 1e-5
+    # what the shared mixer and attention read besides, at this family's
+    # values (one group, no multipliers, hidden / heads, no positions)
+    mamba_n_groups: int = 1
+    ssm_in_multiplier: float = 1.0
+    ssm_multipliers: Tuple[float, ...] = ()
+    head_dim: int = 0
+    rope_theta: float = 0.0
+    key_multiplier: float = 1.0
 
     def __post_init__(self):
         bad = [t for t in self.layer_types if t not in LAYER_TYPES]
@@ -118,20 +140,36 @@ def causal_conv(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray):
         + b.astype(x.dtype)
 
 
+def segment_multipliers(multipliers, widths) -> np.ndarray:
+    """The constant vector that scales the mixer's projection: one of the
+    five `multipliers` over each of the segments [z | x | B | C | dt] of
+    `widths`, in that order."""
+    if len(multipliers) != len(widths):
+        raise ValueError(f"{len(multipliers)} multipliers for the "
+                         f"{len(widths)} segments of the mixer's projection")
+    return np.repeat(np.asarray(multipliers, np.float32), widths)
+
+
 class Mamba2Mixer(nn.Module):
-    sizes: GraniteSizes
+    sizes: Any                    # GraniteSizes | models/falcon_h1.py's
     dtype: jnp.dtype = jnp.bfloat16
 
     @nn.compact
     def __call__(self, u: jnp.ndarray) -> jnp.ndarray:
         c = self.sizes
         B, L, d = u.shape
-        H, P, N = c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state
-        inner, conv_dim = H * P, H * P + 2 * N
+        H, P, N, G = (c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state,
+                      c.mamba_n_groups)
+        inner, conv_dim = H * P, H * P + 2 * G * N
         dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=self.dtype,
                                          name=name)
         with jax.named_scope("mamba.in_proj"):
-            zxd = dense(inner + conv_dim + H, "in_proj")(u)
+            zxd = dense(inner + conv_dim + H, "in_proj")(
+                times(u, c.ssm_in_multiplier))
+            if c.ssm_multipliers:
+                zxd = zxd * jnp.asarray(segment_multipliers(
+                    c.ssm_multipliers, (inner, inner, G * N, G * N, H)),
+                    zxd.dtype)
         z, xbc, dt = (zxd[..., :inner], zxd[..., inner:inner + conv_dim],
                       zxd[..., inner + conv_dim:])
         with jax.named_scope("mamba.conv"):
@@ -144,22 +182,27 @@ class Mamba2Mixer(nn.Module):
         a_log = self.param("A_log", _a_log_init, (H,))
         dt_bias = self.param("dt_bias", _dt_bias_init, (H,))
         skip = self.param("D", nn.initializers.ones, (H,))
+        # B and C by group; one group's stay [B, L, N]
+        grouped = lambda t: t if G == 1 else t.reshape(B, L, G, N)
         with jax.named_scope("mamba.ssd"):
             delta = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
-            y = ssd_scan(x, delta, -jnp.exp(a_log), xbc[..., inner:inner + N],
-                         xbc[..., inner + N:], c.mamba_chunk_size)
+            y = ssd_scan(x, delta, -jnp.exp(a_log),
+                         grouped(xbc[..., inner:inner + G * N]),
+                         grouped(xbc[..., inner + G * N:]),
+                         c.mamba_chunk_size)
             y = y + skip[:, None] * x.astype(jnp.float32)
         with jax.named_scope("mamba.gate_norm"):
             g = y.reshape(B, L, inner) * nn.silu(z.astype(jnp.float32))
-            g = RmsNorm(dtype=self.dtype, eps=c.norm_eps, name="norm")(g)
+            g = RmsNorm(dtype=self.dtype, eps=c.norm_eps, groups=G,
+                        name="norm")(g)
         with jax.named_scope("mamba.out_proj"):
             return dense(d, "out_proj")(g)
 
 
 class GqaAttention(nn.Module):
-    """Causal grouped-query attention with no positions and a stated score
-    scale."""
-    sizes: GraniteSizes
+    """Causal grouped-query attention at a stated score scale, with rotary
+    positions or none."""
+    sizes: Any                    # GraniteSizes | models/falcon_h1.py's
     dtype: jnp.dtype = jnp.bfloat16
     kind: str = "flash"           # flash | dense
 
@@ -168,18 +211,24 @@ class GqaAttention(nn.Module):
         c = self.sizes
         B, L, d = u.shape
         H, G = c.num_heads, c.num_kv_heads
-        dh = d // H
+        dh = c.head_dim or d // H
         dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=self.dtype,
                                          name=name)
-        q = dense(H * dh, "wq")(u).reshape(B, L, H, dh)
-        k = dense(G * dh, "wk")(u).reshape(B, L, G, dh)
-        v = dense(G * dh, "wv")(u).reshape(B, L, G, dh)
+        with jax.named_scope("attn.qkv"):
+            q = dense(H * dh, "wq")(u).reshape(B, L, H, dh)
+            k = times(dense(G * dh, "wk")(u), c.key_multiplier).reshape(
+                B, L, G, dh)
+            v = dense(G * dh, "wv")(u).reshape(B, L, G, dh)
+        if c.rope_theta:
+            with jax.named_scope("attn.rope"):
+                q, k = rope(q, c.rope_theta), rope(k, c.rope_theta)
         k, v = (jnp.repeat(t, H // G, axis=2) for t in (k, v))
         bhld = lambda t: t.transpose(0, 2, 1, 3)
         if self.kind == "flash":
             from dnn_page_vectors_tpu.ops.flash_attention import (
                 flash_attention)
-            q = q * jnp.asarray(c.attention_multiplier * np.sqrt(dh), q.dtype)
+            to_kernels = c.attention_multiplier * np.sqrt(dh)
+            q = times(q, 1.0 if np.isclose(to_kernels, 1.0) else to_kernels)
             with jax.named_scope("attn.flash"):
                 out = flash_attention(bhld(q), bhld(k), bhld(v), pad_mask,
                                       block_q=_FLASH_BLOCK,
@@ -197,7 +246,8 @@ class GqaAttention(nn.Module):
         else:
             raise ValueError(f"unknown attention kind {self.kind!r} for the "
                              "hybrid tower (want dense | flash)")
-        return dense(d, "wo")(out.reshape(B, L, H * dh))
+        with jax.named_scope("attn.out"):
+            return dense(d, "wo")(out.reshape(B, L, H * dh))
 
 
 class HybridBlock(nn.Module):

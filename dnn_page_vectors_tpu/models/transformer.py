@@ -42,14 +42,22 @@ def _relative_position_bucket(rel_pos: jnp.ndarray, num_buckets: int = 32,
 
 
 class RmsNorm(nn.Module):
+    """x / rms(x) * scale over the last axis; with `groups` > 1 each of that
+    many equal runs of the axis is divided by its own root mean square (the
+    learned scale still spans the whole axis)."""
     dtype: jnp.dtype = jnp.bfloat16
     eps: float = 1e-6
+    groups: int = 1
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
         xf = x.astype(jnp.float32)
+        if self.groups > 1:
+            xf = xf.reshape(x.shape[:-1] + (self.groups, -1))
         var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
         y = xf * jax.lax.rsqrt(var + self.eps)
+        if self.groups > 1:
+            y = y.reshape(x.shape)
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
         return (y * scale).astype(self.dtype)
 

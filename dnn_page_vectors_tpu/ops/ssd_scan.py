@@ -5,8 +5,11 @@ Per head h, with state S in R^{P x N}, S_0 = 0, a_t = delta_t A_h <= 0:
 
     S_t = exp(a_t) S_{t-1} + delta_t X_t B_t^T;    Y_t = S_t C_t
 
-(`D X_t` is the caller's). B and C belong to one group that all heads share.
-In chunks of Q tokens that is, with cs the running sum of a inside a chunk:
+(`D X_t` is the caller's). B and C belong to a GROUP of heads: with G groups
+head h reads group h // (H / G), and the [Q, Q] score tile below is one a
+group. The body is one group's; more groups are that body mapped over the
+group axis (`jax.vmap`: a batch dimension of every product). In chunks of Q
+tokens that is, with cs the running sum of a inside a chunk:
 
   within a chunk   Y_i += sum_{j <= i} (C_i . B_j) exp(cs_i - cs_j) delta_j X_j
                    the masked-decay quadratic form: one [Q, Q] score tile a
@@ -39,13 +42,38 @@ def _within(cs: jnp.ndarray) -> jnp.ndarray:
     return jnp.exp(jnp.where(keep, diff, -jnp.inf))
 
 
+def _by_group(one_group, x, delta, a, b, c):
+    """`one_group(x, delta, a, b, c)` (b and c [B, L, N]) on b and c
+    [B, L, G, N]: the heads split into G runs of H / G, the group a batch
+    axis of every product. [B, L, N], or one group, is the body itself."""
+    if b.ndim == 3:
+        return one_group(x, delta, a, b, c)
+    B, L, H, P = x.shape
+    G = b.shape[2]
+    if H % G:
+        raise ValueError(f"{H} heads do not split into {G} groups")
+    if G == 1:
+        return one_group(x, delta, a, b[:, :, 0], c[:, :, 0])
+    y = jax.vmap(one_group, in_axes=(2, 2, 0, 2, 2), out_axes=2)(
+        x.reshape(B, L, G, H // G, P), delta.reshape(B, L, G, H // G),
+        a.reshape(G, H // G), b, c)
+    return y.reshape(B, L, H, P)
+
+
 def ssd_scan(x: jnp.ndarray, delta: jnp.ndarray, a: jnp.ndarray,
              b: jnp.ndarray, c: jnp.ndarray, chunk: int,
              carry_state: bool = True) -> jnp.ndarray:
     """x [B, L, H, P], delta [B, L, H] float32 (after the softplus),
-    a [H] float32 (negative), b and c [B, L, N] -> Y [B, L, H, P] float32.
-    `carry_state=False` drops the state at every chunk boundary: a planted
-    fault for the tests, never a mode of the program."""
+    a [H] float32 (negative), b and c [B, L, G, N] ([B, L, N]: one group)
+    -> Y [B, L, H, P] float32. `carry_state=False` drops the state at every
+    chunk boundary: a planted fault for the tests, never a mode of the
+    program."""
+    return _by_group(
+        lambda *one: _scan_group(*one, chunk, carry_state), x, delta, a, b, c)
+
+
+def _scan_group(x, delta, a, b, c, chunk: int, carry_state: bool):
+    """One group's heads: b and c [B, L, N]."""
     B, L, H, P = x.shape
     Q = min(chunk, L)
     pad = (-L) % Q
@@ -88,7 +116,11 @@ def ssd_scan(x: jnp.ndarray, delta: jnp.ndarray, a: jnp.ndarray,
 
 def ssd_recurrence(x, delta, a, b, c) -> jnp.ndarray:
     """The same Y token by token (`lax.scan` over L), float32 throughout:
-    what the chunked form is tested against."""
+    what the chunked form is tested against (b and c as `ssd_scan`'s)."""
+    return _by_group(_recurrence_group, x, delta, a, b, c)
+
+
+def _recurrence_group(x, delta, a, b, c):
     f32 = jnp.float32
     x, delta, b, c = (t.astype(f32) for t in (x, delta, b, c))
     B, L, H, P = x.shape

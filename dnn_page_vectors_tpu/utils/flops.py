@@ -15,6 +15,21 @@ from typing import Optional
 from dnn_page_vectors_tpu.config import Config, ModelConfig
 
 
+def _mixer_flops(m: ModelConfig, L: int) -> float:
+    """One Mamba-2 mixer over one sequence: its two projections and the
+    chunked recurrence (within-chunk products over the visible pairs once,
+    the score tile one a group; the chunks' states; the carried state's part
+    of the output)."""
+    d = m.model_dim
+    H, P, N, G = (m.mamba_n_heads, m.mamba_d_head, m.mamba_d_state,
+                  m.mamba_n_groups)
+    Q = min(m.mamba_chunk_size, L)
+    n = -(-L // Q)
+    scan = n * Q * (Q + 1) / 2 * (2 * N * G + 2 * H * P) \
+        + 2 * (n - 1) * 2 * Q * H * P * N
+    return L * (2 * d * (2 * H * P + 2 * G * N + H) + 2 * H * P * d) + scan
+
+
 def encoder_flops_per_example(m: ModelConfig, seq_len: int) -> float:
     """Forward-pass matmul FLOPs for ONE sequence through one tower."""
     if m.encoder in ("bert", "t5"):
@@ -46,18 +61,11 @@ def encoder_flops_per_example(m: ModelConfig, seq_len: int) -> float:
                           + (m.num_layers - dense) * moe)
                      + 2 * d * m.out_dim)
     if m.encoder == "granitemoehybrid":
-        # per layer the mixer (two projections and the chunked recurrence:
-        # within-chunk products over the visible pairs once, the chunks'
-        # states, the carried state's part of the output) or grouped-query
-        # attention (causal scores counted once), then the router, the
-        # shared expert and the EXPECTED share of assignments held
+        # per layer the mixer (`_mixer_flops`) or grouped-query attention
+        # (causal scores counted once), then the router, the shared expert
+        # and the EXPECTED share of assignments held
         d, L = m.model_dim, seq_len
-        H, P, N = m.mamba_n_heads, m.mamba_d_head, m.mamba_d_state
-        Q = min(m.mamba_chunk_size, L)
-        n = -(-L // Q)
-        scan = n * Q * (Q + 1) / 2 * (2 * N + 2 * H * P) \
-            + 2 * (n - 1) * 2 * Q * H * P * N
-        mamba = L * (2 * d * (2 * H * P + 2 * N + H) + 2 * H * P * d) + scan
+        mamba = _mixer_flops(m, L)
         dh = d // m.num_heads
         attn = L * (4 * d * d + 4 * d * m.num_key_value_heads * dh) \
             + 4 * dh * m.num_heads * L * (L + 1) / 2
@@ -68,6 +76,17 @@ def encoder_flops_per_example(m: ModelConfig, seq_len: int) -> float:
         n_mamba = sum(t == "mamba" for t in m.layer_types)
         return float(n_mamba * mamba + (m.num_layers - n_mamba) * attn
                      + m.num_layers * moe + 2 * d * m.out_dim)
+    if m.encoder == "falcon_h1":
+        # per layer the mixer (`_mixer_flops`), grouped-query attention
+        # beside it (causal scores counted once) and the dense SwiGLU
+        d, L = m.model_dim, seq_len
+        mamba = _mixer_flops(m, L)
+        dh = m.head_dim
+        attn = L * (4 * d * m.num_heads * dh
+                    + 4 * d * m.num_key_value_heads * dh) \
+            + 4 * dh * m.num_heads * L * (L + 1) / 2
+        return float(m.num_layers * (mamba + attn + L * 6 * d * m.mlp_dim)
+                     + 2 * d * m.out_dim)
     if m.encoder == "cdssm":
         E, C = m.embed_dim, m.conv_channels
         conv = sum(2 * w * E * C for w in m.conv_widths) * seq_len

@@ -182,3 +182,37 @@ def test_hybrid_block_compiles_for_v5e_at_the_published_widths(
     assert " conditional(" not in text
     assert text.count("tpu_custom_call") == (4 if kind == "attention" else 3)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
+
+
+# Falcon-H1-34B's widths (config.py:falcon_h1_34b_pp12): one query of 1,024
+# tokens a call, weights held in bfloat16
+def test_falcon_h1_block_compiles_for_v5e_at_the_published_widths(
+        one_chip, monkeypatch):
+    """One layer's forward as the serve path runs it: the chunked scan's
+    products with 2 groups and a 128 x 256 state a head (chunk 128), the
+    causal flash kernel under 20 query and 4 key/value heads of 128 after
+    rotary, and the 21,504-wide SwiGLU, within the chip's memory."""
+    import functools
+    from dnn_page_vectors_tpu.config import get_config
+    from dnn_page_vectors_tpu.infer.bulk_embed import hold_weights
+    from dnn_page_vectors_tpu.models import falcon_h1
+    from dnn_page_vectors_tpu.models.factory import build_two_tower
+    from dnn_page_vectors_tpu.ops import flash_attention as fa
+    monkeypatch.setattr(fa, "flash_attention", functools.partial(
+        fa.flash_attention, interpret=False))
+    sizes = build_two_tower(get_config("falcon_h1_34b_pp12"),
+                            261_120).query_tower.sizes
+    block = falcon_h1.FalconH1Block(sizes, dtype=jnp.bfloat16)
+    h = jax.ShapeDtypeStruct((1, 1024, 5120), jnp.bfloat16,
+                             sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((1, 1024), jnp.bool_, sharding=one_chip)
+    held = jax.eval_shape(
+        lambda t: hold_weights(t, "bfloat16"),
+        jax.eval_shape(block.init, jax.random.PRNGKey(0), h, mask))
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        held)
+    compiled = jax.jit(block.apply).lower(params, h, mask).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1           # flash_fwd
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
