@@ -11,11 +11,11 @@ BATCH-shaped (`query_batch` rows), so one-query-at-a-time serving wastes
 most of every dispatch on padding. Three mechanisms recover that width:
 
   * `search_many(queries, k)` — vectorized multi-query search: one
-    encode_batch over up to `query_batch` real queries, one fused per-shard
-    top-k + device merge, one packed transfer, results split per query
-    (each shard's scan hands back ONE packed int32 [B, 2k] array, scores'
-    bits then row ids — ops/topk.py:pack_topk — and the merge packs its
-    winners the same way); larger lists tile over full buckets (one
+    encode_batch over up to `query_batch` real queries, one scan per
+    resident shard that folds the shard into a running top-k, one packed
+    transfer, results split per query (the running top-k is ONE packed
+    int32 [B, 2k] array, scores' bits then row ids, ops/topk.py:pack_topk,
+    donated to each launch); larger lists tile over full buckets (one
     compiled shape throughout).
   * a dynamic micro-batcher (`serve.batch_window_ms` / `serve.max_batch`,
     start_batcher()): concurrent search() callers enqueue onto a bounded
@@ -128,7 +128,7 @@ from dnn_page_vectors_tpu.infer.bulk_embed import BulkEmbedder
 from dnn_page_vectors_tpu.infer.transport import DeadlineExceeded
 from dnn_page_vectors_tpu.infer.vector_store import VectorStore, read_ahead
 from dnn_page_vectors_tpu.ops.topk import (
-    merge_shard_topk, pack_topk, sharded_topk_fn, stage_shard,
+    empty_topk, merge_shard_topk, sharded_topk_fn, stage_shard,
     topk_over_store, unpack_topk)
 from dnn_page_vectors_tpu.utils import faults
 from dnn_page_vectors_tpu.utils.profiling import LatencyStats, PipelineProfiler
@@ -397,7 +397,7 @@ def _merge_topk_host(s1, i1, s2, i2, k: int):
     """Fold two [n, k] (scores fp32, page_ids int64) candidate sets into
     one top-k on host — the cross-stamp merge for the streaming dual-stamp
     path (docs/MAINTENANCE.md "Rolling model migration"); the resident
-    path merges all stamps on device through the view's packed program.
+    path folds all stamps on device through the one carried scan.
     Stable on ties (first set wins), -inf/-1 padding sorts last."""
     s = np.concatenate([s1, s2], axis=1)
     i = np.concatenate([i1, i2], axis=1)
@@ -409,20 +409,21 @@ def _merge_topk_host(s1, i1, s2, i2, k: int):
 class _Shard(NamedTuple):
     """One store shard resident on the device, with everything a launch of
     the scan over it takes (_stage_view makes it, _dispatch_bucket reads
-    it): a refresh hands a shard whose bytes are unchanged on whole, or
-    with newer tombstones masked in `ids` alone."""
+    it): a refresh hands a shard whose bytes are unchanged on whole (a new
+    `span` if its slot moved), or with newer tombstones masked in `ids`."""
     ids: np.ndarray        # [n] int64 page ids, -1 = tombstoned since staged
     n: int                 # rows of `pages` that hold a vector
     pages: object          # [pad_rows, D] device rows at the stored width
     scales: object         # [pad_rows] device fp16 scales (int8 store) | None
-    valid: object          # `n` as an int32 scalar replicated on the device
+    span: object           # int32 [2] replicated on the device: `n`, and the
+    #                        shard's first combined id, slot * pad_rows
 
 
 class _ServeView:
     """One atomic serving snapshot (docs/UPDATES.md): everything
     search_many touches that a refresh() can change — the store handle
     (with its frozen generation chain and tombstone map), the staged HBM
-    shards, the combined-id table, the device merge program, the
+    shards, the combined-id table, the
     degraded-tail entries, and the IVF index. The hot-swap is a single
     reference assignment: in-flight dispatches finish on the view they
     captured at entry, the next dispatch sees the new one — no lock on
@@ -430,7 +431,7 @@ class _ServeView:
 
     __slots__ = ("store", "entries", "generation", "shards", "shard_keys",
                  "shard_steps", "steps", "stream_entries", "pid_table",
-                 "merge", "pad_rows", "index", "index_error", "index_info",
+                 "pad_rows", "index", "index_error", "index_info",
                  "docs_appended", "tombstoned", "num_vectors", "maint_stats",
                  "restricted")
 
@@ -462,7 +463,6 @@ class _ServeView:
         self.shard_steps: List[Optional[int]] = []   # stamp per staged shard
         self.stream_entries: List[Dict] = []
         self.pid_table = None
-        self.merge = None
         self.pad_rows = 0
         self.index = None
         self.index_error: Optional[str] = None
@@ -531,6 +531,9 @@ class SearchService:
         # reduces them on the device; BulkEmbedder.encode_query_call)
         self._m_encode = {name: reg.counter("encode." + name)
                           for name in BulkEmbedder.ENCODE_COUNTERS}
+        # resident buckets answered by the carried scan alone | tail too
+        self._m_carried = reg.counter("topk.carried_buckets")
+        self._m_tail = reg.counter("topk.tail_buckets")
         self._m_ann_lists = reg.counter("serve.ann_lists_scanned")
         self._m_ann_reranked = reg.counter("serve.ann_candidates_reranked")
         self._m_ann_fallbacks = reg.counter("serve.ann_fallbacks")
@@ -1366,8 +1369,6 @@ class SearchService:
                     budget_bytes: float, per_row: int,
                     reuse: "_ServeView" = None) -> None:
         import jax
-        import jax.numpy as jnp
-        from jax import lax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         plan = faults.active()
@@ -1377,25 +1378,30 @@ class SearchService:
         # staged device array (keyed on gen/index/count/crc) and pays
         # device transfer for exactly the delta; ids reload host-side so
         # newer tombstones re-mask rows the device copy still carries
-        reuse_map = {}
+        reuse_map, span_of = {}, {}
         if (reuse is not None and reuse.shards
                 and reuse.pad_rows == rows):
             reuse_map = {key: tup for key, tup
                          in zip(reuse.shard_keys, reuse.shards)}
-        # the scan's `valid` argument, made HERE and kept with the shard: an
-        # int32 scalar replicated over the mesh, one per distinct row count
-        # (a reused shard brings its own along), so that a bucket's launch
-        # loop (_dispatch_bucket) converts and transfers nothing per shard
+            span_of = {(shard.n, slot): shard.span
+                       for slot, shard in enumerate(reuse.shards)}
+        # the scan's `span` argument, made HERE and kept with the shard:
+        # (rows that hold a vector, first combined id) as int32 [2]
+        # replicated over the mesh, so that a bucket's launch loop
+        # (_dispatch_bucket) converts and transfers nothing per shard, and
+        # traced, so that every slot runs the one program; a reused shard
+        # brings its own along while it keeps its slot
         replicated = NamedSharding(self.embedder.mesh, P())
-        valid_of = {shard.n: shard.valid for shard in reuse_map.values()}
-
-        def device_count(n: int):
-            v = valid_of.get(n)
-            if v is None:
-                v = valid_of[n] = jax.device_put(np.int32(n), replicated)
-            return v
-
         staged, keys, stamps = [], [], []
+
+        def device_span(n: int):
+            slot = len(staged)
+            span = span_of.get((n, slot))
+            if span is None:
+                span = jax.device_put(
+                    np.array([n, slot * rows], np.int32), replicated)
+            return span
+
         used = 0.0
         per_shard = rows * per_row / self._n_data
         for entry in view.entries:
@@ -1417,7 +1423,7 @@ class SearchService:
                     if np.array_equal(live, alive_old):
                         # staged block current (modulo rows already masked
                         # by an earlier skip): plain reuse
-                        staged.append(hit)
+                        staged.append(hit._replace(span=device_span(old_n)))
                         keys.append(key)
                         stamps.append(estep)
                         used += per_shard
@@ -1434,7 +1440,8 @@ class SearchService:
                     if dead_frac <= self._restage_density:
                         masked = np.where(np.isin(old_ids, live),
                                           old_ids, np.int64(-1))
-                        staged.append(hit._replace(ids=masked))
+                        staged.append(hit._replace(
+                            ids=masked, span=device_span(old_n)))
                         keys.append(key)
                         stamps.append(estep)
                         used += per_shard
@@ -1474,7 +1481,7 @@ class SearchService:
                 staged.append(_Shard(
                     ids, n, *stage_shard(vecs, rows, store.dim,
                                          self.embedder.mesh, scales=scl),
-                    device_count(n)))
+                    device_span(n)))
                 keys.append(key)
                 stamps.append(estep)
                 used += per_shard
@@ -1499,39 +1506,11 @@ class SearchService:
         view.shard_steps = stamps
         if not staged:
             return
-        # combined-id -> page-id table for the device-side merge below:
+        # combined-id -> page-id table for the carried scan's ids:
         # shard slot s, padded row r  ->  slot s * rows + r
         view.pid_table = np.full((len(staged) * rows,), -1, np.int64)
         for slot, shard in enumerate(staged):
             view.pid_table[slot * rows: slot * rows + shard.n] = shard.ids
-        if reuse is not None and reuse.merge is not None \
-                and reuse.pad_rows == rows:
-            # the merge program depends only on pad_rows (and retraces per
-            # candidate-list structure): reusing the jitted fn object keeps
-            # the XLA cache warm across refreshes
-            view.merge = reuse.merge
-            return
-
-        @jax.named_scope("merge")    # names its ops; the program stays
-        def merge(cands):            # `jit_merge`
-            # Device-side cross-shard merge of the scans' packed arrays,
-            # one [B, 2k] int32 a resident shard (ops/topk.py:pack_topk),
-            # packed the same way on the way out: every host<->device
-            # round trip adds to per-query serving latency, so the k
-            # winners across all resident shards come back in a single
-            # transfer.
-            parts = [unpack_topk(c) for c in cands]
-            cat_s = jnp.concatenate([s for s, _ in parts], axis=1)
-            cat_i = jnp.concatenate(
-                [jnp.where(i >= 0, i + slot * rows, -1)
-                 for slot, (_, i) in enumerate(parts)], axis=1)
-            k = parts[0][0].shape[1]
-            top_s, pos = lax.top_k(cat_s, k)          # cat width S*k >= k
-            top_i = jnp.take_along_axis(cat_i, pos, axis=1)
-            top_i = jnp.where(jnp.isfinite(top_s), top_i, -1)
-            return pack_topk(top_s, top_i)
-
-        view.merge = jax.jit(merge)
 
     # -- query-embedding cache --------------------------------------------
     @staticmethod
@@ -2227,9 +2206,9 @@ class SearchService:
         """Vectorized multi-query search: one result list per query, in
         order. Queries fill the compiled `query_batch` bucket (larger lists
         tile over full buckets — one compiled program regardless of count);
-        per-shard top-k and the cross-shard merge run once per bucket, and
-        on a degraded service the failed shards' disk sweep folds in once
-        per bucket too.
+        the per-shard top-k, which carries the cross-shard merge, runs once
+        per bucket, and on a degraded service the failed shards' disk sweep
+        folds in once per bucket too.
 
         Telemetry: the call runs under a request trace (a fresh root for
         direct callers, a child span inside a batcher dispatch) and — for
@@ -2441,8 +2420,8 @@ class SearchService:
                         out_s, out_i, np.asarray(s_g[:n], np.float32),
                         np.asarray(i_g[:n], np.int64), k)
             return out_s, out_i, scan
-        # Two passes over the buckets: dispatch them ALL first (the merge
-        # output stays on device — JAX's async queue runs bucket i+1's
+        # Two passes over the buckets: dispatch them ALL first (the last
+        # scan's output stays on device — JAX's async queue runs bucket i+1's
         # top-k while bucket i's packed transfer drains), THEN materialize
         # in order. A >bucket batch therefore pipelines compute against
         # transfer instead of serializing dispatch/drain per bucket.
@@ -2557,42 +2536,41 @@ class SearchService:
     # graftcheck: hot
     def _dispatch_bucket(self, view: "_ServeView", qblocks: Dict, k: int):
         """HBM-resident fast path for ONE compiled bucket (<= query_batch
-        real rows): every resident shard's top-k program dispatches under
-        JAX's async queue and the cross-shard merge runs ON DEVICE; the
-        packed [B, 2k] result is returned still on device — exactly ONE
+        real rows): the bucket's running top-k, one packed [B, 2k] array
+        (ops/topk.py:pack_topk, ids in the view's combined numbering), is
+        threaded through one launch of the scan per resident shard, in
+        slot order, under JAX's async queue. The last launch's output IS
+        the bucket's result and is returned still on device — exactly ONE
         drain round trip per BUCKET happens later in _collect_bucket,
         regardless of shard count or how many queries share the dispatch.
-        (The old per-shard host merge cost ~2 transfers per shard, each a
-        forced pipeline bubble.)
 
         `qblocks` maps model stamp -> [<=B, D] query block (_qv_blocks):
         each shard is scored by the block matching its recorded stamp, so
-        a mid-migration bucket runs the same one merged dispatch — the
-        dual-stamp routing costs one extra h2d put per extra stamp, not a
-        second sweep.
+        a mid-migration bucket runs the same one chain — the dual-stamp
+        routing costs one extra h2d put per extra stamp, not a second
+        sweep.
 
         The launch loop holds nothing but the launches: the jitted scan is
         resolved once per bucket, and each shard's arguments were made
-        when the view was staged (_stage_view) — `pages` and `scales`
-        padded to `pad_rows`, which _build_view keeps a multiple of the
-        mesh's 'data' axis, and `valid`, the shard's row count as an
-        int32 scalar already on the device. Once the query blocks are up,
-        a bucket moves nothing from host to device and runs one program
-        per shard plus the merge. Each launch hands back ONE array (the
-        packed [B, 2k] of pack_topk), so the runtime makes one output
-        buffer a launch and the merge binds one argument a shard."""
-        import jax.numpy as jnp
+        when the view was staged (_stage_view). The query blocks and the
+        empty carry go up by one explicit put; after that a bucket moves
+        nothing from host to device and runs one program per shard and no
+        other. The scan DONATES the carry: each launch writes its one
+        output into the buffer the last one left, the runtime allocates
+        nothing a launch, and only the newest carry may be touched."""
+        import jax
 
         nreal = next(iter(qblocks.values())).shape[0]
         B = self.query_batch
-        qs: Dict = {}
-        for st, blk in qblocks.items():
-            if blk.shape[0] < B:
-                blk = np.concatenate(
-                    [blk, np.zeros((B - blk.shape[0], blk.shape[1]),
-                                   np.float32)])
-            qs[st] = jnp.asarray(blk, jnp.float32)
-        fallback = next(iter(qs.values()))
+        # ONE explicit put: the blocks (float32 from _topk_view) zero-padded
+        # to the bucket and the empty carry beside them; a put of its own
+        # for the carry cost 0.15-0.2 ms a bucket
+        *blocks, packed = jax.device_put(
+            [np.pad(blk, ((0, B - blk.shape[0]), (0, 0)))
+             for blk in qblocks.values()] + [empty_topk(B, k)],
+            view.shards[0].span.sharding)
+        qs: Dict = dict(zip(qblocks, blocks))
+        fallback = blocks[0]
         self._note_dispatch_shape("sharded_topk", batch=B, k=k,
                                   rows=view.pad_rows,
                                   shards=len(view.shards))
@@ -2602,14 +2580,14 @@ class SearchService:
             scaled = view.shards[0].scales is not None
             scan = sharded_topk_fn(self.embedder.mesh, k, scaled=scaled)
             if scaled:
-                cands = [scan(qs.get(st, fallback), pages, scl, valid)
-                         for st, (_, _, pages, scl, valid)
-                         in zip(view.shard_steps, view.shards)]
+                for st, (_, _, pages, scl, span) in zip(view.shard_steps,
+                                                        view.shards):
+                    packed = scan(qs.get(st, fallback), pages, scl, span,
+                                  packed)
             else:
-                cands = [scan(qs.get(st, fallback), pages, valid)
-                         for st, (_, _, pages, _, valid)
-                         in zip(view.shard_steps, view.shards)]
-            packed = view.merge(cands)                 # async, on device
+                for st, (_, _, pages, _, span) in zip(view.shard_steps,
+                                                      view.shards):
+                    packed = scan(qs.get(st, fallback), pages, span, packed)
         return nreal, qs, packed
 
     # graftcheck: hot
@@ -2618,11 +2596,13 @@ class SearchService:
         """Drain one dispatched bucket to host (scores [nreal, k] fp32,
         page_ids [nreal, k] int64) — formatting happens once per call in
         _search_view, so the partitioned scatter-gather can fold raw
-        per-partition candidates before any snippet work."""
+        per-partition candidates before any snippet work. Stage `merge` is
+        the pull alone: the cross-shard merge happened in the launches."""
         with self._stage("merge"):
             # graftcheck: off=host-sync -- THE one packed d2h per
-            # bucket: the whole point of the merged [B, 2k] layout
+            # bucket: the whole point of the carried [B, 2k] layout
             packed = np.asarray(packed)
+        (self._m_tail if view.stream_entries else self._m_carried).inc()
         top_s, top_i = unpack_topk(packed)
         pids = np.where(top_i >= 0,
                         view.pid_table[np.clip(top_i, 0, None)], -1)

@@ -125,20 +125,35 @@ def unpack_topk(packed) -> Tuple:
             packed[:, k:])
 
 
+def empty_topk(batch: int, k: int) -> np.ndarray:
+    """A running top-k that holds nothing yet, on the host in `pack_topk`'s
+    layout (scores -inf, ids -1): what a chain of carried scans starts
+    from, put on the device explicitly and not by a jitted program."""
+    return np.concatenate(
+        [np.full((batch, k), -np.inf, np.float32).view(np.int32),
+         np.full((batch, k), -1, np.int32)], axis=1)
+
+
 _SHARDED_CACHE: Dict[Tuple, Tuple] = {}
 
 
 def _build_sharded_topk(mesh: Mesh, k: int, chunk: int, scaled: bool):
-    """Jitted (q, pages[, scales], valid) -> packed [Bq, 2k] int32
-    (`pack_topk` of scores and global row idx) with pages (and int8
-    scales) row-sharded over 'data'. Cached per (mesh, k, chunk, scaled);
-    jit retraces per pages dtype within a key."""
+    """Jitted (q, pages[, scales], span, carry) -> packed [Bq, 2k] int32
+    with pages (and int8 scales) row-sharded over 'data'. `span` is int32
+    [2], replicated: the count of valid rows and the offset this launch's
+    row ids get. `carry` is a running top-k in `pack_topk`'s layout
+    (`empty_topk` to start one), replicated and DONATED: the one output,
+    the carry with this launch's rows folded in, takes its buffer, so a
+    launch allocates nothing and the caller's carry is gone. Carried
+    entries come first in the fold and `lax.top_k` keeps the lower
+    position among equal scores: the earlier launch wins a tie. Cached per
+    (mesh, k, chunk, scaled); jit retraces per pages dtype within a key."""
     n_data = mesh.shape["data"]
 
-    def run(q, pages_local, scales_local, valid):
+    def run(q, pages_local, scales_local, span, carry):
         rows = pages_local.shape[0]                  # per-shard row count
         shard = lax.axis_index("data")
-        valid_local = jnp.clip(valid - shard * rows, 0, rows).astype(jnp.int32)
+        valid_local = jnp.clip(span[0] - shard * rows, 0, rows)
         c = min(chunk, rows)
         pad = (-rows) % c
         if pad:
@@ -148,8 +163,10 @@ def _build_sharded_topk(mesh: Mesh, k: int, chunk: int, scaled: bool):
             if scales_local is not None:
                 scales_local = jnp.concatenate(
                     [scales_local, jnp.zeros((pad,), scales_local.dtype)])
-        # carry starts as a constant; pcast marks it varying over 'data' so
-        # the scan's in/out types agree under shard_map
+        # the local scan starts from a constant, NOT from `carry`: every
+        # device would bring the same earlier winners to the gather below,
+        # n_data copies of each. pcast marks it varying over 'data' so the
+        # scan's in/out types agree under shard_map
         init = jax.tree_util.tree_map(
             lambda x: lax.pcast(x, ("data",), to="varying"),
             (jnp.full((q.shape[0], k), -jnp.inf, jnp.float32),
@@ -160,16 +177,16 @@ def _build_sharded_topk(mesh: Mesh, k: int, chunk: int, scaled: bool):
             s, i = _topk_scan(q, pages_local, k, c, valid_local,
                               scales=scales_local, init=init)
         with jax.named_scope("sharded_topk.local_topk"):
-            gi = jnp.where(i >= 0, i + shard * rows, -1)
+            gi = jnp.where(i >= 0, i + (shard * rows + span[1]), -1)
             # gather every shard's k candidates over ICI and merge
-            # everywhere
-            all_s = lax.all_gather(s, "data")        # [n_data, Bq, k]
-            all_i = lax.all_gather(gi, "data")
-            Bq = q.shape[0]
-            cat_s = jnp.transpose(all_s, (1, 0, 2)).reshape(Bq, n_data * k)
-            cat_i = jnp.transpose(all_i, (1, 0, 2)).reshape(Bq, n_data * k)
-            kk = min(k, n_data * k)
-            top_s, pos = lax.top_k(cat_s, kk)
+            # everywhere, the carry's k ahead of them: folded ONCE
+            flat = lambda x: jnp.transpose(                  # noqa: E731
+                lax.all_gather(x, "data"),                   # [n_data, Bq, k]
+                (1, 0, 2)).reshape(q.shape[0], n_data * k)
+            carry_s, carry_i = unpack_topk(carry)
+            cat_s = jnp.concatenate([carry_s, flat(s)], axis=1)
+            cat_i = jnp.concatenate([carry_i, flat(gi)], axis=1)
+            top_s, pos = lax.top_k(cat_s, k)
             top_i = jnp.take_along_axis(cat_i, pos, axis=1)
             top_i = jnp.where(jnp.isfinite(top_s), top_i, -1)
             return pack_topk(top_s, top_i)
@@ -180,24 +197,26 @@ def _build_sharded_topk(mesh: Mesh, k: int, chunk: int, scaled: bool):
     # documented escape hatch for exactly this collective-then-merge shape.
     if scaled:
         fn = run
-        in_specs = (P(), P("data"), P("data"), P())
+        in_specs = (P(), P("data"), P("data"), P(), P())
     else:
-        fn = lambda q, pages, valid: run(q, pages, None, valid)  # noqa: E731
-        in_specs = (P(), P("data"), P())
+        fn = lambda q, pages, span, carry: run(      # noqa: E731
+            q, pages, None, span, carry)
+        in_specs = (P(), P("data"), P(), P())
     mapped = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                            out_specs=P(), check_vma=False)
-    return jax.jit(mapped)
+    return jax.jit(mapped, donate_argnums=len(in_specs) - 1)
 
 
 def sharded_topk_fn(mesh: Mesh, k: int, chunk: int = 8192,
                     scaled: bool = False):
     """The jitted scan `sharded_topk` launches, for a caller that resolves
-    it once and then launches it per shard on arguments it already holds
-    on the device (`SearchService._dispatch_bucket`): (q, pages, valid),
-    or (q, pages, scales, valid) when `scaled`, giving ONE packed int32
-    [Bq, 2k] array a launch (`pack_topk`), left on the device. The caller
-    owns what the wrapper checks: pages rows divide mesh 'data', `valid`
-    is an int32 scalar."""
+    it once and then threads a running top-k through one launch per shard
+    on arguments it already holds on the device
+    (`SearchService._dispatch_bucket`): (q, pages, span, carry), or
+    (q, pages, scales, span, carry) when `scaled`, giving ONE packed int32
+    [Bq, 2k] array a launch, left on the device in the donated carry's
+    buffer (`_build_sharded_topk`). The caller owns what the wrapper
+    checks: pages rows divide mesh 'data', and a carry is passed once."""
     key = (mesh, int(k), int(chunk), bool(scaled))
     fn = _SHARDED_CACHE.get(key)
     if fn is None:
@@ -206,7 +225,7 @@ def sharded_topk_fn(mesh: Mesh, k: int, chunk: int = 8192,
 
 
 def sharded_topk(q: jnp.ndarray, pages, mesh: Mesh, k: int = 10,
-                 chunk: int = 8192, valid: int | jax.Array | None = None,
+                 chunk: int = 8192, valid: int | None = None,
                  scales=None) -> Tuple[np.ndarray, np.ndarray]:
     """Top-k with pages [N, D] row-sharded over the mesh 'data' axis.
 
@@ -217,19 +236,18 @@ def sharded_topk(q: jnp.ndarray, pages, mesh: Mesh, k: int = 10,
     fp16 rows or int8 codes with per-row `scales` [N] — widened on-device
     (_topk_scan).
 
-    `valid` is a Python int, made into a device scalar HERE (one small
-    program per call: fine for a sweep that also stages a shard per call),
-    or an int32 scalar already on the device, passed through as it is (the
-    serving view makes one per distinct count when it is staged,
-    `SearchService._stage_view`)."""
+    One launch of the carried scan from an empty carry at offset 0, both
+    put up from host constants: the call runs no program but the scan."""
     fn = sharded_topk_fn(mesh, k, chunk, scales is not None)
     N = pages.shape[0]
     if N % mesh.shape["data"]:
         raise ValueError(f"pages rows {N} must divide mesh data axis "
                          f"{mesh.shape['data']}; pad the input")
-    v = valid if isinstance(valid, jax.Array) else jnp.int32(
-        N if valid is None else valid)
-    packed = fn(q, pages, v) if scales is None else fn(q, pages, scales, v)
+    span, carry = jax.device_put(
+        (np.array([N if valid is None else valid, 0], np.int32),
+         empty_topk(q.shape[0], k)), NamedSharding(mesh, P()))
+    packed = (fn(q, pages, span, carry) if scales is None
+              else fn(q, pages, scales, span, carry))
     return unpack_topk(np.asarray(packed))
 
 
@@ -320,8 +338,8 @@ def merge_partition_topk(parts) -> Tuple[np.ndarray, np.ndarray]:
 
     `parts` is a sequence of (scores [Nq, k], page_ids [Nq, k]) — one
     entry per partition, ids global (-1 = empty slot). Each partition
-    already merged its own shards on device (`sharded_topk` + the
-    per-view merge program); this fold generalizes `merge_shard_topk`'s
+    already merged its own shards on device (the carried scan,
+    `sharded_topk_fn`); this fold generalizes `merge_shard_topk`'s
     running merge to partition granularity: pairs merge through
     `merge_topk_host`, log2(P) levels deep, so the host-side merge cost
     per level stays O(Nq * k) regardless of partition count. With

@@ -41,7 +41,7 @@ class PipelineProfiler:
       encode        compiled query-tower dispatch (+ host materialize)
       topk          per-shard sharded_topk dispatches (or the streaming
                     sweep on a non-resident store)
-      merge         device cross-shard merge + the one packed transfer
+      merge         the one packed transfer of the carried top-k
       format        page-id mapping + snippet assembly
 
     Seconds are CUMULATIVE ACROSS THREADS — a pool of N tokenizer workers

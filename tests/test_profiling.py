@@ -209,7 +209,9 @@ def test_named_scopes_reach_the_lowered_step_and_scan(tmp_path):
                 ("data", "model", "seq"))
     scan = topk._build_sharded_topk(mesh, 5, 64, False)
     text = _lowered_text(scan, jnp.zeros((4, 16)),
-                         jnp.zeros((256, 16), jnp.float16), jnp.int32(256))
+                         jnp.zeros((256, 16), jnp.float16),
+                         jnp.array([256, 0], jnp.int32),
+                         jnp.zeros((4, 10), jnp.int32))
     assert "jit__lambda" in text     # trace_modules.scan finds it by this
     for scope in ("sharded_topk.scan/", "sharded_topk.local_topk/"):
         assert scope in text, scope

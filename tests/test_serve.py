@@ -221,11 +221,12 @@ def test_bucket_is_one_launch_per_shard_and_moves_nothing(
         tmp_path, launches, case):
     """Everything a shard's launch needs was made when the view was staged:
     a warmed bucket runs with host-to-device transfers disallowed (only the
-    query block's explicit put goes up) and launches one scan per shard and
-    one merge, no other program; each scan hands the merge ONE packed array,
-    so the merge binds as many arguments as there are resident shards; the
-    answers are the streaming sweep's, and bit for bit those of a bucket
-    that kept every shard's scores and row ids apart."""
+    explicit puts of the query block and of the empty carry go up) and
+    launches one scan per shard and no other program: the running top-k is
+    threaded through the launches, each of which is handed the last one's
+    output, and the last output is the bucket's; the answers are the
+    streaming sweep's, and bit for bit those of a bucket that kept every
+    shard's scores and row ids apart and merged them in one pass."""
     import jax
     from jax.sharding import Mesh
 
@@ -235,33 +236,27 @@ def test_bucket_is_one_launch_per_shard_and_moves_nothing(
     svc = _resident_view(str(tmp_path / "store"), mesh, case)
     view, k = svc._view, 7
     assert [shard.n for shard in view.shards] == list(_ROWS)
-    assert [int(shard.valid) for shard in view.shards] == list(_ROWS)
-    assert view.shards[0].valid is view.shards[1].valid   # one per count
+    assert [np.asarray(shard.span).tolist() for shard in view.shards] == [
+        [n, slot * view.pad_rows] for slot, n in enumerate(_ROWS)]
     stamps = sorted(set(view.shard_steps))
     assert stamps == ([1, 2] if case == "two_stamp" else [1])
     qv = np.concatenate([_unit_rows(100 + s, 5) for s in stamps], axis=1)
     blocks = svc._qv_blocks(view, qv)
     assert sorted(blocks) == stamps
     svc._collect_bucket(view, *svc._dispatch_bucket(view, blocks, k), k)
-    merge, merged = view.merge, []
-    view.merge = lambda cands: merged.append(cands) or merge(cands)
     with launches() as seen, jax.transfer_guard_host_to_device("disallow"):
         bucket = svc._dispatch_bucket(view, blocks, k)
+    packed = bucket[2]
+    assert isinstance(packed, jax.Array) and packed.dtype == np.int32
+    assert packed.shape == (svc.query_batch, 2 * k)
     got_s, got_i = svc._collect_bucket(view, *bucket, k)
-    assert seen["programs"] == len(view.shards) + 1
-    assert seen["jitted"] == {"run" if case == "int8" else "<lambda>",
-                              "merge"}
-    # the merge binds ONE array a resident shard: the scan's packed result
-    (cands,) = merged
-    leaves = jax.tree_util.tree_leaves(cands)
-    assert len(leaves) == len(view.shards)
-    assert all(isinstance(c, jax.Array) and c.dtype == np.int32
-               and c.shape == (svc.query_batch, 2 * k) for c in leaves)
+    assert seen["programs"] == len(view.shards)
+    assert seen["jitted"] == {"run" if case == "int8" else "<lambda>"}
     # bit for bit the bucket of two arrays a shard: each shard's scores
     # and row ids apart, side by side in shard order, the k best by a
     # stable sort (lax.top_k's order on ties), through the id table
     qs = bucket[1]
-    parts = [sharded_topk(qs[st], shard.pages, mesh, k=k, valid=shard.valid,
+    parts = [sharded_topk(qs[st], shard.pages, mesh, k=k, valid=shard.n,
                           scales=shard.scales)
              for st, shard in zip(view.shard_steps, view.shards)]
     cat_s = np.concatenate([s for s, _ in parts], axis=1)[:5]

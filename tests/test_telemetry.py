@@ -578,16 +578,77 @@ def test_stage_sums_accumulate_with_every_tracer_off(served):
         assert sec.get(name, 0.0) > 0.0, (name, sec)
 
 
-def test_device_merge_carries_its_scope_and_keeps_its_name(served):
-    """The cross-shard merge's ops are named `merge/...` in the lowered
-    program, whose own name stays `jit_merge` (trace_modules.merge)."""
-    import jax.numpy as jnp
+def test_fold_of_the_carry_lies_under_the_scans_own_scope(served):
+    """The cross-shard merge is the scan's own last step since the running
+    top-k rides through the launches: in the program the service launches,
+    lowered on the shapes it launches it with, the carry's unpacking, the
+    `top_k` over [carry | this shard's candidates] and the pack of the
+    winners are all named `sharded_topk.local_topk/...`, and the program
+    is still `jit__lambda`."""
+    import jax
+    import numpy as np
+
+    from dnn_page_vectors_tpu.ops.topk import sharded_topk_fn
     svc = _svc(served, preload=4.0)
     try:
-        view = svc._view
-        cands = [jnp.zeros((4, 10), jnp.int32) for _ in view.shards]
-        text = view.merge.lower(cands).as_text(debug_info=True)
+        view, B, k = svc._view, svc.query_batch, 10
+        shard = view.shards[0]
+        text = sharded_topk_fn(svc.embedder.mesh, k).lower(
+            jax.ShapeDtypeStruct((B, view.store.dim), np.float32),
+            shard.pages, shard.span,
+            jax.ShapeDtypeStruct((B, 2 * k), np.int32)
+        ).as_text(debug_info=True)
     finally:
         svc.close()
-    assert len(view.shards) == 3
-    assert "jit_merge" in text and "jit(merge)/merge/" in text
+    assert "@jit__lambda" in text
+    locs = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+
+    def scope_of(pattern):
+        (line,) = [ln for ln in text.splitlines() if re.search(pattern, ln)]
+        return locs[re.search(r"loc\((#loc\d+)\)\s*$", line).group(1)]
+
+    n_data = svc.embedder.mesh.shape["data"]
+    wide = f"{B}x{k + n_data * k}"
+    fold = "sharded_topk.local_topk/"
+    assert scope_of(rf"chlo\.top_k.*tensor<{wide}xf32>").endswith(
+        fold + "top_k")
+    for pattern in (                         # unpack the carry, pack the out
+            rf"bitcast_convert .*tensor<{B}x{k}xi32>\) -> tensor<{B}x{k}xf32>",
+            rf"bitcast_convert .*tensor<{B}x{k}xf32>\) -> tensor<{B}x{k}xi32>"):
+        assert scope_of(pattern).endswith(fold + "bitcast_convert_type")
+
+
+def test_bucket_counters_read_buckets_and_zero(served):
+    """`topk.carried_buckets` counts every resident bucket whose answer is
+    the carried scan's last output, `topk.tail_buckets` those that went on
+    through the degraded tail's host fold: after a served batch on a
+    healthy view the first reads the buckets (one `merge` stage each) and
+    the second 0; with a shard that failed to stage it is the other way
+    round, and the answers are the same."""
+    _, trainer, _, _ = served
+
+    def read(svc):
+        return [svc.registry.counter("topk." + name).value
+                for name in ("carried_buckets", "tail_buckets")]
+
+    svc = _svc(served, preload=4.0)
+    try:
+        assert read(svc) == [0, 0]
+        res = svc.search_many(
+            [trainer.corpus.query_text(i)
+             for i in range(svc.query_batch + 3)], k=5)
+        buckets = svc.profiler.counts()["merge"]
+        assert all(res) and buckets == 2
+        assert read(svc) == [buckets, 0]
+    finally:
+        svc.close()
+    faults.install(faults.FaultPlan.parse("hbm_stage:io_error:1", seed=0))
+    svc = _svc(served, preload=4.0)
+    faults.reset()
+    try:
+        assert svc.degraded and len(svc._view.stream_entries) == 1
+        got = svc.search_many([trainer.corpus.query_text(7)], k=5)
+        assert read(svc) == [0, 1]
+    finally:
+        svc.close()
+    assert got == res[7:8]

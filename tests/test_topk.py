@@ -53,33 +53,30 @@ def test_sharded_topk_matches_single_device(eight_devices):
 
 
 @pytest.mark.parametrize("n_data", [1, 4])
-def test_sharded_topk_takes_valid_as_a_device_scalar(eight_devices, launches,
-                                                     n_data):
-    """`valid` as a Python int and as an int32 scalar already on the
-    device (replicated over the mesh, as the serving view stages it) give
-    the same bits; the device scalar goes to the scan as it is, so the call
-    moves nothing up and launches the scan alone, where the int costs a
-    conversion program first."""
+def test_sharded_topk_launches_the_scan_alone(eight_devices, launches,
+                                              n_data):
+    """`sharded_topk` is one launch of the carried scan: the row count with
+    an offset of 0 and a carry that holds nothing go up from host
+    constants by explicit puts, so on arguments already on the device the
+    call runs under a guard that refuses every implicit host-to-device
+    transfer and launches no program but the scan (the count cost a
+    conversion program when the scan took it as a scalar)."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
     mesh = make_mesh(MeshConfig(data=n_data))
     rng = np.random.default_rng(3)
+    rows = rng.normal(size=(256, 16)).astype(np.float16)
     q = jax.device_put(rng.normal(size=(6, 16)).astype(np.float32),
                        NamedSharding(mesh, P()))
-    pages = jax.device_put(rng.normal(size=(256, 16)).astype(np.float16),
-                           NamedSharding(mesh, P("data")))
-    on_device = jax.device_put(np.int32(200), NamedSharding(mesh, P()))
-    want = sharded_topk(q, pages, mesh, k=9, chunk=32, valid=200)
-    sharded_topk(q, pages, mesh, k=9, chunk=32, valid=on_device)   # warm
+    pages = jax.device_put(rows, NamedSharding(mesh, P("data")))
+    sharded_topk(q, pages, mesh, k=9, chunk=32, valid=200)         # warm
     with launches() as seen, jax.transfer_guard_host_to_device("disallow"):
-        got = sharded_topk(q, pages, mesh, k=9, chunk=32, valid=on_device)
-    for g, w in zip(got, want):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
-    assert (np.asarray(got[1]) < 200).all()
+        got = sharded_topk(q, pages, mesh, k=9, chunk=32, valid=200)
     assert seen["jitted"] == {"<lambda>"} and seen["programs"] == 1
-    with launches() as seen:
-        sharded_topk(q, pages, mesh, k=9, chunk=32, valid=200)
-    assert seen["jitted"] == {"<lambda>", "convert_element_type"}
+    assert seen["pulls"] == 1
+    want = chunked_topk(q, jnp.asarray(rows[:200]), k=9, chunk=32)
+    np.testing.assert_allclose(got[0], np.asarray(want[0]), rtol=1e-5)
+    assert (got[1] < 200).all() and (got[1] >= 0).all()
 
 
 def _exact_rows(seed, n, dim, scaled):
@@ -104,12 +101,14 @@ def _exact_rows(seed, n, dim, scaled):
 def test_scan_hands_back_one_packed_array(eight_devices, scaled, valid):
     """What a launch of the jitted scan returns: ONE int32 [Bq, 2k] array,
     the scores' bits then the row ids, for the unscaled and the scaled
-    program alike; unpacked it is `chunked_topk` over the valid rows bit
-    for bit, padding slots -inf / -1."""
+    program alike; on a carry that holds nothing it unpacks to
+    `chunked_topk` over the valid rows bit for bit, padding slots
+    -inf / -1."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from dnn_page_vectors_tpu.ops.topk import sharded_topk_fn, unpack_topk
+    from dnn_page_vectors_tpu.ops.topk import (
+        empty_topk, sharded_topk_fn, unpack_topk)
     mesh = make_mesh(MeshConfig(data=2))
     k = 9
     q, rows, scales, wide = _exact_rows(11, 64, 16, scaled)
@@ -117,7 +116,8 @@ def test_scan_hands_back_one_packed_array(eight_devices, scaled, valid):
     args = [put(q, P()), put(rows, P("data"))]
     if scaled:
         args.append(put(scales, P("data")))
-    args.append(put(np.int32(valid), P()))
+    args += [put(np.array([valid, 0], np.int32), P()),
+             put(empty_topk(6, k), P())]
     packed = sharded_topk_fn(mesh, k, chunk=16, scaled=scaled)(*args)
     assert isinstance(packed, jax.Array)
     assert packed.dtype == jnp.int32 and packed.shape == (6, 2 * k)
@@ -131,7 +131,7 @@ def test_scan_hands_back_one_packed_array(eight_devices, scaled, valid):
     if valid < k:
         assert np.isneginf(got_s[:, valid:]).all()
         assert (got_i[:, valid:] == -1).all()
-    # the same split inside a jitted caller (the serving merge's use)
+    # the same split inside a jitted caller (the scan's, of its carry)
     dev_s, dev_i = jax.jit(unpack_topk)(packed)
     np.testing.assert_array_equal(np.asarray(dev_s).view(np.int32),
                                   got_s.view(np.int32))
@@ -176,6 +176,20 @@ def test_merge_shard_topk_pulls_one_array(eight_devices, launches, scaled):
     np.testing.assert_array_equal(got_i, want_i)
 
 
+def _scan_shapes(mesh, scaled, batch=8, rows=64, dim=16, k=10):
+    """The carried scan's arguments as shapes on `mesh`, for lowering."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    shape = lambda dims, dtype, spec: jax.ShapeDtypeStruct(   # noqa: E731
+        dims, dtype, sharding=NamedSharding(mesh, spec))
+    return ([shape((batch, dim), jnp.float32, P()),
+             shape((rows, dim), jnp.int8 if scaled else jnp.float16,
+                   P("data"))]
+            + ([shape((rows,), jnp.float16, P("data"))] if scaled else [])
+            + [shape((2,), jnp.int32, P()),
+               shape((batch, 2 * k), jnp.int32, P())])
+
+
 def test_scan_keeps_the_name_the_roofline_finds_it_by(eight_devices):
     """`sharded_topk_roofline` finds the scan in a trace by its compiled
     module's name (`benchmarks/workloads/bert_mini.serve_exact.json`,
@@ -183,8 +197,6 @@ def test_scan_keeps_the_name_the_roofline_finds_it_by(eight_devices):
     compiles under exactly that name, as the reducer strips it."""
     import json
     import os
-
-    import jax
 
     from benchmarks import trace_reduce
     from dnn_page_vectors_tpu.ops.topk import sharded_topk_fn
@@ -194,12 +206,165 @@ def test_scan_keeps_the_name_the_roofline_finds_it_by(eight_devices):
         want = json.load(f)["trace_modules"]["scan"]
     mesh = make_mesh(MeshConfig(data=1))
     compiled = sharded_topk_fn(mesh, 10).lower(
-        jax.ShapeDtypeStruct((8, 16), jnp.float32),
-        jax.ShapeDtypeStruct((64, 16), jnp.float16),
-        jax.ShapeDtypeStruct((), jnp.int32)).compile()
+        *_scan_shapes(mesh, False)).compile()
     name = compiled.runtime_executable().hlo_modules()[0].name
     assert trace_reduce.module_name(f"{name}(1234567890)") == want
     assert trace_reduce.module_name(name) == want
+
+
+@pytest.mark.parametrize("scaled", [False, True],
+                         ids=["float16", "int8_scales"])
+def test_scan_donates_its_carry_to_its_one_output(eight_devices, scaled):
+    """The carry is the scan's LAST argument and is donated: the compiled
+    program aliases it, and no other argument, to the one output, so a
+    launch makes no output buffer; the compiled module keeps its name
+    (`jit__lambda` unscaled, `jit_run` with scales); and a carry that was
+    passed is deleted, so nothing can read it again by mistake."""
+    import re
+
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from dnn_page_vectors_tpu.ops.topk import empty_topk, sharded_topk_fn
+    mesh = make_mesh(MeshConfig(data=2))
+    scan = sharded_topk_fn(mesh, 10, scaled=scaled)
+    shapes = _scan_shapes(mesh, scaled)
+    head = scan.lower(*shapes).compile().as_text().split("\n", 1)[0]
+    assert head.startswith(
+        "HloModule " + ("jit_run," if scaled else "jit__lambda,"))
+    # the output (the module's one result, `{}`) lives in the last
+    # parameter's buffer, and nothing else is aliased
+    assert re.findall(r"input_output_alias=\{(.*?)\}, entry", head) == [
+        " {}: (%d, {}, may-alias) " % (len(shapes) - 1)]
+    q, rows, scales, _ = _exact_rows(13, 64, 16, scaled)
+    put = lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec))  # noqa: E731
+    args = [put(np.concatenate([q, q[:2]]), P()), put(rows, P("data"))]
+    if scaled:
+        args.append(put(scales, P("data")))
+    span, carry = put(np.array([64, 0], np.int32), P()), put(
+        empty_topk(8, 10), P())
+    out = scan(*args, span, carry)
+    assert carry.is_deleted() and not span.is_deleted()
+    assert out.shape == (8, 20) and not out.is_deleted()
+
+
+def _carried(mesh, k, q, shards, scaled, chunk=16):
+    """Thread one running top-k through the carried scan, one launch a
+    shard in slot order, as the serving loop does: `shards` are (rows,
+    scales, valid), every one padded to the same row count; the ids come
+    back in the combined numbering slot * rows + row."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from dnn_page_vectors_tpu.ops.topk import (
+        empty_topk, sharded_topk_fn, unpack_topk)
+    put = lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec))  # noqa: E731
+    scan = sharded_topk_fn(mesh, k, chunk=chunk, scaled=scaled)
+    qd = put(q, P())
+    packed = put(empty_topk(q.shape[0], k), P())
+    for slot, (rows, scales, valid) in enumerate(shards):
+        args = [qd, put(rows, P("data"))]
+        if scaled:
+            args.append(put(scales, P("data")))
+        span = put(np.array([valid, slot * rows.shape[0]], np.int32), P())
+        packed = scan(*args, span, packed)
+    return unpack_topk(np.asarray(packed))
+
+
+def _tied_shards(seed, scaled, rows=32, dim=16):
+    """Four shards of `rows` padded rows: 32, 20, 0 (all padding) and 32
+    valid, the last holding copies of rows of the first two, so that the
+    same score turns up in two shards. Returns the shards, the float32
+    rows of the valid ones end to end, and each of those rows' combined
+    id."""
+    valids = (rows, 20, 0, rows)
+    q, raw, scales, wide = _exact_rows(seed, 4 * rows, dim, scaled)
+    raw, wide = raw.copy(), wide.copy()
+    for dst, src in ((3 * rows + 1, 4), (3 * rows + 7, rows + 3),
+                     (3 * rows + 8, 9)):
+        raw[dst], wide[dst] = raw[src], wide[src]
+        if scaled:
+            scales[dst] = scales[src]
+    shards, cat, ids = [], [], []
+    for slot, valid in enumerate(valids):
+        sl = slice(slot * rows, (slot + 1) * rows)
+        shards.append((raw[sl], None if scales is None else scales[sl],
+                       valid))
+        cat.append(wide[sl][:valid])
+        ids.append(slot * rows + np.arange(valid))
+    return q, shards, np.concatenate(cat), np.concatenate(ids)
+
+
+@pytest.mark.parametrize("scaled", [False, True],
+                         ids=["float16", "int8_scales"])
+def test_carried_scan_is_chunked_topk_over_the_shards_end_to_end(
+        eight_devices, scaled):
+    """A running top-k threaded through one launch a shard IS
+    `chunked_topk` over the valid rows of all shards end to end, bit for
+    bit, ids in the combined numbering: a row that two shards hold comes
+    back under the lower slot's id first (the carried entry wins a tie),
+    and a shard of all padding leaves the carry as it was."""
+    mesh = make_mesh(MeshConfig(data=2))
+    k = 30                      # wide enough to hold both copies of a tie
+    q, shards, cat, ids = _tied_shards(21, scaled)
+    got_s, got_i = _carried(mesh, k, q, shards, scaled)
+    want_s, want_pos = chunked_topk(jnp.asarray(q), jnp.asarray(cat), k=k,
+                                    chunk=16)
+    np.testing.assert_array_equal(got_s.view(np.int32),
+                                  np.asarray(want_s).view(np.int32))
+    np.testing.assert_array_equal(got_i, ids[np.asarray(want_pos)])
+    # the planted copies: wherever both are in, the lower slot's is first
+    for low, high in ((4, 97), (35, 103), (9, 104)):
+        for row in got_i:
+            at = {int(i): n for n, i in enumerate(row)}
+            if low in at and high in at:
+                assert at[low] + 1 == at[high]
+    # a carry that meets a shard of all padding comes out as it went in
+    two = _carried(mesh, k, q, shards[:2], scaled)
+    three = _carried(mesh, k, q, shards[:3], scaled)
+    np.testing.assert_array_equal(two[0].view(np.int32),
+                                  three[0].view(np.int32))
+    np.testing.assert_array_equal(two[1], three[1])
+
+
+def test_carry_is_folded_once_on_a_wide_mesh(eight_devices):
+    """On a mesh whose 'data' axis is wider than 1 every device scans its
+    slice from an empty start and the carry joins ONCE, after the gather:
+    the answer holds no id twice (a carry that started every device's
+    scan would come back eight times over) and is still `chunked_topk`
+    over the rows end to end; with fewer valid rows than k the rest stays
+    -inf / -1."""
+    mesh = make_mesh(MeshConfig(data=8))
+    k = 12
+    q, shards, cat, ids = _tied_shards(22, False, rows=64)
+    got_s, got_i = _carried(mesh, k, q, shards, False)
+    for row in got_i:
+        assert len(set(row.tolist())) == k and (row >= 0).all()
+    want_s, want_pos = chunked_topk(jnp.asarray(q), jnp.asarray(cat), k=k,
+                                    chunk=16)
+    np.testing.assert_array_equal(got_s.view(np.int32),
+                                  np.asarray(want_s).view(np.int32))
+    np.testing.assert_array_equal(got_i, ids[np.asarray(want_pos)])
+    few = [(rows, scl, min(valid, 3)) for rows, scl, valid in shards]
+    few_s, few_i = _carried(mesh, k, q, few, False)
+    assert np.isfinite(few_s[:, :9]).all() and np.isneginf(few_s[:, 9:]).all()
+    assert (few_i[:, 9:] == -1).all()
+    assert sorted(few_i[0, :9].tolist()) == [0, 1, 2, 64, 65, 66,
+                                             192, 193, 194]
+
+
+def test_empty_topk_is_the_packed_layout_of_nothing():
+    """`empty_topk` is `pack_topk` of -inf scores and -1 ids, made on the
+    host with no program: what a chain of carried scans starts from."""
+    from dnn_page_vectors_tpu.ops.topk import (
+        empty_topk, pack_topk, unpack_topk)
+    host = empty_topk(3, 5)
+    assert host.dtype == np.int32 and host.shape == (3, 10)
+    s, i = unpack_topk(host)
+    assert np.isneginf(s).all() and (i == -1).all()
+    np.testing.assert_array_equal(host, np.asarray(pack_topk(
+        jnp.full((3, 5), -jnp.inf, jnp.float32),
+        jnp.full((3, 5), -1, jnp.int32))))
 
 
 def test_topk_over_store_matches_brute_force(eight_devices, tmp_path):

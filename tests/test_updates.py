@@ -412,14 +412,18 @@ def test_tombstone_aware_restage_policy(env, tmp_path):
     svc.close()
 
 
+def _spans(shards):
+    return [np.asarray(s.span).tolist() for s in shards]
+
+
 def test_reused_shards_keep_their_device_row_count(env, tmp_path):
-    """The scan's `valid` argument is staged with the shard
-    (SearchService._stage_view): a refresh that appends a generation, and
-    one that only adds tombstones under the restage threshold, hand every
-    reused shard on with the SAME device scalar (and device rows) it had,
-    the appended shard gets its own, and the masked shard keeps the count
-    of the rows its device copy still holds — the tombstoned page never
-    surfaces."""
+    """The scan's `span` argument (row count, first combined id) is staged
+    with the shard (SearchService._stage_view): a refresh that appends a
+    generation, and one that only adds tombstones under the restage
+    threshold, hand every reused shard on with the SAME device array (and
+    device rows) it had, the appended shard gets its own, and the masked
+    shard keeps the count of the rows its device copy still holds — the
+    tombstoned page never surfaces."""
     import dataclasses
     emb, trainer = env["emb"], env["trainer"]
     store = _copy_store(env, tmp_path)
@@ -427,18 +431,16 @@ def test_reused_shards_keep_their_device_row_count(env, tmp_path):
         env["cfg"].updates, restage_tombstone_density=0.05))
     svc = SearchService(cfg, emb, trainer.corpus, store, preload_hbm_gb=4.0)
     base = svc._view.shards
-    assert [int(s.valid) for s in base] == [100, 100, 100]
-    assert base[0].valid is base[1].valid is base[2].valid   # one per count
+    assert _spans(base) == [[100, 0], [100, 100], [100, 200]]
 
     append_corpus(emb, _grown(trainer.corpus, 350), store)
     svc.refresh()
     grown = svc._view.shards
     assert len(grown) == 4
     for old, new in zip(base, grown):
-        assert new.valid is old.valid and new.pages is old.pages
-    assert grown[3].n == 50 and int(grown[3].valid) == 50
-    assert grown[3].valid is not base[0].valid
-    assert grown[3].valid.dtype == np.int32
+        assert new.span is old.span and new.pages is old.pages
+    assert grown[3].n == 50 and _spans(grown)[3] == [50, 300]
+    assert grown[3].span.dtype == np.int32
 
     dead_vec = _stored_vecs(store, [7, 340])
     append_corpus(emb, _grown(trainer.corpus, 350), store, tombstone=[7])
@@ -446,12 +448,60 @@ def test_reused_shards_keep_their_device_row_count(env, tmp_path):
     assert svc.restage_skipped >= 1 and svc.restage_forced == 0
     masked = svc._view.shards
     for old, new in zip(grown, masked):
-        assert new.valid is old.valid and new.pages is old.pages
-    assert masked[0].n == 100 and int(masked[0].valid) == 100
+        assert new.span is old.span and new.pages is old.pages
+    assert masked[0].n == 100 and _spans(masked)[0] == [100, 0]
     assert 7 not in masked[0].ids and 7 in grown[0].ids
     _, got = svc.topk_vectors(dead_vec, k=10)
     assert 7 not in got[0].tolist(), "tombstoned row still servable"
     assert got[1][0] == 340             # the appended shard serves
+    svc.close()
+
+
+def test_reused_shard_in_a_new_slot_answers_with_that_slots_ids(
+        env, tmp_path):
+    """A shard's id offset belongs to its SLOT in the view, not to its
+    bytes: once a shard ahead of it has left the view, a shard reused
+    across an append and across a masked-tombstone refresh keeps its
+    device rows and its row count, takes the offset of the slot it now
+    stands in, and the carried scan's combined ids still resolve to its
+    own pages through the view's id table."""
+    import dataclasses
+    emb, trainer = env["emb"], env["trainer"]
+    store = _copy_store(env, tmp_path)
+    cfg = env["cfg"].replace(updates=dataclasses.replace(
+        env["cfg"].updates, restage_tombstone_density=0.05))
+    svc = SearchService(cfg, emb, trainer.corpus, store, preload_hbm_gb=4.0)
+    base = svc._view.shards
+    probe = _stored_vecs(store, [150, 250, 207])
+    # shard 0 leaves the view (corrupt bytes: quarantined at the next
+    # open) in the refresh that appends a generation
+    victim = os.path.join(store.directory, "shard_00000.vec.npy")
+    with open(victim, "r+b") as f:
+        f.truncate(os.path.getsize(victim) // 2)
+    append_corpus(emb, _grown(trainer.corpus, 350),
+                  VectorStore(store.directory))
+    svc.refresh()
+    moved = svc._view.shards
+    assert _spans(moved) == [[100, 0], [100, 100], [50, 200]]
+    for old, new in zip(base[1:], moved):
+        assert new.pages is old.pages and new.n == old.n
+        assert new.span is not old.span
+    _, got = svc.topk_vectors(probe, k=10)
+    assert [int(r[0]) for r in got] == [150, 250, 207]
+    assert not (got < 100).any()         # the quarantined range is gone
+    # a tombstone under the threshold: masked in place, in the new slots
+    append_corpus(emb, _grown(trainer.corpus, 350),
+                  VectorStore(store.directory), tombstone=[207])
+    svc.refresh()
+    assert svc.restage_skipped >= 1 and svc.restage_forced == 0
+    masked = svc._view.shards
+    for old, new in zip(moved, masked):
+        assert new.pages is old.pages and new.span is old.span
+    assert _spans(masked)[:2] == [[100, 0], [100, 100]]
+    assert 207 not in masked[1].ids and 207 in moved[1].ids
+    _, got = svc.topk_vectors(probe, k=10)
+    assert [int(r[0]) for r in got[:2]] == [150, 250]
+    assert 207 not in got[2].tolist(), "tombstoned row still servable"
     svc.close()
 
 
