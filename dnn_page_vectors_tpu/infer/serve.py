@@ -118,9 +118,9 @@ import contextlib
 import queue as queue_mod
 import threading
 import time
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from concurrent.futures import Future
-from typing import Dict, List, NamedTuple, Optional, Sequence
+from typing import Deque, Dict, List, NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -170,7 +170,11 @@ class _MicroBatcher:
         self._max = max(1, int(max_batch))
         self._q: "queue_mod.Queue[object]" = queue_mod.Queue(
             maxsize=max(self._max, int(max_queue)))
-        self.batch_sizes: List[int] = []     # dispatch telemetry
+        # dispatch telemetry since boot: a count and a sum for the
+        # metrics, and the newest sizes alone (bounded) for inspection
+        self.batches = 0
+        self.batched = 0
+        self.batch_sizes: Deque[int] = deque(maxlen=4096)
         self._t = threading.Thread(target=self._run, daemon=True,
                                    name="serve-batcher")
         self._t.start()
@@ -266,7 +270,11 @@ class _MicroBatcher:
                 # this request sat in the queue before its dispatch
                 ctx.child("queue_wait", now - t0, t0=t0)
         # graftcheck: off=locks -- single-writer: only the dispatcher
-        # thread appends; readers consume after stop() joins the thread
+        # thread writes; readers consume after stop() joins the thread
+        self.batches += 1
+        # graftcheck: off=locks -- the same single writer
+        self.batched += len(batch)
+        # graftcheck: off=locks -- the same single writer
         self.batch_sizes.append(len(batch))
         by_key: Dict[tuple, list] = {}
         for query, key, fut, _, ctx, deadline in batch:
@@ -284,9 +292,16 @@ class _MicroBatcher:
                 # one measurement, N complete span trees
                 with tracer.trace("dispatch", record=False,
                                   batch_size=len(items)) as dsp:
+                    # for the slow-query log alone: what of this dispatch
+                    # the collector took, on any thread
+                    slow_log = tracer.slow_ms is not None
+                    gc0 = svc.profiler.gc_seconds() if slow_log else 0.0
                     res = svc.search_many(
                         [q for q, _, _, _ in items], k=k, nprobe=nprobe,
                         filters=ftext, _record=False, deadline=group_dl)
+                    if slow_log:
+                        dsp.set_attrs(gc_ms=round(
+                            (svc.profiler.gc_seconds() - gc0) * 1e3, 3))
             except BaseException:  # noqa: BLE001 — isolate per request
                 for q, fut, ctx, deadline in items:
                     try:
@@ -690,7 +705,7 @@ class SearchService:
                 self._m_queue_wait, gauge=win_gauge,
                 on_change=self._on_window_adapt)
         self._batcher: Optional[_MicroBatcher] = None
-        self._batch_sizes: List[int] = []   # telemetry after close()
+        self._batch_totals = (0, 0)   # (batches, requests) after close()
         # background maintenance (docs/MAINTENANCE.md): start_maintenance()
         # attaches the service and — under maintenance.bg_rebuild — moves
         # drift-triggered IVF full rebuilds off the refresh() caller onto
@@ -1846,9 +1861,14 @@ class SearchService:
                     [enc, np.zeros((pad,) + enc.shape[1:], enc.dtype)])
             self._note_dispatch_shape("encode_query", batch=B,
                                       tokens=int(enc.shape[1]))
+            # split where the host starts to wait: the put and the launch,
+            # then the pull, which blocks on the tower
             with self._stage("encode", queries=len(grp)):
-                vecs, counts = self.embedder.encode_query_call(enc, params)
-                vecs = np.asarray(vecs, np.float32)[: len(grp)]
+                with self._stage("encode_launch"):
+                    vecs, counts = self.embedder.encode_query_call(enc,
+                                                                   params)
+                with self._stage("encode_wait"):
+                    vecs = np.asarray(vecs, np.float32)[: len(grp)]
             self._count_encode(counts)
             out[grp] = vecs
         for i, j in alias:
@@ -1877,6 +1897,9 @@ class SearchService:
                         else lambda: self._window_base_ms / 1000.0)
             self._batcher = _MicroBatcher(self, window_s,
                                           s.max_batch, s.max_queue)
+            # the collector's passes stall the dispatcher wherever they
+            # run: timed as stage `gc` until close()
+            self.profiler.watch_gc()
         return self
 
     @property
@@ -1914,9 +1937,11 @@ class SearchService:
             self._pset.close()
         if self._batcher is not None:
             self._batcher.close()
+            self.profiler.unwatch_gc()
             # telemetry survives the thread: metrics() after close still
             # reports what the batcher did
-            self._batch_sizes = self._batcher.batch_sizes
+            self._batch_totals = (self._batcher.batches,
+                                  self._batcher.batched)
             self._batcher = None
         if self._log is not None:
             self._log.write(self.metrics())
@@ -1964,11 +1989,12 @@ class SearchService:
             **self._window_metrics(),
             **self.profiler.summary(prefix="serve_stage_"),
         }
-        sizes = (self._batcher.batch_sizes if self._batcher is not None
-                 else self._batch_sizes)
-        if sizes:
-            rec["serve_batches"] = len(sizes)
-            rec["serve_mean_batch"] = round(sum(sizes) / len(sizes), 2)
+        b = self._batcher
+        batches, batched = ((b.batches, b.batched) if b is not None
+                            else self._batch_totals)
+        if batches:
+            rec["serve_batches"] = batches
+            rec["serve_mean_batch"] = round(batched / batches, 2)
         if self._pset is not None:
             # partitioned-serving topology + routing health
             # (docs/SCALING.md): per-partition/replica qps, p99, queue

@@ -107,8 +107,10 @@ class KnobDriftRule(Rule):
         # OBSERVABILITY.md "The combined trace") — exempt every such pair
         prefix_re = re.compile(
             r"PipelineProfiler\(\s*prefix=[\"']([a-z_]+\.)[\"']")
+        # (a stage opened by name, or an event name the profiler makes
+        # itself from its prefix: the collector's `<prefix>gc`)
         stage_re = re.compile(
-            r"\b_?stage\(\s*[\"']([a-z_][a-z0-9_]*)[\"']")
+            r"(?:\b_?stage\(|\b_prefix\s*\+)\s*[\"']([a-z_][a-z0-9_]*)[\"']")
         prefixes, stages = set(), set()
         for rel in ctx.glob(ctx.pkg, ".py"):
             text = ctx.read(rel) or ""

@@ -8,10 +8,12 @@ breakdown says it in one metrics line.
 from __future__ import annotations
 
 import contextlib
+import gc
 import os
 import threading
 import time
-from typing import Dict
+import weakref
+from typing import Dict, Optional
 
 import jax
 from jax.profiler import TraceAnnotation
@@ -41,8 +43,15 @@ class PipelineProfiler:
       encode        compiled query-tower dispatch (+ host materialize)
       topk          per-shard sharded_topk dispatches (or the streaming
                     sweep on a non-resident store)
+      encode_launch inside encode: the put of the ids and the launch of
+                    the query tower, host only
+      encode_wait   inside encode: the pull of the vectors, i.e. the host
+                    blocked on the tower, plus their copy
       merge         the one packed transfer of the carried top-k
       format        page-id mapping + snippet assembly
+      gc            Python's cyclic collector, one pass a call, on
+                    whichever thread collected (watch_gc); gc_gen2 the
+                    second-generation passes among them
 
     Seconds are CUMULATIVE ACROSS THREADS — a pool of N tokenizer workers
     adds each worker's time, so `read`/`tokenize` can exceed wall clock.
@@ -69,6 +78,8 @@ class PipelineProfiler:
         self._sec: Dict[str, float] = {}
         self._n: Dict[str, int] = {}
         self._bytes: Dict[str, int] = {}
+        self._gc: Optional[_GcWatch] = None
+        self._gc_finalizer: Optional[weakref.finalize] = None
 
     def add(self, name: str, seconds: float) -> None:
         with self._lock:
@@ -96,20 +107,62 @@ class PipelineProfiler:
             finally:
                 self.add(name, time.perf_counter() - t0)
 
+    # -- Python's collector ------------------------------------------------
+    def watch_gc(self) -> None:
+        """Time every pass of Python's cyclic collector as stage `gc` (and
+        `gc_gen2` for the second generation) on whichever thread collects;
+        a second-generation pass is also a `<prefix>gc` event in the trace
+        (the younger ones, hundreds a second under the tracer's own
+        allocations, are left out of it). A pass holds the GIL, so it
+        stops every other thread's Python too. Idempotent; `unwatch_gc()`
+        removes the hook, and a profiler that is freed removes it by
+        itself. The hook holds no reference to the profiler
+        and takes no lock (a pass can start inside `add()`, under it)."""
+        if self._gc is None:
+            self._gc = _GcWatch(self._prefix + "gc")
+            gc.callbacks.append(self._gc)
+            self._gc_finalizer = weakref.finalize(
+                self, gc.callbacks.remove, self._gc)
+
+    def unwatch_gc(self) -> None:
+        if self._gc is not None:
+            self._gc_finalizer()
+            self._gc = None
+
+    def gc_seconds(self) -> float:
+        """Collector seconds since watch_gc(), never reset: read around an
+        interval, the difference is what of it the collector took."""
+        w = self._gc
+        return w.total_s if w is not None else 0.0
+
     def reset(self) -> None:
         with self._lock:
             self._sec.clear()
             self._n.clear()
             self._bytes.clear()
+            if self._gc is not None:
+                self._gc.reset()
+
+    def _gc_sums(self):
+        """[(key, seconds, passes)] of the collector, or none unwatched:
+        plain reads of what only the hook writes."""
+        w = self._gc
+        if w is None:
+            return []
+        return [("gc", w.sec, w.n), ("gc_gen2", w.sec2, w.n2)]
 
     def stages(self) -> Dict[str, float]:
         """{stage: cumulative seconds} snapshot."""
         with self._lock:
-            return dict(self._sec)
+            out = dict(self._sec)
+        out.update((k, s) for k, s, _ in self._gc_sums())
+        return out
 
     def counts(self) -> Dict[str, int]:
         with self._lock:
-            return dict(self._n)
+            out = dict(self._n)
+        out.update((k, n) for k, _, n in self._gc_sums())
+        return out
 
     def summary(self, prefix: str = "stage_") -> Dict[str, float]:
         """Flat metrics-ready dict: {f'{prefix}{stage}_s': seconds,
@@ -117,14 +170,61 @@ class PipelineProfiler:
         can pin on e.g. stage_produce_wait_s — and the per-stage call count
         next to the cumulative seconds makes mean-per-call computable from
         ONE metrics line."""
+        sec, n = self.stages(), self.counts()
         with self._lock:
-            out: Dict[str, float] = {}
-            for k in sorted(self._sec):
-                out[f"{prefix}{k}_s"] = round(self._sec[k], 4)
-                out[f"{prefix}{k}_n"] = self._n.get(k, 0)
-                if k in self._bytes:
-                    out[f"{prefix}{k}_bytes"] = self._bytes[k]
-            return out
+            nbytes = dict(self._bytes)
+        out: Dict[str, float] = {}
+        for k in sorted(sec):
+            out[f"{prefix}{k}_s"] = round(sec[k], 4)
+            out[f"{prefix}{k}_n"] = n.get(k, 0)
+            if k in nbytes:
+                out[f"{prefix}{k}_bytes"] = nbytes[k]
+        return out
+
+
+class _GcWatch:
+    """The `gc.callbacks` hook of one PipelineProfiler. Only this hook
+    writes its sums and it takes no lock: CPython runs one collection at a
+    time, and a lock here could be the one the collecting thread already
+    holds. Readers fold the plain attributes in (a read may see a pass's
+    seconds a moment before its count)."""
+
+    __slots__ = ("_name", "_ann", "_t0", "sec", "n", "sec2", "n2",
+                 "total_s")
+
+    def __init__(self, name: str) -> None:
+        self._name = name
+        self._ann = None
+        self._t0 = None
+        self.total_s = 0.0
+        self.reset()
+
+    def reset(self) -> None:
+        self.sec = self.sec2 = 0.0
+        self.n = self.n2 = 0
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            if info.get("generation") == 2:
+                self._ann = TraceAnnotation(self._name)
+                self._ann.__enter__()
+            self._t0 = time.perf_counter()
+            return
+        if self._t0 is None:
+            # hooked during a pass: its finalizers run Python, and so may
+            # another thread's watch_gc()
+            return
+        dt = time.perf_counter() - self._t0
+        self._t0 = None
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        self.sec += dt
+        self.n += 1
+        self.total_s += dt
+        if info.get("generation") == 2:
+            self.sec2 += dt
+            self.n2 += 1
 
 
 class LatencyStats:

@@ -46,9 +46,15 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
-# perf_counter -> epoch alignment for export: spans time themselves on the
-# monotonic clock, the trace viewer wants wall-clock microseconds
-_EPOCH0 = time.time() - time.perf_counter()
+
+def _epoch_offset() -> float:
+    """Seconds that put a `perf_counter` reading on the wall clock, read
+    NOW: spans time themselves on the monotonic clock, while the trace
+    viewer and the jax profiler's host events (its session's
+    `profile_start_time` plus an offset) are on the wall clock. Taken at
+    export, not once at import, so a long-lived service's spans do not
+    drift from the profiler's events as the wall clock is slewed."""
+    return time.time() - time.perf_counter()
 
 _IDS = itertools.count(1)
 
@@ -122,15 +128,19 @@ class Span:
                 return hit
         return None
 
-    def to_dict(self) -> Dict[str, Any]:
+    def to_dict(self, epoch: Optional[float] = None) -> Dict[str, Any]:
+        """JSON-ready tree; `start_ms` on the wall clock, through one
+        offset for the whole tree (`_epoch_offset()` unless given)."""
+        if epoch is None:
+            epoch = _epoch_offset()
         return {
             "name": self.name,
             "trace_id": self.trace_id,
             "span_id": self.span_id,
-            "start_ms": round((_EPOCH0 + self.t0) * 1000.0, 3),
+            "start_ms": round((epoch + self.t0) * 1000.0, 3),
             "dur_ms": round(self.dur_ms, 4),
             "attrs": dict(self.attrs),
-            "children": [c.to_dict() for c in self.children],
+            "children": [c.to_dict(epoch) for c in self.children],
         }
 
 
@@ -252,7 +262,8 @@ class Tracer:
         """Recent finished traces, oldest first (JSON-ready dicts)."""
         with self._lock:
             roots = list(self._traces)
-        return [r.to_dict() for r in roots]
+        epoch = _epoch_offset()
+        return [r.to_dict(epoch) for r in roots]
 
     def last_trace(self) -> Optional[Dict[str, Any]]:
         with self._lock:

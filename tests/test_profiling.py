@@ -2,7 +2,8 @@
 smoke-only; nothing asserted a trace appears) — plus the LatencyStats /
 PipelineProfiler contracts the observability PR leans on: bounded-memory
 reservoir with nearest-rank percentile semantics stable across the change,
-and per-stage call counts next to the cumulative seconds."""
+per-stage call counts next to the cumulative seconds, and the collector's
+hook under the stages."""
 import glob
 import os
 import threading
@@ -273,3 +274,181 @@ def test_train_marks_its_steps_and_logs_rates_without_the_compile(tmp_path):
     assert last["pages_per_sec_per_chip"] > 3 * since_t0, (last, wall)
     with open(os.path.join(str(tmp_path), "metrics.jsonl")) as f:
         assert json.loads(f.readlines()[-1])["step"] == 4
+
+
+def test_gc_event_marks_second_generation_passes_alone(tmp_path):
+    """In a recorded trace the collector's hook names each second-
+    generation pass `<prefix>gc` and leaves the younger ones out, which
+    the tracer's own allocations make hundreds a second: every pass is
+    still summed as stage `gc`."""
+    import gc
+    prof = PipelineProfiler(prefix="serve.")
+    prof.watch_gc()
+    try:
+        with maybe_profile(True, str(tmp_path)):
+            for _ in range(3):
+                gc.collect(0)
+            gc.collect()
+        n = prof.counts()
+    finally:
+        prof.unwatch_gc()
+    assert n["gc"] >= 4 and n["gc_gen2"] >= 1
+    ev = _host_intervals(os.path.join(str(tmp_path), "trace"))
+    assert len(ev["serve.gc"]) == n["gc_gen2"]
+
+
+def test_watch_gc_counts_passes_and_unwatch_restores_callbacks():
+    import gc
+    before = list(gc.callbacks)
+    prof = PipelineProfiler(prefix="serve.")
+    prof.watch_gc()
+    prof.watch_gc()                                  # idempotent
+    try:
+        assert len(gc.callbacks) == len(before) + 1
+        assert prof.counts()["gc"] == 0 == prof.counts()["gc_gen2"]
+        gc.collect()
+        gc.collect(0)
+        sec, n = prof.stages(), prof.counts()
+        assert n["gc"] >= 2 and n["gc_gen2"] >= 1
+        assert sec["gc"] >= sec["gc_gen2"] > 0.0
+        assert prof.gc_seconds() == pytest.approx(sec["gc"])
+        s = prof.summary()
+        assert s["stage_gc_n"] == n["gc"] and "stage_gc_gen2_s" in s
+        prof.reset()
+        assert prof.counts()["gc"] == 0 and prof.gc_seconds() > 0.0
+    finally:
+        prof.unwatch_gc()
+    assert gc.callbacks == before
+    gc.collect()
+    assert "gc" not in prof.counts()
+    # a profiler dropped while watching takes its hook with it
+    lost = PipelineProfiler()
+    lost.watch_gc()
+    del lost
+    gc.collect()
+    assert gc.callbacks == before
+
+
+def test_collection_during_add_on_another_thread_does_not_deadlock():
+    """The hook takes no lock: passes forced on this thread while another
+    thread loops over add() (holding the profiler's lock most of the time)
+    all finish, and every add is counted."""
+    import gc
+    import sys
+    prof = PipelineProfiler()
+    prof.watch_gc()
+    stop = threading.Event()
+    adds = []
+
+    def adder():
+        k = 0
+        while not stop.is_set():
+            prof.add("topk", 1e-6)
+            junk = [[] for _ in range(50)]       # cycles of work for gen 0
+            junk[0].append(junk)
+            k += 1
+        adds.append(k)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        t = threading.Thread(target=adder, daemon=True)
+        t.start()
+        done = threading.Event()
+
+        def collect():
+            for _ in range(30):
+                gc.collect()
+            done.set()
+
+        c = threading.Thread(target=collect, daemon=True)
+        c.start()
+        assert done.wait(timeout=60), "a collection deadlocked"
+        stop.set()
+        t.join(timeout=30)
+        assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(old)
+        prof.unwatch_gc()
+    assert prof.counts()["topk"] == adds[0]
+
+
+@pytest.fixture(scope="module")
+def toy_service(tmp_path_factory):
+    """An untrained toy tower over a 2-shard store: enough for the serve
+    path's stages and spans, no training."""
+    from dnn_page_vectors_tpu.infer.bulk_embed import BulkEmbedder
+    from dnn_page_vectors_tpu.infer.vector_store import VectorStore
+    wd = str(tmp_path_factory.mktemp("profiling_serve"))
+    cfg = get_config("cdssm_toy", {
+        "data.num_pages": 64, "data.trigram_buckets": 512,
+        "model.embed_dim": 16, "model.conv_channels": 16,
+        "model.out_dim": 16, "train.batch_size": 16,
+        "eval.embed_batch_size": 32, "eval.store_shard_size": 32})
+    trainer = Trainer(cfg, workdir=wd)
+    state = trainer.init_state()
+    emb = BulkEmbedder(cfg, trainer.model, state.params, trainer.page_tok,
+                       trainer.mesh, query_tok=trainer.query_tok)
+    store = VectorStore(os.path.join(wd, "store"), dim=cfg.model.out_dim,
+                        shard_size=32)
+    store.ensure_model_step(int(state.step))
+    emb.embed_corpus(trainer.corpus, store)
+    return cfg, trainer, emb, store
+
+
+def _host_intervals(trace_dir):
+    """{event name: [(thread line's index, start_ns, end_ns)]} over the
+    host planes, on the wall clock (the session's `profile_start_time`
+    plus each event's offset)."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    data = ProfileData.from_file(path)
+    t0 = dict(data.find_plane_with_name("Task Environment").stats)[
+        "profile_start_time"]
+    out = {}
+    for plane in data.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            for ev in line.events:
+                out.setdefault(ev.name, []).append(
+                    (i, t0 + ev.start_ns, t0 + ev.end_ns))
+    return out
+
+
+def test_encode_children_and_request_spans_on_the_profilers_clock(
+        toy_service, tmp_path):
+    """In a recorded CPU trace of requests through the batcher, every
+    `serve.encode_launch` and `serve.encode_wait` lies inside a
+    `serve.encode` on the serve-batcher thread (the line of its
+    `serve.batch_window`), and the exported request span `encode` starts
+    within 1 ms of its `serve.encode` annotation: one clock for both."""
+    from dnn_page_vectors_tpu.infer.serve import SearchService
+    cfg, trainer, emb, store = toy_service
+    svc = SearchService(cfg, emb, trainer.corpus, store, preload_hbm_gb=1.0)
+    svc.start_batcher()
+    try:
+        assert svc.search(trainer.corpus.query_text(1), k=3)   # compiles
+        svc.tracer.clear()
+        with maybe_profile(True, str(tmp_path)):
+            for i in range(2, 5):
+                assert svc.search(trainer.corpus.query_text(i), k=3)
+    finally:
+        svc.close()
+    ev = _host_intervals(os.path.join(str(tmp_path), "trace"))
+    batcher = {ln for ln, _, _ in ev["serve.batch_window"]}
+    assert len(batcher) == 1
+    encodes = ev["serve.encode"]
+    assert len(encodes) == 3
+    for child in ("serve.encode_launch", "serve.encode_wait"):
+        assert len(ev[child]) == 3
+        for ln, s, e in ev[child]:
+            assert ln in batcher
+            assert any(ln == pl and ps <= s and e <= pe
+                       for pl, ps, pe in encodes), child
+    starts_us = sorted(e["ts"] for e in svc.tracer.chrome_trace()[
+        "traceEvents"] if e["name"] == "encode")
+    assert len(starts_us) == 3
+    for ts, (_, s, _) in zip(starts_us, sorted(encodes, key=lambda x: x[1])):
+        assert abs(ts * 1e3 - s) < 1e6, (ts * 1e3 - s)

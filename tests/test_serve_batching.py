@@ -186,7 +186,7 @@ def test_microbatcher_coalesces_concurrent_callers(served):
             range(12)))
     for qi, r in enumerate(results):
         _assert_same(r, direct[qi])
-    sizes = svc._batcher.batch_sizes[before:]
+    sizes = list(svc._batcher.batch_sizes)[before:]
     assert sum(sizes) == 12
     assert max(sizes) > 1, "concurrent callers never coalesced"
     assert max(sizes) <= 8                  # serve.max_batch respected

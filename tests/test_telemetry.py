@@ -652,3 +652,102 @@ def test_bucket_counters_read_buckets_and_zero(served):
     finally:
         svc.close()
     assert got == res[7:8]
+
+
+def test_encode_splits_into_launch_and_wait(served):
+    """Inside every `encode` the service opens `encode_launch` (the put
+    and the launch) and then `encode_wait` (the pull):
+    one of each an encode call, their seconds within encode's. A tower
+    that takes 0.2 s to launch puts them in `encode_launch`, not in the
+    wait."""
+    _, trainer, emb, _ = served
+    svc = _svc(served, preload=4.0)
+    svc.start_batcher()
+    try:
+        assert all(_search_through_batcher(svc, trainer, n=12, clients=4))
+        sec, n = svc.profiler.stages(), svc.profiler.counts()
+        assert n["encode"] > 0
+        assert n["encode_launch"] == n["encode_wait"] == n["encode"]
+        assert sec["encode_launch"] + sec["encode_wait"] <= sec["encode"]
+        svc.profiler.reset()
+        launch = emb.encode_query_call
+
+        def slow_launch(ids, params=None):
+            import time
+            time.sleep(0.2)
+            return launch(ids, params)
+
+        emb.encode_query_call = slow_launch
+        try:
+            assert svc.search(trainer.corpus.query_text(901), k=5)
+        finally:
+            del emb.encode_query_call        # the class's method again
+        sec, n = svc.profiler.stages(), svc.profiler.counts()
+        assert n["encode"] == 1
+        assert sec["encode_launch"] >= 0.2 and sec["encode_wait"] < 0.1
+    finally:
+        svc.close()
+
+
+@pytest.mark.parametrize("slow_ms", [0.0, None])
+def test_dispatch_span_carries_gc_ms_and_close_unhooks(served, slow_ms):
+    """With the slow-query log on, the batcher's shared `dispatch` span
+    says how much of it the collector took (`gc_ms`); with it off the
+    dispatch reads nothing for it. The service's collector hook is in
+    `gc.callbacks` while the batcher runs, counts forced passes as stage
+    `gc`, and close() takes it out again."""
+    import gc
+    _, trainer, _, _ = served
+    before = list(gc.callbacks)
+    svc = _svc(served, preload=4.0,
+               obs=None if slow_ms is None else {"slow_ms": slow_ms})
+    svc.start_batcher()
+    try:
+        assert len(gc.callbacks) == len(before) + 1
+        gc.collect()
+        assert svc.profiler.counts()["gc_gen2"] >= 1
+        assert svc.search(trainer.corpus.query_text(3), k=5)
+    finally:
+        svc.close()
+    assert gc.callbacks == before
+    root = [t for t in svc.tracer.traces() if t["name"] == "search"][-1]
+
+    def find(d, name):
+        if d["name"] == name:
+            return d
+        for c in d["children"]:
+            hit = find(c, name)
+            if hit is not None:
+                return hit
+        return None
+
+    attrs = find(root, "dispatch")["attrs"]
+    assert attrs["batch_size"] == 1
+    if slow_ms is None:
+        assert "gc_ms" not in attrs
+    else:
+        assert attrs["gc_ms"] >= 0.0
+
+
+def test_batch_telemetry_is_bounded_and_counts_since_boot(served):
+    """`serve_batches` / `serve_mean_batch` come from a running count and
+    sum, not from the list of sizes, which keeps the newest 4,096 alone:
+    emptied, the metrics read the same, before and after close()."""
+    _, trainer, _, _ = served
+    svc = _svc(served, preload=4.0)
+    svc.start_batcher()
+    b = svc._batcher
+    try:
+        res = _search_through_batcher(svc, trainer, n=10, clients=5)
+        assert all(res) and b.batch_sizes.maxlen == 4096
+        m = svc.metrics()
+        assert m["serve_batches"] == b.batches == len(b.batch_sizes) > 0
+        assert b.batched == sum(b.batch_sizes) == len(res)
+        assert m["serve_mean_batch"] == round(len(res) / b.batches, 2)
+        b.batch_sizes.clear()
+        assert svc.metrics()["serve_batches"] == m["serve_batches"]
+    finally:
+        svc.close()
+    after = svc.metrics()
+    assert after["serve_batches"] == m["serve_batches"]
+    assert after["serve_mean_batch"] == m["serve_mean_batch"]
