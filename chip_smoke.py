@@ -414,7 +414,7 @@ def phase_data_parallel(root: str, overrides: dict, chips: int, steps: int,
     for n in (chips, 1):
         mesh = make_mesh(MeshConfig(data=n, strict=True))
         before = _bytes_in_use(devs)
-        pages, _ = stage_shard(vecs, store_rows, dim, mesh)
+        pages, _ = stage_shard(vecs, store_rows, dim, mesh, words=True)
         if n == chips:
             on = {s.device for s in pages.addressable_shards}
             _must(len(on) == chips,

@@ -128,8 +128,8 @@ from dnn_page_vectors_tpu.infer.bulk_embed import BulkEmbedder
 from dnn_page_vectors_tpu.infer.transport import DeadlineExceeded
 from dnn_page_vectors_tpu.infer.vector_store import VectorStore, read_ahead
 from dnn_page_vectors_tpu.ops.topk import (
-    empty_topk, merge_shard_topk, sharded_topk_fn, stage_shard,
-    topk_over_store, unpack_topk)
+    empty_topk, merge_shard_topk, scans_in_kernel, sharded_topk_fn,
+    stage_shard, topk_over_store, unpack_topk)
 from dnn_page_vectors_tpu.utils import faults
 from dnn_page_vectors_tpu.utils.profiling import LatencyStats, PipelineProfiler
 from dnn_page_vectors_tpu.utils.telemetry import MetricsRegistry
@@ -428,7 +428,10 @@ class _Shard(NamedTuple):
     `span` if its slot moved), or with newer tombstones masked in `ids`."""
     ids: np.ndarray        # [n] int64 page ids, -1 = tombstoned since staged
     n: int                 # rows of `pages` that hold a vector
-    pages: object          # [pad_rows, D] device rows at the stored width
+    pages: object          # device rows at the stored width, for the scan
+    #                        alone: [pad_rows, D] int8 | float32 rows, and a
+    #                        float16 store as its pair words, uint32
+    #                        [pad_rows, D/2] (ops/topk.py:pair_words)
     scales: object         # [pad_rows] device fp16 scales (int8 store) | None
     span: object           # int32 [2] replicated on the device: `n`, and the
     #                        shard's first combined id, slot * pad_rows
@@ -546,9 +549,11 @@ class SearchService:
         # reduces them on the device; BulkEmbedder.encode_query_call)
         self._m_encode = {name: reg.counter("encode." + name)
                           for name in BulkEmbedder.ENCODE_COUNTERS}
-        # resident buckets answered by the carried scan alone | tail too
+        # resident buckets answered by the carried scan alone | tail too;
+        # those whose resident shards the Pallas kernel scanned
         self._m_carried = reg.counter("topk.carried_buckets")
         self._m_tail = reg.counter("topk.tail_buckets")
+        self._m_kernel = reg.counter("topk.kernel_buckets")
         self._m_ann_lists = reg.counter("serve.ann_lists_scanned")
         self._m_ann_reranked = reg.counter("serve.ann_candidates_reranked")
         self._m_ann_fallbacks = reg.counter("serve.ann_fallbacks")
@@ -1495,7 +1500,8 @@ class SearchService:
                 n = int(ids.shape[0])
                 staged.append(_Shard(
                     ids, n, *stage_shard(vecs, rows, store.dim,
-                                         self.embedder.mesh, scales=scl),
+                                         self.embedder.mesh, scales=scl,
+                                         words=True),
                     device_span(n)))
                 keys.append(key)
                 stamps.append(estep)
@@ -2629,6 +2635,8 @@ class SearchService:
             # bucket: the whole point of the carried [B, 2k] layout
             packed = np.asarray(packed)
         (self._m_tail if view.stream_entries else self._m_carried).inc()
+        if scans_in_kernel(view.shards[0].pages.dtype, k):
+            self._m_kernel.inc()
         top_s, top_i = unpack_topk(packed)
         pids = np.where(top_i >= 0,
                         view.pid_table[np.clip(top_i, 0, None)], -1)
@@ -2660,7 +2668,8 @@ class SearchService:
                     continue
                 pages, scales = stage_shard(vecs, view.pad_rows,
                                             view.store.dim,
-                                            self.embedder.mesh, scales=scl)
+                                            self.embedder.mesh, scales=scl,
+                                            words=True)
                 # the degraded tail routes by stamp too: a failed-to-stage
                 # shard still scores against its own tower's block
                 q_e = qs.get(view.store.entry_step(entry), fallback)

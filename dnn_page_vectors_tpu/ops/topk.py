@@ -9,7 +9,9 @@ the MXU. Exact (brute-force) search, three tiers:
   * `chunked_topk`   — one device, pages resident in HBM.
   * `sharded_topk`   — pages row-sharded over the mesh 'data' axis; each
     device scores its slice, per-shard top-k candidates are all-gathered
-    over ICI and merged. HBM per device holds only N/n_data rows.
+    over ICI and merged. HBM per device holds only N/n_data rows. Float16
+    rows staged as pair words are scored by one Pallas kernel,
+    `exact_scan` (below), other rows by the `lax.scan` above.
   * `topk_over_store`— streams vector-store shards from disk through
     `sharded_topk`, merging on host. Peak footprint is ONE store shard
     spread over the mesh, so 1B-page retrieval (BASELINE.md:16) runs on a
@@ -28,6 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -38,10 +42,11 @@ def _topk_scan(q: jnp.ndarray, pages: jnp.ndarray, k: int, chunk: int,
     rows >= `valid` (traced scalar) are padding and score -inf. `init` lets
     shard_map callers pass a carry pcast to the right varying axes.
 
-    pages may be narrow (fp16 rows, or int8 codes with per-row `scales`):
-    the widening happens HERE, fused into the matmul's HBM read, so device
-    memory and host->device traffic stay at the stored width. For int8 the
-    per-row scale factors out of the dot product — score[b, j] =
+    pages may be narrow (fp16 rows, their pair words [N, D/2] uint32
+    (`pair_words`), or int8 codes with per-row `scales`): the widening
+    happens HERE, fused into the matmul's HBM read, so device memory and
+    host->device traffic stay at the stored width. For int8 the per-row
+    scale factors out of the dot product — score[b, j] =
     (q[b] . codes[j]) * scale[j] — so dequant is one [Bq, chunk] multiply
     on the score block, never a materialized fp32 page matrix."""
     Bq = q.shape[0]
@@ -61,7 +66,7 @@ def _topk_scan(q: jnp.ndarray, pages: jnp.ndarray, k: int, chunk: int,
         # HIGHEST precision: ranking fidelity matters more than the ~2x MXU
         # cost of the fp32-via-bf16-passes matmul on TPU. fp16->fp32 widening
         # is exact; int8 codes (<= 127 in magnitude) are exact in any float.
-        s = jnp.matmul(q, block.T.astype(jnp.float32),
+        s = jnp.matmul(q, _widen(block).T,
                        precision=lax.Precision.HIGHEST,
                        preferred_element_type=jnp.float32)  # [Bq, chunk]
         if scl is not None:
@@ -82,6 +87,228 @@ def _topk_scan(q: jnp.ndarray, pages: jnp.ndarray, k: int, chunk: int,
         body, (init_scores, init_idx),
         (jnp.arange(n_chunks, dtype=jnp.int32), blocks, scale_blocks))
     return scores, idx
+
+
+# The exact scan of float16 rows as ONE Pallas kernel (`exact_scan`): the
+# shard streams from HBM through VMEM once, in blocks of _SCAN_BLOCK_BYTES
+# that the grid's BlockSpec double-buffers; each block is scored on the MXU
+# in exact bfloat16 pieces and folded into a running top-k that stays in the
+# kernel's output blocks (VMEM) across the grid. The running top-k is
+# `_LANES` wide (k <= 128), sorted by score, the lower row id first among
+# equal scores, -inf / -1 in the slots nothing filled.
+#
+# Exactness. A float16 x has 11 significant bits: hi = x with its float32
+# bits past bfloat16's cut to zero and lo = x - hi (at most 3 bits) are both
+# bfloat16 values and hi + lo == x; subnormals included, since bfloat16 has
+# float32's exponent range. A float32 q splits into three bfloat16 pieces
+# that sum back to q (`split_query`; cut by masks, not by round trips
+# through bfloat16, which XLA on the chip may drop as excess precision).
+# Every piece product is exact in float32, so the score is the
+# float32-accumulated sum of all 3 x 2 of them: a superset of the terms
+# `Precision.HIGHEST` keeps for these operands, never fewer.
+#
+# The rows' layout. Mosaic takes no float16 operand, and on the chip XLA's
+# bitcast of float16 to bfloat16 flushes the patterns that read as bfloat16
+# subnormals (float16 values under 2^-17) to zero. So the kernel reads the
+# rows as "pair words": uint32 [N, D/2], each word the bit patterns of
+# columns 2c (low half) and 2c + 1 of a row (`pair_words`, a view of the
+# host's float16 bytes that `stage_shard(words=True)` puts on the device:
+# nothing is converted a launch), decodes each half with integer ops
+# (`_f16_value`) and scores the even and the odd columns against the
+# queries' even and odd columns. Float16 rows handed to the scan as they
+# are, and pair words with k > _LANES, take `_topk_scan` (which decodes
+# the words a chunk at a time, `_widen`).
+_LANES = 128
+_SCAN_BLOCK_BYTES = 4 << 20     # rows a grid step reads
+_SCAN_TILE_ROWS = 256           # rows scored per inner step
+_SCAN_TILE_WORDS = 128          # pair words (256 columns) per MXU product
+_SCAN_QUERY_BLOCK = 128         # queries a pass over the shard serves
+_SCAN_VMEM_LIMIT = 32 * 1024 * 1024
+
+
+def pair_words(vecs: np.ndarray) -> np.ndarray:
+    """float16 rows [N, D] (D even) -> uint32 [N, D/2], each word the bit
+    patterns of columns 2c (low half) and 2c + 1: the layout `exact_scan`
+    reads, as a view of the same bytes (nothing is copied)."""
+    return np.ascontiguousarray(vecs, np.float16).view("<u4")
+
+
+def _cut(x: jnp.ndarray) -> jnp.ndarray:
+    """float32 x with every bit past bfloat16's cut to zero: its top 8
+    significant bits, a bfloat16 value, by a mask (a round trip through
+    bfloat16 would round, and XLA may drop such a round trip as excess
+    precision)."""
+    return lax.bitcast_convert_type(
+        lax.bitcast_convert_type(x, jnp.uint32) & jnp.uint32(0xFFFF0000),
+        jnp.float32)
+
+
+def split_query(q: jnp.ndarray) -> Tuple[jnp.ndarray, ...]:
+    """float32 q -> (hi, mid, lo) bfloat16 with hi + mid + lo == q exactly:
+    the 24 significant bits cut into three runs of 8."""
+    hi = _cut(q)
+    r = q - hi
+    mid = _cut(r)
+    return tuple(p.astype(jnp.bfloat16) for p in (hi, mid, r - mid))
+
+
+def _f16_value(h: jnp.ndarray) -> jnp.ndarray:
+    """uint32 holding a float16 bit pattern in its low 16 bits -> the
+    float32 value, exactly: the exponent is rebiased from 15 to 127 in
+    integer ops; a subnormal (exponent 0) is read as 2^-14 (1 + m/1024) and
+    2^-14 taken off again, both exact."""
+    mag = (h & jnp.uint32(0x7FFF)) << jnp.uint32(13)
+    sign = (h & jnp.uint32(0x8000)) << jnp.uint32(16)
+    sub = mag < jnp.uint32(0x00800000)
+    base = jnp.where(sub, jnp.uint32(113 << 23), jnp.uint32(112 << 23))
+    as_f32 = lambda u: lax.bitcast_convert_type(u, jnp.float32)  # noqa: E731
+    return (as_f32((mag + base) | sign)
+            - as_f32(jnp.where(sub, base | sign, jnp.uint32(0))))
+
+
+def f16_pieces(h: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """uint32 holding float16 bit patterns in their low 16 bits -> (hi, lo)
+    bfloat16 with float32(hi) + float32(lo) == the float16 value exactly."""
+    x = _f16_value(h)
+    hi = _cut(x)
+    return hi.astype(jnp.bfloat16), (x - hi).astype(jnp.bfloat16)
+
+
+def _widen(block: jnp.ndarray) -> jnp.ndarray:
+    """A block of rows as float32 [rows, D]: pair words [rows, D/2] decoded
+    exactly by `_f16_value` (both halves, columns interleaved back), any
+    other dtype converted, which is exact for float16 and int8."""
+    if block.dtype != jnp.uint32:
+        return block.astype(jnp.float32)
+    halves = (block & jnp.uint32(0xFFFF), block >> jnp.uint32(16))
+    return jnp.stack([_f16_value(h) for h in halves], axis=-1).reshape(
+        block.shape[0], -1)
+
+
+def _scan_kernel(valid_ref, q_ref, x_ref, s_ref, i_ref, blk_ref, *, k: int,
+                 qb: int, tile: int, words: int):
+    """One grid step (query block, row block): score the row block into
+    `blk_ref` [qb, rows] (-inf past `valid`), then fold it into the running
+    top-k (`s_ref` / `i_ref` [qb, _LANES]) by rounds of max, lowest row at
+    the max, insert and mask, while some query's best in the block beats
+    its running k-th: a block that no query's k-th admits costs no round.
+    Blocks arrive in rising row order and an insert goes after every equal
+    score already held, so the lower row wins a tie, as `lax.top_k`'s
+    lower position does in `_topk_scan`. `q_ref` holds the query block's
+    three pieces stacked by rows, its even columns in [0, 0] and its odd
+    ones in [0, 1]."""
+    j = pl.program_id(1)
+    rows, width = x_ref.shape
+    base = j * rows
+    valid = valid_ref[0]
+
+    @pl.when(j == 0)
+    def _():
+        s_ref[...] = jnp.full(s_ref.shape, -jnp.inf, jnp.float32)
+        i_ref[...] = jnp.full(i_ref.shape, -1, jnp.int32)
+
+    @pl.when(base < valid)
+    def _():
+        nt = (((1,), (1,)), ((), ()))
+        for r in range(0, rows, tile):
+            p = None
+            for c in range(0, width, words):
+                w = x_ref[r:r + tile, c:c + words]
+                for half, h in enumerate((w & jnp.uint32(0xFFFF),
+                                          w >> jnp.uint32(16))):
+                    q = q_ref[0, half, :, c:c + words]
+                    for piece in f16_pieces(h):
+                        part = lax.dot_general(
+                            q, piece, nt, preferred_element_type=jnp.float32)
+                        p = part if p is None else p + part
+            row = base + r + lax.broadcasted_iota(jnp.int32, (qb, tile), 1)
+            blk_ref[:, r:r + tile] = jnp.where(
+                row < valid, (p[2 * qb:3 * qb] + p[qb:2 * qb]) + p[:qb],
+                -jnp.inf)
+        row = base + lax.broadcasted_iota(jnp.int32, (qb, rows), 1)
+        lane = lax.broadcasted_iota(jnp.int32, (qb, _LANES), 1)
+
+        def kth():
+            return jnp.max(jnp.where(lane == k - 1, s_ref[...], -jnp.inf),
+                           axis=1, keepdims=True)
+
+        def beats(best):
+            return jnp.any(best > kth())
+
+        def fold(best):
+            blk = blk_ref[...]
+            at = jnp.min(jnp.where(blk == best, row, jnp.int32(2 ** 31 - 1)),
+                         axis=1, keepdims=True)
+            blk = jnp.where(row == at, -jnp.inf, blk)
+            blk_ref[...] = blk
+            run_s, run_i = s_ref[...], i_ref[...]
+            n_ge = jnp.sum((run_s >= best).astype(jnp.int32), axis=1,
+                           keepdims=True)
+            ins_s = jnp.where(lane < n_ge, run_s, jnp.where(
+                lane == n_ge, best, pltpu.roll(run_s, 1, 1)))
+            ins_i = jnp.where(lane < n_ge, run_i, jnp.where(
+                lane == n_ge, at, pltpu.roll(run_i, 1, 1)))
+            take = (best > kth()) & (lane < k)
+            s_ref[...] = jnp.where(take, ins_s, run_s)
+            i_ref[...] = jnp.where(take, ins_i, run_i)
+            return jnp.max(blk, axis=1, keepdims=True)
+
+        lax.while_loop(beats, fold,
+                       jnp.max(blk_ref[...], axis=1, keepdims=True))
+
+
+def _kernel_topk(q: jnp.ndarray, pages: jnp.ndarray, k: int,
+                 valid: jnp.ndarray, interpret: Optional[bool] = None
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """`_topk_scan`'s answer for float16 rows from `exact_scan`: (scores
+    [Bq, k] float32, row ids [Bq, k] int32, -inf / -1 past the rows <
+    `valid` that exist; k <= _LANES). `pages` is pair words [N, D/2]
+    (`pair_words`). A grid step reads _SCAN_BLOCK_BYTES of rows; steps
+    past the last valid row repeat its block index, so Pallas fetches
+    nothing for them. Queries go in blocks of up to _SCAN_QUERY_BLOCK, each
+    its own pass over the rows."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    Bq = q.shape[0]
+    N, width = pages.shape
+    words = _SCAN_TILE_WORDS if width % _SCAN_TILE_WORDS == 0 else width
+    tile = min(_SCAN_TILE_ROWS, -(-N // 8) * 8)
+    rows = max(tile, _SCAN_BLOCK_BYTES // (4 * width) // tile * tile)
+    rows = min(rows, -(-N // tile) * tile)
+    if N % rows:
+        pages = jnp.concatenate(
+            [pages, jnp.zeros((rows - N % rows, width), pages.dtype)])
+    qb = min(-(-Bq // 8) * 8, _SCAN_QUERY_BLOCK)
+    nq = -(-Bq // qb)
+    m = -(-3 * qb // 16) * 16
+    qf = jnp.pad(q.astype(jnp.float32),
+                 ((0, nq * qb - Bq), (0, 2 * width - q.shape[1])))
+    # [nq, parity, m, width]: a query block's three pieces stacked by rows
+    # (zero rows up to m), its even columns at parity 0, odd at 1
+    lhs = jnp.stack(split_query(qf)).reshape(3, nq, qb, width, 2)
+    lhs = jnp.pad(lhs.transpose(1, 4, 0, 2, 3).reshape(nq, 2, 3 * qb, width),
+                  ((0, 0), (0, 0), (0, m - 3 * qb), (0, 0)))
+
+    def row_block(i, j, v):
+        return jnp.minimum(j, jnp.maximum(v[0] - 1, 0) // rows), 0
+
+    s, ids = pl.pallas_call(
+        partial(_scan_kernel, k=k, qb=qb, tile=tile, words=words),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(nq, pages.shape[0] // rows),
+            in_specs=[pl.BlockSpec((1, 2, m, width),
+                                   lambda i, j, v: (i, 0, 0, 0)),
+                      pl.BlockSpec((rows, width), row_block)],
+            out_specs=[pl.BlockSpec((qb, _LANES), lambda i, j, v: (i, 0))] * 2,
+            scratch_shapes=[pltpu.VMEM((qb, rows), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((nq * qb, _LANES), jnp.float32),
+                   jax.ShapeDtypeStruct((nq * qb, _LANES), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_SCAN_VMEM_LIMIT),
+        interpret=interpret, name="exact_scan",
+    )(jnp.reshape(valid, (1,)).astype(jnp.int32), lhs, pages)
+    return s[:Bq, :k], ids[:Bq, :k]
 
 
 @partial(jax.jit, static_argnames=("k", "chunk"))
@@ -134,6 +361,33 @@ def empty_topk(batch: int, k: int) -> np.ndarray:
          np.full((batch, k), -1, np.int32)], axis=1)
 
 
+def scans_in_kernel(dtype, k: int) -> bool:
+    """Whether the carried scan answers rows of `dtype` with `exact_scan`:
+    float16 rows staged as pair words (uint32), and a top-k that fits the
+    kernel's lanes. Pair words with a wider top-k, float16 rows as they
+    are, int8 codes with scales and float32 rows take `_topk_scan`."""
+    return jnp.dtype(dtype) == jnp.uint32 and k <= _LANES
+
+
+def _local_scan(q, pages, scales, k: int, chunk: int, valid):
+    """`_topk_scan` over one device's rows inside `shard_map`, padded to a
+    chunk multiple. The scan starts from a constant, NOT from the carry:
+    every device would bring the same earlier winners to the gather that
+    follows, n_data copies of each; pcast marks it varying over 'data' so
+    the scan's in/out types agree."""
+    pad = (-pages.shape[0]) % chunk
+    if pad:
+        pages = jnp.concatenate(
+            [pages, jnp.zeros((pad, pages.shape[1]), pages.dtype)])
+        if scales is not None:
+            scales = jnp.concatenate([scales, jnp.zeros((pad,), scales.dtype)])
+    init = jax.tree_util.tree_map(
+        lambda x: lax.pcast(x, ("data",), to="varying"),
+        (jnp.full((q.shape[0], k), -jnp.inf, jnp.float32),
+         jnp.full((q.shape[0], k), -1, jnp.int32)))
+    return _topk_scan(q, pages, k, chunk, valid, scales=scales, init=init)
+
+
 _SHARDED_CACHE: Dict[Tuple, Tuple] = {}
 
 
@@ -146,36 +400,24 @@ def _build_sharded_topk(mesh: Mesh, k: int, chunk: int, scaled: bool):
     the carry with this launch's rows folded in, takes its buffer, so a
     launch allocates nothing and the caller's carry is gone. Carried
     entries come first in the fold and `lax.top_k` keeps the lower
-    position among equal scores: the earlier launch wins a tie. Cached per
-    (mesh, k, chunk, scaled); jit retraces per pages dtype within a key."""
+    position among equal scores: the earlier launch wins a tie. Each
+    device's rows are scanned by `exact_scan` where `scans_in_kernel` says
+    so (pair words, k <= 128), else by `_topk_scan` in chunks of `chunk`. Cached per (mesh, k, chunk, scaled); jit retraces per pages
+    dtype within a key."""
     n_data = mesh.shape["data"]
 
     def run(q, pages_local, scales_local, span, carry):
         rows = pages_local.shape[0]                  # per-shard row count
         shard = lax.axis_index("data")
         valid_local = jnp.clip(span[0] - shard * rows, 0, rows)
-        c = min(chunk, rows)
-        pad = (-rows) % c
-        if pad:
-            pages_local = jnp.concatenate(
-                [pages_local,
-                 jnp.zeros((pad, pages_local.shape[1]), pages_local.dtype)])
-            if scales_local is not None:
-                scales_local = jnp.concatenate(
-                    [scales_local, jnp.zeros((pad,), scales_local.dtype)])
-        # the local scan starts from a constant, NOT from `carry`: every
-        # device would bring the same earlier winners to the gather below,
-        # n_data copies of each. pcast marks it varying over 'data' so the
-        # scan's in/out types agree under shard_map
-        init = jax.tree_util.tree_map(
-            lambda x: lax.pcast(x, ("data",), to="varying"),
-            (jnp.full((q.shape[0], k), -jnp.inf, jnp.float32),
-             jnp.full((q.shape[0], k), -1, jnp.int32)))
         # named_scope: the two regions of this program carry their names
         # in every op's metadata, for --profile in xprof/Perfetto
         with jax.named_scope("sharded_topk.scan"):
-            s, i = _topk_scan(q, pages_local, k, c, valid_local,
-                              scales=scales_local, init=init)
+            if scales_local is None and scans_in_kernel(pages_local.dtype, k):
+                s, i = _kernel_topk(q, pages_local, k, valid_local)
+            else:
+                s, i = _local_scan(q, pages_local, scales_local, k,
+                                   min(chunk, rows), valid_local)
         with jax.named_scope("sharded_topk.local_topk"):
             gi = jnp.where(i >= 0, i + (shard * rows + span[1]), -1)
             # gather every shard's k candidates over ICI and merge
@@ -233,8 +475,9 @@ def sharded_topk(q: jnp.ndarray, pages, mesh: Mesh, k: int = 10,
     index -1). q is replicated. Returns (scores, indices) ON THE HOST,
     indices global into the sharded row order: the scan's one packed array
     pulled in one transfer and split there (`unpack_topk`). `pages` may be
-    fp16 rows or int8 codes with per-row `scales` [N] — widened on-device
-    (_topk_scan).
+    the pair words of fp16 rows (`stage_shard(words=True)`), scanned by
+    `exact_scan`, or fp16 rows or int8 codes with per-row `scales` [N],
+    widened on-device (_topk_scan).
 
     One launch of the carried scan from an empty carry at offset 0, both
     put up from host constants: the call runs no program but the scan."""
@@ -360,20 +603,25 @@ def merge_partition_topk(parts) -> Tuple[np.ndarray, np.ndarray]:
     return merged[0]
 
 
-def stage_shard(vecs, rows: int, dim: int, mesh: Mesh, scales=None
+def stage_shard(vecs, rows: int, dim: int, mesh: Mesh, scales=None,
+                words: bool = False
                 ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """Zero-pad one store shard to `rows` (the static compiled shape) and
     place it row-sharded over the mesh 'data' axis, AT ITS STORED WIDTH
     (fp16 rows / int8 codes + fp16 `scales`): host->device traffic and HBM
     per shard are 2x / 4x under the old fp32 staging, and the widening fuses
-    into the device matmul (VERDICT r4 Weak #3). Shared by the streaming
-    sweep below and the HBM-resident serving path (infer/serve.py).
-    Returns (pages, scales-or-None)."""
+    into the device matmul (VERDICT r4 Weak #3). `words` stages float16
+    rows as the pair words `exact_scan` reads (`pair_words`: the same
+    bytes, uint32 [rows, dim/2]), for a caller that hands the pages to the
+    scan alone. Shared by the streaming sweep below and the HBM-resident
+    serving path (infer/serve.py). Returns (pages, scales-or-None)."""
     dtype = np.asarray(vecs).dtype
     if dtype not in (np.float16, np.int8):
         dtype = np.float32
     buf = np.zeros((rows, dim), dtype)
     buf[: vecs.shape[0]] = vecs
+    if words and dtype == np.float16 and dim % 2 == 0:
+        buf = pair_words(buf)
     pages = jax.device_put(buf, NamedSharding(mesh, P("data")))
     if scales is None:
         return pages, None
@@ -432,7 +680,8 @@ def topk_over_store(query_vecs: np.ndarray, store, mesh: Mesh, k: int = 10,
         n = vecs.shape[0]
         if n == 0:        # empty shard: nothing to score, don't stage it
             continue
-        pages, scales = stage_shard(vecs, shard_rows, dim, mesh, scales=scl)
+        pages, scales = stage_shard(vecs, shard_rows, dim, mesh, scales=scl,
+                                    words=True)
         ids = np.asarray(ids, np.int64)
         for s in range(0, nq, qb):
             q = query_vecs[s: s + qb]

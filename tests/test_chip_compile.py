@@ -10,6 +10,7 @@ at a time may load libtpu, and every xdist worker imports this file), and
 all four compiles live in this one file so one worker owns the library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -86,6 +87,29 @@ def test_causal_flash_compiles_for_v5e_at_head_width_256(one_chip, mode):
         lambda *a: jnp.sum(attn(*a).astype(jnp.float32)), (0, 1, 2))
     text = jax.jit(fn).lower(*qkv, mask).compile().as_text()
     assert text.count("tpu_custom_call") >= (1 if mode == "forward" else 3)
+
+
+@pytest.mark.parametrize("dim,batch", [(256, 8), (1024, 8), (256, 200)])
+def test_exact_scan_compiles_for_v5e_at_the_store_widths(one_chip, dim,
+                                                         batch):
+    """`exact_scan` over a 65,536-row shard of pair words at the benchmark's
+    two widths (bert_mini's 256, the hybrid towers' 1,024) and a query
+    batch past one query block: Mosaic takes the integer decode, the
+    running top-k's loop and its VMEM, and the words go to the kernel with
+    no XLA pass over them."""
+    import functools
+
+    from dnn_page_vectors_tpu.ops.topk import _kernel_topk
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype,  # noqa: E731
+                                                     sharding=one_chip)
+    text = jax.jit(functools.partial(_kernel_topk, k=10, interpret=False)
+                   ).lower(shape((batch, dim), jnp.float32),
+                           shape((65536, dim // 2), jnp.uint32),
+                           valid=shape((), jnp.int32)).compile().as_text()
+    entry = text[text.index("ENTRY"):]
+    # the kernel reads the words as they were put, no XLA op between
+    assert re.search(r"custom-call\([^)]*%pages\.\d+\)", entry)
+    assert "tpu_custom_call" in entry
 
 
 # a buffer of 72 tiles (the worst case) and one of 24 (the expected load)

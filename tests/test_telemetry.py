@@ -654,6 +654,33 @@ def test_bucket_counters_read_buckets_and_zero(served):
     assert got == res[7:8]
 
 
+def test_kernel_bucket_counter_reads_every_float16_bucket(served):
+    """`topk.kernel_buckets` counts the buckets whose resident shards
+    `exact_scan` scanned, once a bucket: a float16 store is staged as pair
+    words, so every bucket of a served batch is one. A k wider than the
+    kernel's 128 lanes is answered from the same words by the XLA scan,
+    the same pages first, and is not counted."""
+    import numpy as np
+
+    _, trainer, _, _ = served
+    svc = _svc(served, preload=4.0)
+    try:
+        assert svc._view.shards[0].pages.dtype == np.uint32
+        kernel = svc.registry.counter("topk.kernel_buckets")
+        assert kernel.value == 0
+        res = svc.search_many(
+            [trainer.corpus.query_text(i)
+             for i in range(2 * svc.query_batch + 1)], k=5)
+        assert all(res)
+        assert kernel.value == svc.profiler.counts()["merge"] == 3
+        (wide,) = svc.search_many([trainer.corpus.query_text(7)], k=200)
+        assert len(wide) == 200 and kernel.value == 3
+        assert ([r["page_id"] for r in wide[:5]]
+                == [r["page_id"] for r in res[7]])
+    finally:
+        svc.close()
+
+
 def test_encode_splits_into_launch_and_wait(served):
     """Inside every `encode` the service opens `encode_launch` (the put
     and the launch) and then `encode_wait` (the pull):

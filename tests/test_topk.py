@@ -509,3 +509,183 @@ def test_topk_over_store_skips_empty_shard(eight_devices, tmp_path):
         scores, np.take_along_axis(ref_s, ref_idx, axis=1),
         rtol=1e-4, atol=1e-4)
     assert (pids >= 0).all() and (pids < 40).all()
+
+
+def _f64_topk(q, rows, k, valid):
+    """The float64 reference: every row's exact score, rows >= valid out,
+    the lower row first among equal scores; -inf / -1 past what exists."""
+    s = q.astype(np.float64) @ rows.astype(np.float64).T
+    s[:, valid:] = -np.inf
+    pos = np.argsort(-s, axis=1, kind="stable")[:, :k]
+    top = np.take_along_axis(s, pos, axis=1)
+    return top, np.where(np.isfinite(top), pos, -1)
+
+
+def _kernel_case(case):
+    """(q, float16 rows, valid, k, block rows) for one case of the kernel's
+    test; a small block makes several grid steps of a few hundred rows."""
+    rng = np.random.default_rng(40)
+    dim = {"d1024": 1024}.get(case, 256)
+    n, valid, k, block = 1024, 1024, 10, 256
+    rows = (rng.normal(size=(n, dim)) / np.sqrt(dim)).astype(np.float16)
+    q = rng.normal(size=(8, dim)).astype(np.float32)
+    if case == "valid_inside":
+        valid = 601                          # inside the third block
+    elif case == "duplicate_rows":
+        best = int(np.argmax(q[0] @ rows.astype(np.float32).T))
+        rows[[(best + 300) % n, (best + 700) % n]] = rows[best]
+    elif case == "best_equals_kth":
+        # exact scores: the first block's 10th best is copied into the
+        # second block as that block's best, a tie the earlier row wins
+        q = (rng.integers(-64, 65, (8, dim)) / 64).astype(np.float32)
+        rows = (rng.integers(-64, 65, (n, dim)) / 256).astype(np.float16)
+        s = q @ rows.astype(np.float32).T
+        kth = int(np.argsort(-s[0, :block], kind="stable")[k - 1])
+        rows[block:2 * block] = rows[block:2 * block] * np.float16(0.25)
+        rows[block + 5] = rows[kth]
+    elif case == "padded_query_block":
+        q = q[:5]                            # zero rows up to 8
+    return q, rows, valid, k, block
+
+
+@pytest.mark.parametrize("case", [
+    "d256", "d1024", "valid_inside", "duplicate_rows", "best_equals_kth",
+    "padded_query_block", "carry_folded"])
+def test_exact_scan_kernel_matches_float64(eight_devices, monkeypatch, case):
+    """`exact_scan` (Pallas interpret mode here) against a float64 numpy
+    reference: the same ids in the same order, scores to float32 rounding,
+    over 256- and 1,024-wide rows in blocks of 256; rows at or past `valid`
+    score -inf with id -1; of two equal rows the lower id comes first; a
+    block whose best only ties the running k-th adds nothing; a query
+    block padded with zero queries answers the real ones; and through the
+    carried scan on a two-device mesh the carry folds in after the scan,
+    the earlier launch first."""
+    import jax
+
+    from dnn_page_vectors_tpu.ops import topk
+    q, rows, valid, k, block = _kernel_case(case)
+    monkeypatch.setattr(topk, "_SCAN_BLOCK_BYTES", block * rows.shape[1] * 2)
+    want_s, want_i = _f64_topk(q, rows, k, valid)
+    if case == "carry_folded":
+        mesh = make_mesh(MeshConfig(data=2))
+        half = rows.shape[0] // 2
+        shards = [(rows[:half], None, half), (rows[half:], None, 900 - half)]
+        got_s, got_i = _carried(mesh, k, q, shards, False)
+        want_s, want_i = _f64_topk(q, rows, k, 900)
+    else:
+        got_s, got_i = jax.jit(topk._kernel_topk, static_argnums=2)(
+            jnp.asarray(q), jnp.asarray(topk.pair_words(rows)), k,
+            jnp.int32(valid))
+    got_s, got_i = np.asarray(got_s), np.asarray(got_i)
+    np.testing.assert_array_equal(got_i, want_i)
+    np.testing.assert_allclose(got_s, want_s, rtol=2e-6, atol=1e-6)
+    if case == "valid_inside":
+        assert (got_i < valid).all()
+    if case == "duplicate_rows":
+        same = np.nonzero((rows == rows[got_i[0, 0]]).all(axis=1))[0]
+        assert len(same) == 3 and list(got_i[0, :3]) == sorted(same)
+    if case == "best_equals_kth":
+        assert block + 5 not in got_i[0] and np.isfinite(got_s).all()
+
+
+@pytest.mark.parametrize("side", ["float16_rows", "float32_queries"])
+def test_the_split_into_bfloat16_pieces_is_exact(side):
+    """The kernel's arithmetic rests on two exact splits: every finite
+    float16 bit pattern, subnormals and both zeros included, equals
+    float32(hi) + float32(lo) of its two bfloat16 pieces; and the three
+    bfloat16 pieces of a float32 query sum back to it, bit for bit."""
+    import jax
+
+    from dnn_page_vectors_tpu.ops.topk import f16_pieces, split_query
+    if side == "float16_rows":
+        bits = np.arange(65536, dtype=np.uint32)
+        bits = bits[np.isfinite(bits.astype(np.uint16).view(np.float16))]
+        hi, lo = jax.jit(f16_pieces)(jnp.asarray(bits))
+        got = np.asarray(hi, np.float32) + np.asarray(lo, np.float32)
+        want = bits.astype(np.uint16).view(np.float16).astype(np.float32)
+    else:
+        rng = np.random.default_rng(41)
+        want = np.concatenate([
+            rng.normal(size=4096), rng.normal(size=4096) * 1e-3,
+            rng.uniform(-1, 1, 4096) / 16]).astype(np.float32)
+        pieces = jax.jit(split_query)(jnp.asarray(want))
+        got = sum(np.asarray(p, np.float32).astype(np.float64)
+                  for p in pieces).astype(np.float32)
+    assert np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("form", ["float16", "pair_words", "pair_words_k200",
+                                  "int8_scales", "float32"])
+def test_the_pages_dtype_picks_the_scan_body(eight_devices, form):
+    """The pages' dtype picks the body: a float16 store staged as pair
+    words (`stage_shard(words=True)`, the same bytes) runs `exact_scan`
+    when k fits its 128 lanes; a wider k over pair words, float16 rows as
+    they are, int8 codes with scales (`jit_run`) and float32 rows run the
+    XLA scan. No option chooses it."""
+    import jax
+
+    from dnn_page_vectors_tpu.ops.topk import (
+        pair_words, sharded_topk_fn, stage_shard)
+    mesh = make_mesh(MeshConfig(data=2))
+    rng = np.random.default_rng(42)
+    rows = rng.normal(size=(64, 16)).astype(np.float16)
+    scaled = form == "int8_scales"
+    words = form.startswith("pair_words")
+    k = 200 if form == "pair_words_k200" else 10
+    raw = {"int8_scales": rows.astype(np.int8),
+           "float32": rows.astype(np.float32)}.get(form, rows)
+    pages, scales = stage_shard(raw, 64, 16, mesh,
+                                scales=np.ones(64, np.float16) if scaled
+                                else None, words=words)
+    if words:
+        assert pages.dtype == jnp.uint32 and pages.shape == (64, 8)
+        np.testing.assert_array_equal(np.asarray(pages), pair_words(rows))
+    args = [jax.ShapeDtypeStruct((8, 16), jnp.float32), pages]
+    args += [scales] if scaled else []
+    args += [jax.ShapeDtypeStruct((2,), jnp.int32),
+             jax.ShapeDtypeStruct((8, 2 * k), jnp.int32)]
+    jaxpr = str(jax.make_jaxpr(sharded_topk_fn(mesh, k, scaled=scaled))(
+        *args))
+    assert ("exact_scan" in jaxpr) == (form == "pair_words")
+
+
+@pytest.mark.parametrize("path", ["carried", "topk_over_store"])
+def test_pair_words_answer_a_top_k_past_the_lanes(eight_devices, tmp_path,
+                                                  path):
+    """A float16 store staged as pair words answers k = 200, wider than
+    `exact_scan`'s 128 lanes, through the XLA scan, which decodes the words
+    a chunk at a time: on the carried path bit for bit what the same rows
+    as float16 give, and through `topk_over_store` the float64 ranking of
+    the stored vectors, page ids and all."""
+    from dnn_page_vectors_tpu.ops.topk import pair_words
+    mesh = make_mesh(MeshConfig(data=2))
+    rng = np.random.default_rng(43)
+    dim, n, k = 16, 700, 200
+    rows = (rng.normal(size=(n, dim)) / 4).astype(np.float16)
+    rows[5, :3] = [6e-8, -3e-6, 1e-5]             # float16 subnormals
+    q = rng.normal(size=(8, dim)).astype(np.float32)
+    if path == "carried":
+        pad = np.zeros((768 - n, dim), np.float16)
+        full = np.concatenate([rows, pad])
+        shards = [(full[s:s + 256], None, min(256, n - s))
+                  for s in range(0, 768, 256)]
+        got_s, got_i = _carried(mesh, k, q, [
+            (pair_words(r), None, v) for r, _, v in shards], False)
+        want_s, want_i = _carried(mesh, k, q, shards, False)
+        np.testing.assert_array_equal(got_s.view(np.int32),
+                                      want_s.view(np.int32))
+        np.testing.assert_array_equal(got_i, want_i)
+        ref_s, ref_i = _f64_topk(q, rows, k, n)
+    else:
+        from dnn_page_vectors_tpu.infer.vector_store import VectorStore
+        ids = np.arange(5000, 5000 + n)
+        store = VectorStore(str(tmp_path / "store"), dim=dim, shard_size=256)
+        for si in range(3):
+            sl = slice(si * 256, min((si + 1) * 256, n))
+            store.write_shard(si, ids[sl], rows[sl])
+        got_s, got_i = topk_over_store(q, store, mesh, k=k, chunk=64,
+                                       query_batch=8)
+        ref_s, ref_i = _f64_topk(q, rows, k, n)
+        ref_i = ids[ref_i]
+    np.testing.assert_array_equal(got_i, ref_i)
+    np.testing.assert_allclose(got_s, ref_s, rtol=2e-6, atol=1e-6)
