@@ -50,7 +50,7 @@ class DataConfig:
 class ModelConfig:
     """Encoder zoo settings. `encoder` selects the family."""
     # cdssm | kim_cnn | lstm | bert | t5 | glm4_moe_lite | granitemoehybrid
-    # | falcon_h1
+    # | falcon_h1 | qwen3_next
     encoder: str = "cdssm"
     embed_dim: int = 128             # token/word embedding width
     out_dim: int = 128               # final vector dimension (both towers)
@@ -123,6 +123,20 @@ class ModelConfig:
     attention_out_multiplier: float = 1.0
     key_multiplier: float = 1.0
     mlp_multipliers: Tuple[float, ...] = ()    # gate, down
+    # qwen3_next (models/qwen3_next.py): the published keys under their
+    # published names, beside num_key_value_heads, head_dim, rope_theta,
+    # rms_norm_eps, moe_intermediate_size and num_experts_per_tok above
+    # (num_layers is its num_hidden_layers, num_heads its
+    # num_attention_heads, n_routed_experts its num_experts,
+    # shared_intermediate_size its shared_expert_intermediate_size, mlp_dim
+    # its intermediate_size, which no layer reads: every layer is sparse)
+    full_attention_interval: int = 4
+    linear_num_key_heads: int = 16
+    linear_num_value_heads: int = 32
+    linear_key_head_dim: int = 128
+    linear_value_head_dim: int = 128
+    linear_conv_kernel_dim: int = 4
+    partial_rotary_factor: float = 1.0
     # What BulkEmbedder / SearchService hold a tower's matrices in
     # (infer/bulk_embed.py:hold_weights): float32 as trained, or bfloat16
     # (cast once at construction; what the tower computes with in float32
@@ -854,6 +868,37 @@ def falcon_h1_34b_pp12() -> Config:
     )
 
 
+def qwen3_next_80b_ep16() -> Config:
+    """Qwen3-Next-80B-A3B-Instruct (Qwen, `qwen3_next`) as ONE shared tower,
+    cut to one chip's share of a layer that 16 chips hold together (expert
+    parallel: 32 of the 512 routed experts here; Gated DeltaNet, attention,
+    router and shared expert replicated; an eighth of the 151,936 embedding
+    rows): one whole period of the published 48 layers (three Gated DeltaNet
+    layers, then gated attention), every width as published. Pages of 2,048
+    tokens, last-token pool, per-block recomputation
+    (benchmarks/configs/qwen3_next_80b_ep16.json states the cut)."""
+    return Config(
+        name="qwen3_next_80b_ep16",
+        data=DataConfig(tokenizer="wordpiece", corpus="toy",
+                        num_pages=10_000_000, vocab_size=18_992,
+                        page_len=2048, query_len=64),
+        model=ModelConfig(
+            encoder="qwen3_next", num_layers=4, num_heads=16,
+            num_key_value_heads=2, head_dim=256, model_dim=2048,
+            mlp_dim=5120, moe_intermediate_size=512,
+            shared_intermediate_size=512, n_routed_experts=512,
+            num_experts_per_tok=10, experts_held=32, experts_held_start=0,
+            full_attention_interval=4, linear_num_key_heads=16,
+            linear_num_value_heads=32, linear_key_head_dim=128,
+            linear_value_head_dim=128, linear_conv_kernel_dim=4,
+            partial_rotary_factor=0.25, rope_theta=1e7, rms_norm_eps=1e-6,
+            out_dim=2048, attention="flash", dropout=0.0,
+            shared_towers=True, remat_blocks=True),
+        mesh=MeshConfig(data=1),
+        train=TrainConfig(batch_size=16, steps=100_000, learning_rate=1e-4),
+    )
+
+
 CONFIGS = {
     "cdssm_toy": cdssm_toy,
     "kim_cnn_v5e8": kim_cnn_v5e8,
@@ -865,6 +910,7 @@ CONFIGS = {
     "glm47_flash_ep8": glm47_flash_ep8,
     "granite4_h_small_ep2": granite4_h_small_ep2,
     "falcon_h1_34b_pp12": falcon_h1_34b_pp12,
+    "qwen3_next_80b_ep16": qwen3_next_80b_ep16,
 }
 
 

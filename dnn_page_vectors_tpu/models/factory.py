@@ -15,6 +15,8 @@ from dnn_page_vectors_tpu.models.granite_hybrid import (GraniteHybridEncoder,
                                                         GraniteSizes)
 from dnn_page_vectors_tpu.models.kim_cnn import KimCnnEncoder
 from dnn_page_vectors_tpu.models.lstm import LstmEncoder
+from dnn_page_vectors_tpu.models.qwen3_next import (Qwen3NextEncoder,
+                                                    Qwen3NextSizes)
 from dnn_page_vectors_tpu.models.transformer import TransformerEncoder
 from dnn_page_vectors_tpu.models.two_tower import TwoTower
 
@@ -119,6 +121,30 @@ def _build_encoder(cfg: Config, vocab_size: int, name: str,
                                num_layers=m.num_layers, out_dim=m.out_dim,
                                attention_kind=m.attention, dtype=dtype,
                                name=name)
+    if m.encoder == "qwen3_next":
+        sizes = Qwen3NextSizes(
+            model_dim=m.model_dim, num_heads=m.num_heads,
+            num_kv_heads=m.num_key_value_heads, head_dim=m.head_dim,
+            partial_rotary_factor=m.partial_rotary_factor,
+            rope_theta=m.rope_theta,
+            full_attention_interval=m.full_attention_interval,
+            linear_num_key_heads=m.linear_num_key_heads,
+            linear_num_value_heads=m.linear_num_value_heads,
+            linear_key_head_dim=m.linear_key_head_dim,
+            linear_value_head_dim=m.linear_value_head_dim,
+            linear_conv_kernel_dim=m.linear_conv_kernel_dim,
+            moe_mlp_dim=m.moe_intermediate_size,
+            shared_mlp_dim=m.shared_intermediate_size,
+            n_routed_experts=m.n_routed_experts,
+            num_experts_per_tok=m.num_experts_per_tok,
+            experts_held=m.experts_held or m.n_routed_experts,
+            experts_held_start=m.experts_held_start,
+            norm_eps=m.rms_norm_eps)
+        return Qwen3NextEncoder(vocab_size=vocab_size, sizes=sizes,
+                                num_layers=m.num_layers, out_dim=m.out_dim,
+                                remat=m.remat_blocks,
+                                attention_kind=m.attention, dtype=dtype,
+                                name=name)
     raise ValueError(f"unknown encoder {cfg.model.encoder!r}")
 
 

@@ -224,6 +224,9 @@ class RoutedExperts(nn.Module):
     dtype: jnp.dtype = jnp.bfloat16
     router: str = "sigmoid_noaux_tc"    # how scores become a selection
     shared_dim: int = 0                 # the shared expert's width; 0: mlp_dim
+    # the shared expert's output times sigmoid(u W), W [d, 1]
+    # (`shared_expert_gate`), a scalar a token: Qwen3-Next's
+    shared_gate: bool = False
 
     @nn.compact
     def __call__(self, x: jnp.ndarray):
@@ -287,6 +290,10 @@ class RoutedExperts(nn.Module):
         with jax.named_scope("moe.shared"):
             shared = SwiGlu(self.shared_dim or self.mlp_dim, d,
                             dtype=self.dtype, name="shared")(u)
+            if self.shared_gate:
+                shared = shared * jax.nn.sigmoid(nn.Dense(
+                    1, use_bias=False, dtype=self.dtype,
+                    name="shared_expert_gate")(u))
         stats = {"held": plan.sizes, "absent": plan.absent,
                  "dropped": B * L * k - plan.absent - placed,
                  "worst_case": fallback}

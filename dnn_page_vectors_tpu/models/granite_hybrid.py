@@ -54,7 +54,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Tuple
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -131,13 +131,15 @@ def _dt_bias_init(key, shape, dtype=jnp.float32):
     return dt + jnp.log(-jnp.expm1(-dt))
 
 
-def causal_conv(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray):
+def causal_conv(x: jnp.ndarray, w: jnp.ndarray,
+                b: Optional[jnp.ndarray] = None):
     """Depthwise causal convolution along axis 1 of [B, L, C] with taps
-    w [K, C] (tap K-1 is the token itself), zeros on the left, bias b [C]."""
+    w [K, C] (tap K-1 is the token itself), zeros on the left, bias b [C]
+    (None: no bias)."""
     K, L = w.shape[0], x.shape[1]
     xp = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
-    return sum(xp[:, i:i + L] * w[i].astype(x.dtype) for i in range(K)) \
-        + b.astype(x.dtype)
+    y = sum(xp[:, i:i + L] * w[i].astype(x.dtype) for i in range(K))
+    return y if b is None else y + b.astype(x.dtype)
 
 
 def segment_multipliers(multipliers, widths) -> np.ndarray:
@@ -201,10 +203,19 @@ class Mamba2Mixer(nn.Module):
 
 class GqaAttention(nn.Module):
     """Causal grouped-query attention at a stated score scale, with rotary
-    positions or none."""
-    sizes: Any                    # GraniteSizes | models/falcon_h1.py's
+    positions or none. Three options, each off by default (and then no
+    operation of the program): `qk_norm`, a zero-centred RMSNorm over each
+    head of q and of k (`q_norm`, `k_norm`, eps the sizes' `norm_eps`)
+    before the rotary; `rotary_dim`, rotary over the first that many dims of
+    a head only (0: the whole head); `output_gate`, `wq` twice as wide, [q |
+    gate] a head, and the heads' output times sigmoid(gate) before `wo`
+    (models/qwen3_next.py)."""
+    sizes: Any                    # GraniteSizes | falcon_h1's | qwen3_next's
     dtype: jnp.dtype = jnp.bfloat16
     kind: str = "flash"           # flash | dense
+    qk_norm: bool = False
+    rotary_dim: int = 0
+    output_gate: bool = False
 
     @nn.compact
     def __call__(self, u: jnp.ndarray, pad_mask: jnp.ndarray) -> jnp.ndarray:
@@ -215,13 +226,25 @@ class GqaAttention(nn.Module):
         dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=self.dtype,
                                          name=name)
         with jax.named_scope("attn.qkv"):
-            q = dense(H * dh, "wq")(u).reshape(B, L, H, dh)
+            if self.output_gate:
+                q = dense(H * 2 * dh, "wq")(u).reshape(B, L, H, 2 * dh)
+                q, gate = q[..., :dh], q[..., dh:]
+            else:
+                q = dense(H * dh, "wq")(u).reshape(B, L, H, dh)
             k = times(dense(G * dh, "wk")(u), c.key_multiplier).reshape(
                 B, L, G, dh)
             v = dense(G * dh, "wv")(u).reshape(B, L, G, dh)
+            if self.qk_norm:
+                norm = lambda name: RmsNorm(dtype=self.dtype, eps=c.norm_eps,
+                                            zero_centred=True, name=name)
+                q, k = norm("q_norm")(q), norm("k_norm")(k)
         if c.rope_theta:
             with jax.named_scope("attn.rope"):
-                q, k = rope(q, c.rope_theta), rope(k, c.rope_theta)
+                r = self.rotary_dim or dh
+                turn = lambda t: rope(t, c.rope_theta) if r == dh else \
+                    jnp.concatenate([rope(t[..., :r], c.rope_theta),
+                                     t[..., r:]], axis=-1)
+                q, k = turn(q), turn(k)
         k, v = (jnp.repeat(t, H // G, axis=2) for t in (k, v))
         bhld = lambda t: t.transpose(0, 2, 1, 3)
         if self.kind == "flash":
@@ -247,7 +270,10 @@ class GqaAttention(nn.Module):
             raise ValueError(f"unknown attention kind {self.kind!r} for the "
                              "hybrid tower (want dense | flash)")
         with jax.named_scope("attn.out"):
-            return dense(d, "wo")(out.reshape(B, L, H * dh))
+            out = out.reshape(B, L, H * dh)
+            if self.output_gate:
+                out = out * jax.nn.sigmoid(gate.reshape(B, L, H * dh))
+            return dense(d, "wo")(out)
 
 
 class HybridBlock(nn.Module):

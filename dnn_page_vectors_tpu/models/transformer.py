@@ -44,10 +44,13 @@ def _relative_position_bucket(rel_pos: jnp.ndarray, num_buckets: int = 32,
 class RmsNorm(nn.Module):
     """x / rms(x) * scale over the last axis; with `groups` > 1 each of that
     many equal runs of the axis is divided by its own root mean square (the
-    learned scale still spans the whole axis)."""
+    learned scale still spans the whole axis). `zero_centred`: the learned
+    vector w starts at 0 and the scale is 1 + w (param `centred_scale`), as
+    Qwen3-Next's norms have it."""
     dtype: jnp.dtype = jnp.bfloat16
     eps: float = 1e-6
     groups: int = 1
+    zero_centred: bool = False
 
     @nn.compact
     def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
@@ -58,6 +61,10 @@ class RmsNorm(nn.Module):
         y = xf * jax.lax.rsqrt(var + self.eps)
         if self.groups > 1:
             y = y.reshape(x.shape)
+        if self.zero_centred:
+            w = self.param("centred_scale", nn.initializers.zeros,
+                           (x.shape[-1],))
+            return (y * (1.0 + w)).astype(self.dtype)
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
         return (y * scale).astype(self.dtype)
 

@@ -33,6 +33,7 @@ from dnn_page_vectors_tpu.parallel.sharding import (
     batch_sharding, param_shardings, put_global, replicated, shard_params,
     stacked_batch_sharding)
 from dnn_page_vectors_tpu.models.glm_moe import STATS as MOE_STATS
+from dnn_page_vectors_tpu.models.qwen3_next import GDN_STATS
 from dnn_page_vectors_tpu.train.optimizer import SELECT_BIAS, make_optimizer
 from dnn_page_vectors_tpu.utils import faults, telemetry
 from dnn_page_vectors_tpu.utils.logging import MetricsLogger
@@ -68,17 +69,34 @@ def moe_metrics(stats) -> Dict[str, jnp.ndarray]:
             "moe/worst_case_calls": sum(tower["worst_case"]).sum()}
 
 
-def make_train_step(model, tx, loss_chunk: int = 0, moe_stats: bool = False):
+def gdn_metrics(stats) -> Dict[str, jnp.ndarray]:
+    """The linear-attention layers' counters of one step, from what the
+    shared tower sowed (one entry per call, query then page):
+    `gdn/tokens` [layers] the positions the recurrence ran over (summed),
+    `gdn/state_norm_max` [layers] the largest Frobenius norm of a head's
+    final state (the larger of the two calls)."""
+    tower = stats["query_tower"]
+    return {"gdn/tokens": sum(tower["tokens"]),
+            "gdn/state_norm_max": jnp.max(
+                jnp.stack(tower["state_norm_max"]), axis=0)}
+
+
+def make_train_step(model, tx, loss_chunk: int = 0, moe_stats: bool = False,
+                    gdn_stats: bool = False):
     """Build the (un-jitted) global-batch train step; caller jits with
     shardings + donation.
 
     `moe_stats`: the towers have routed-expert layers (models/glm_moe.py),
-    whose counters join the step's metrics, reduced on the device.
+    whose counters join the step's metrics, reduced on the device;
+    `gdn_stats` likewise for linear-attention layers
+    (models/qwen3_next.py).
 
     `loss_chunk` > 0 selects the fused/chunked contrastive loss
     (train.loss_chunk, models/losses.py): the [B, B(1+H)] logits never
     materialize — per-chunk log-sum-exp tiles stream against the
     GSPMD-gathered global page pool instead."""
+
+    collections = [MOE_STATS] * moe_stats + [GDN_STATS] * gdn_stats
 
     def train_step(state: TrainState, batch: Dict[str, jnp.ndarray],
                    base_rng: jax.Array) -> Tuple[TrainState, Dict[str, jnp.ndarray]]:
@@ -91,8 +109,8 @@ def make_train_step(model, tx, loss_chunk: int = 0, moe_stats: bool = False):
                 rngs={"dropout": rng},
                 page_seg=batch.get("page_seg"),
                 page_pos=batch.get("page_pos"),
-                mutable=[MOE_STATS] if moe_stats else False)
-            (q, p, neg, scale), stats = out if moe_stats else (out, None)
+                mutable=collections or False)
+            (q, p, neg, scale), stats = out if collections else (out, None)
             # Flax names the towers' ops by module path; the loss and the
             # optimizer are no modules, so they get their scopes here
             with jax.named_scope("loss"):
@@ -100,6 +118,8 @@ def make_train_step(model, tx, loss_chunk: int = 0, moe_stats: bool = False):
                                                         chunk=loss_chunk)
             if moe_stats:
                 metrics = dict(metrics, **moe_metrics(stats[MOE_STATS]))
+            if gdn_stats:
+                metrics = dict(metrics, **gdn_metrics(stats[GDN_STATS]))
             return loss, metrics
 
         (loss, metrics), grads = jax.value_and_grad(
@@ -148,6 +168,7 @@ class Trainer:
         self.model = build_two_tower(cfg, self.page_tok.vocab_size,
                                      mesh=self.mesh)
         self._moe = getattr(self.model.query_tower, "sows_moe_stats", False)
+        self._gdn = getattr(self.model.query_tower, "sows_gdn_stats", False)
         self.tx = make_optimizer(cfg.train,
                                  no_decay=SELECT_BIAS if self._moe else None)
         self.hard_negative_lookup = hard_negative_lookup
@@ -198,7 +219,8 @@ class Trainer:
         if self._compiled is None:
             step_fn = make_train_step(self.model, self.tx,
                                       loss_chunk=self.cfg.train.loss_chunk,
-                                      moe_stats=self._moe)
+                                      moe_stats=self._moe,
+                                      gdn_stats=self._gdn)
             state_sh = jax.tree_util.tree_map(lambda x: x.sharding, state)
             self._compiled = jax.jit(
                 step_fn,
@@ -267,7 +289,8 @@ class Trainer:
         if self._compiled_multi is None:
             step_fn = make_train_step(self.model, self.tx,
                                       loss_chunk=self.cfg.train.loss_chunk,
-                                      moe_stats=self._moe)
+                                      moe_stats=self._moe,
+                                      gdn_stats=self._gdn)
 
             def multi(state, stacked, base_rng):
                 def body(st, batch):

@@ -13,6 +13,7 @@ from __future__ import annotations
 from typing import Optional
 
 from dnn_page_vectors_tpu.config import Config, ModelConfig
+from dnn_page_vectors_tpu.ops.gated_delta import CHUNK
 
 
 def _mixer_flops(m: ModelConfig, L: int) -> float:
@@ -28,6 +29,22 @@ def _mixer_flops(m: ModelConfig, L: int) -> float:
     scan = n * Q * (Q + 1) / 2 * (2 * N * G + 2 * H * P) \
         + 2 * (n - 1) * 2 * Q * H * P * N
     return L * (2 * d * (2 * H * P + 2 * G * N + H) + 2 * H * P * d) + scan
+
+
+def _gated_delta_flops(m: ModelConfig, L: int) -> float:
+    """The chunked gated delta rule (ops/gated_delta.py) over one sequence
+    in one layer, every product at its full tile as computed: per chunk of
+    Q and value head, K K^T, Q K^T, W, U, the scores' product with V' (Q x Q
+    tiles), the 2 (f - 1) products that make T over f levels of doubling
+    blocks, and the three products with the carried state (W S, Q S,
+    K^T V')."""
+    H, K, V = (m.linear_num_value_heads, m.linear_key_head_dim,
+               m.linear_value_head_dim)
+    Q = min(CHUNK, L)
+    factors = max(Q - 1, 1).bit_length()
+    per_token = 2 * (3 * Q * K + 2 * Q * V + 2 * (factors - 1) * Q * Q
+                     + 3 * K * V)
+    return -(-L // Q) * Q * H * per_token
 
 
 def encoder_flops_per_example(m: ModelConfig, seq_len: int) -> float:
@@ -87,6 +104,27 @@ def encoder_flops_per_example(m: ModelConfig, seq_len: int) -> float:
             + 4 * dh * m.num_heads * L * (L + 1) / 2
         return float(m.num_layers * (mamba + attn + L * 6 * d * m.mlp_dim)
                      + 2 * d * m.out_dim)
+    if m.encoder == "qwen3_next":
+        # per layer Gated DeltaNet (its three projections and the chunked
+        # rule, `_gated_delta_flops`) or gated grouped-query attention
+        # (causal scores counted once); in every layer the router, the gated
+        # shared expert and the EXPECTED share of assignments held
+        d, L = m.model_dim, seq_len
+        Hk, Hv = m.linear_num_key_heads, m.linear_num_value_heads
+        Dk, Dv = m.linear_key_head_dim, m.linear_value_head_dim
+        gdn = L * 2 * d * (2 * Hk * Dk + 2 * Hv * Dv + 2 * Hv + Hv * Dv) \
+            + _gated_delta_flops(m, L)
+        H, G, dh = m.num_heads, m.num_key_value_heads, m.head_dim
+        attn = L * 2 * d * (2 * H * dh + 2 * G * dh + H * dh) \
+            + 4 * dh * H * L * (L + 1) / 2
+        held = (m.experts_held or m.n_routed_experts) / m.n_routed_experts
+        moe = L * (2 * d * m.n_routed_experts + 2 * d
+                   + 6 * d * m.shared_intermediate_size
+                   + m.num_experts_per_tok * held * 6 * d
+                   * m.moe_intermediate_size)
+        n_attn = m.num_layers // m.full_attention_interval
+        return float((m.num_layers - n_attn) * gdn + n_attn * attn
+                     + m.num_layers * moe + 2 * d * m.out_dim)
     if m.encoder == "cdssm":
         E, C = m.embed_dim, m.conv_channels
         conv = sum(2 * w * E * C for w in m.conv_widths) * seq_len
